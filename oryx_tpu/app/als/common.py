@@ -301,7 +301,14 @@ def apply_up_lines(
                         inner = tail[2:-2]
                         if inner == b"":
                             known = []
-                        elif inner.startswith(b'"') and inner.endswith(b'"'):
+                        elif (
+                            inner.startswith(b'"')
+                            and inner.endswith(b'"')
+                            # a quote left inside a piece means the list is
+                            # not compact ("a", "b"): the byte split would
+                            # weld ids together, so json parses it instead
+                            and inner.count(b'"') == 2 * (inner.count(b'","') + 1)
+                        ):
                             known = [
                                 s.decode("utf-8", "replace")
                                 for s in inner[1:-1].split(b'","')

@@ -32,7 +32,7 @@ from oryx_tpu.app import pmml as app_pmml
 from oryx_tpu.app.als.common import apply_up_lines, consume_blocks_columnar
 from oryx_tpu.bus.core import KeyMessage
 from oryx_tpu.common.config import Config
-from oryx_tpu.common import tracing
+from oryx_tpu.common import metrics, tracing
 from oryx_tpu.common.lang import ReadWriteLock
 from oryx_tpu.common.text import read_json
 from oryx_tpu.common.vectormath import Solver, get_solver
@@ -600,6 +600,7 @@ class ALSServingModel(ServingModel):
                 return _host_top_k(y_host, lsh_rows, query, k, cosine=cosine)
             if isinstance(y_mat, topn_ops.ShardedItemMatrix):
                 # mesh-sharded scan: per-device top-k + all_gather merge
+                metrics.registry.counter("serving.scan.sharded.queries").inc()
                 bi, bv = topn_ops.top_k_sharded(y_mat, query, k, cosine=cosine)
                 return bi[0], bv[0]
             # continuous batching: concurrent requests against the same

@@ -26,6 +26,7 @@ from oryx_tpu.app import pmml as app_pmml
 from oryx_tpu.app.als import data as als_data
 from oryx_tpu.app.als.common import apply_up_lines, consume_blocks_columnar
 from oryx_tpu.bus.core import KeyMessage
+from oryx_tpu.common import metrics
 from oryx_tpu.common.config import Config
 from oryx_tpu.common.records import InteractionBlock, Records
 from oryx_tpu.common.text import json_str as _json_str, read_json
@@ -38,6 +39,9 @@ from oryx_tpu.native.store import (
 )
 
 log = logging.getLogger(__name__)
+
+# events by the side that folded them (FoldInSession.ran)
+_FOLD_EVENTS = {"device": "speed.fold.device.events", "host": "speed.fold.host.events"}
 
 # parse_batch may legitimately return None (empty batch), so the native
 # parser signals "run the Python path instead" with a distinct sentinel
@@ -480,6 +484,7 @@ class ALSSpeedModelManager(SpeedModelManager):
         session = self._fold_session(yty, xtx, n, model.features, shard)
         session.add_block(xu, xu_valid, yi, yi_valid, values)
         new_xu, x_upd, new_yi, y_upd = session.solve()
+        metrics.registry.counter(_FOLD_EVENTS[session.ran]).inc(n)
         x_rows = np.nonzero(x_upd)[0]
         y_rows = np.nonzero(y_upd)[0]
         known = not self.no_known_items

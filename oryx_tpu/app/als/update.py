@@ -318,8 +318,10 @@ def _publish_factor_rows(
             records = [
                 (
                     "UP",
+                    # compact, like the speed layer's deltas: consumers'
+                    # columnar parse splits the known list on '","'
                     f'["{tag}",{json_str(i)},{v},'
-                    f"{json.dumps(sorted(known.get(i, ())))}]",
+                    f"{json.dumps(sorted(known.get(i, ())), separators=(',', ':'))}]",
                 )
                 for i, v in zip(chunk_ids, vecs)
             ]

@@ -259,6 +259,29 @@ def encode_block_lines(block) -> bytes:
     )
 
 
+# A columnar block is a fixed-width array: as wide as its longest record
+# for EVERY record (10K factor rows beside a 2 MB inline MODEL document =
+# 20 GB). A record past this size travels in a block of its own. A
+# consumer gathering lines for lines_to_block asks `joinable` how many may
+# join and ends its read there (only it can leave the rest unconsumed).
+SOLO_RECORD_BYTES = 64 * 1024
+
+
+def joinable(lines: list[bytes], gathered: int) -> tuple[int, bool]:
+    """(how many leading ``lines`` may join a block that already holds
+    ``gathered`` lines, whether the block is whole with them). All may
+    join unless one is outsized; then those before it and before its
+    "@trc" header, or, where that leaves the block empty, the outsized
+    record alone with its header: nothing joins after either."""
+    if max(map(len, lines), default=0) <= SOLO_RECORD_BYTES:
+        return len(lines), False
+    big = next(j for j, ln in enumerate(lines) if len(ln) > SOLO_RECORD_BYTES)
+    head = big
+    while head > 0 and lines[head - 1].startswith(TRACE_LINE_PREFIX):
+        head -= 1
+    return (big + 1 if head == 0 and not gathered else head), True
+
+
 def lines_to_block(raw: list[bytes], RecordBlock):
     # trace control records ("@trc" lines): a producer prepends at most
     # one per batch, so the common shapes are an O(1) head check plus one

@@ -613,12 +613,15 @@ class _FileConsumer(TopicConsumer):
 
         ledger.register("consumer", self, live=lambda c: not c.closed())
 
-    def _read_partition_raw(self, i: int, budget: int, out: list[bytes]) -> None:
+    def _read_partition_raw(self, i: int, budget: int, out: list[bytes]) -> bool:
         """Append up to `budget` complete raw record lines (bytes, newline
         stripped) from partition i, walking the segment chain from
         self._pos[i]. Decoding is the caller's job — the hot consume path
-        (poll_block) decodes whole batches columnar instead."""
+        (poll_block) decodes whole batches columnar instead. True when an
+        outsized record ended the read (blockcodec.joinable): `out` is a
+        whole block then, whatever is left of the budget."""
         broker = self._broker
+        closed = False
         while budget > 0:
             segs = broker._segments(self._topic, i)
             pos = self._pos[i]
@@ -631,7 +634,7 @@ class _FileConsumer(TopicConsumer):
             seg_base, seg_path = segs[idx]
             is_active = idx == len(segs) - 1
             if not seg_path.exists():
-                return
+                return closed
             got = 0
             with open(seg_path, "rb") as f:
                 cur = self._cursor.get(i)
@@ -671,13 +674,19 @@ class _FileConsumer(TopicConsumer):
                         taken = sum(map(len, lines)) + len(lines)
                     else:
                         taken = nl + 1
+                    keep, closed = blockcodec.joinable(lines, len(out))
+                    if closed:
+                        lines = lines[:keep]
+                        taken = sum(map(len, lines)) + keep
                     got += len(lines)
                     consumed += taken
                     if b"" in lines:
                         lines = [ln for ln in lines if ln]
                     out.extend(lines)
                     budget -= len(lines)
-                    if taken < len(chunk):
+                    if closed:
+                        budget = 0  # nothing more this call
+                    elif taken < len(chunk):
                         f.seek(byte0 + consumed)  # rewind the over-read
                 if got:
                     self._cursor[i] = (seg_base, byte0 + consumed)
@@ -685,8 +694,9 @@ class _FileConsumer(TopicConsumer):
             if is_active or got == 0:
                 # active exhausted, or an archived segment yielded nothing
                 # (roll race: re-resolve next poll instead of spinning)
-                return
+                return closed
             # archived segment exhausted: fall through to the next one
+        return closed
 
     @staticmethod
     def _decode_line(line: bytes) -> KeyMessage | None:
@@ -732,7 +742,8 @@ class _FileConsumer(TopicConsumer):
         while True:
             raw: list[bytes] = []
             for i in sorted(self._pos):
-                self._read_partition_raw(i, max_records - len(raw), raw)
+                if self._read_partition_raw(i, max_records - len(raw), raw):
+                    break
                 if len(raw) >= max_records:
                     break
             if raw:

@@ -923,11 +923,15 @@ class _ShmConsumer(TopicConsumer):
                 frame_lines = bytes(payload).split(b"\n")
                 if frame_lines and frame_lines[-1] == b"":
                     frame_lines.pop()
-                lines.extend(frame_lines[start : start + take])
+                part = frame_lines[start : start + take]
+                take, whole = blockcodec.joinable(part, len(lines))
+                lines.extend(part[:take])
                 pos += take
                 taken += take
                 if start + take == count:
                     cur += wire
+                if whole:
+                    break  # an outsized record travels alone
                 continue
             # KIND_COLS
             if lines:

@@ -226,6 +226,9 @@ def run_bus_tail(cfg: Config, from_beginning: bool = False, out=None, stop_after
             consumer.close()
 
 
+_INPUT_CHUNK_BYTES = 1 << 20
+
+
 def run_bus_input(cfg: Config, input_file: str | None) -> int:
     """kafka-input analogue: push lines to the input topic, keyed by a hex
     hash of the line so they spread over partitions (the serving layer's
@@ -246,13 +249,17 @@ def run_bus_input(cfg: Config, input_file: str | None) -> int:
     sent = 0
     try:
         with broker.producer(topic) as producer:
-            for line in f:
-                line = line.rstrip("\n")
-                if not line:
-                    continue
-                key = hashlib.md5(line.encode("utf-8")).hexdigest()
-                producer.send(key, line)
-                sent += 1
+            # one batched publish per chunk of lines: a send per line pays
+            # a lock/open/append cycle each (~15K lines/s on the file bus)
+            # (stdin stays line at a time: it may be someone typing)
+            hint = _INPUT_CHUNK_BYTES if input_file else 1
+            while chunk := f.readlines(hint):
+                records = [
+                    (hashlib.md5(line.encode("utf-8")).hexdigest(), line)
+                    for line in (raw.rstrip("\n") for raw in chunk)
+                    if line
+                ]
+                sent += producer.send_many(records)
     finally:
         if f is not sys.stdin:
             f.close()

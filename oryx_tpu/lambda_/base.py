@@ -49,14 +49,18 @@ class AbstractLayer:
         self._supervised: list[SupervisedThread] = []
         # multi-host: join the JAX multi-controller runtime before any
         # backend is touched, so jax.devices() spans the whole pod slice
-        # (no-op unless oryx.batch.compute.distributed.* is configured)
+        # (no-op unless oryx.batch.compute.distributed.* is configured);
+        # then take the device now — a layer that cannot get the platform
+        # its launcher named fails here, not at its first dispatch
         from oryx_tpu.parallel.distributed import (
-            maybe_enable_compile_cache,
+            claim_devices,
+            enable_compile_cache,
             maybe_initialize,
         )
 
         maybe_initialize(config)
-        maybe_enable_compile_cache(config)
+        self.device = claim_devices()
+        enable_compile_cache(config)
 
     # -- topics -------------------------------------------------------------
 
@@ -129,7 +133,11 @@ class AbstractLayer:
                     return
                 healthy = layer.healthy()
                 if self.path == "/healthz":
-                    body = {"healthy": healthy, "layer": layer.layer_name}
+                    body = {
+                        "healthy": healthy,
+                        "layer": layer.layer_name,
+                        "device": layer.device,
+                    }
                     status = 200 if healthy else 503
                 else:
                     if ledger.enabled():
@@ -141,6 +149,8 @@ class AbstractLayer:
                         "id": layer.id,
                         "stopped": layer.is_stopped(),
                         "healthy": healthy,
+                        "device": layer.device,
+                        **layer.status(),
                     }
                     status = 200
                 data = _json.dumps(body, indent=1).encode("utf-8")
@@ -184,6 +194,13 @@ class AbstractLayer:
         """False once any supervised thread has exhausted its restart
         policy and given up."""
         return all(t.healthy for t in self._supervised)
+
+    def status(self) -> dict:
+        """Layer-specific fields of the status JSON. ``input_attached`` is
+        the readiness signal for whoever feeds the input topic: a new
+        consumer group starts at the latest offset, so input sent before
+        it is true is never seen."""
+        return {}
 
     def is_stopped(self) -> bool:
         return self._stop_event.is_set()

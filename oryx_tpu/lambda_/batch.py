@@ -94,6 +94,13 @@ class BatchLayer(AbstractLayer):
         with self._state_lock:
             return self._generation_count
 
+    def status(self) -> dict:
+        with self._state_lock:
+            return {
+                "input_attached": self._consumer is not None,
+                "generations": self._generation_count,
+            }
+
     # -- generation loop ----------------------------------------------------
 
     def _one_interval(self) -> None:

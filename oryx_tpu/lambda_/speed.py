@@ -178,6 +178,19 @@ class SpeedLayer(AbstractLayer):
         with self._state_lock:
             return self._batch_count
 
+    def status(self) -> dict:
+        model = getattr(self.manager, "model", None)
+        # the sharded pipeline attaches per-partition consumers of its own
+        sharded = self._pipeline is not None and bool(self._pipeline.shard_consumers)
+        with self._state_lock:
+            return {
+                "input_attached": sharded or self._input_consumer is not None,
+                "batches": self._batch_count,
+                "model_fraction_loaded": (
+                    model.get_fraction_loaded() if model is not None else 0.0
+                ),
+            }
+
     def note_batch_published(self) -> None:
         """One micro-batch's updates are on the bus. Called by whichever
         worker owns the publish step — the fold loop here or the

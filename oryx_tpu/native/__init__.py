@@ -4,7 +4,9 @@ The reference outsources its hot CPU paths to the JVM's concurrent
 collections; here the serving/speed vector store is real C++ (SURVEY.md:
 "the serving layer's concurrent hash-partitioned vector store gets a C++
 implementation bound into Python, not a Python stand-in"). The shared
-library is compiled once into this package's _build/ directory and reused;
+library is compiled with -march=native into this package's _build/
+directory, keyed by source hash and host CPU, so a tree copied to another
+kind of machine rebuilds there instead of loading code for the wrong CPU;
 set ORYX_NATIVE=0 to force the pure-Python fallbacks.
 """
 
@@ -30,22 +32,43 @@ def native_enabled() -> bool:
     return os.environ.get("ORYX_NATIVE", "1") != "0"
 
 
-def _build_library() -> str | None:
-    """Compile the native sources to one .so, keyed by source hash so edits
-    rebuild and repeat imports reuse."""
+def _host_cpu() -> bytes:
+    """What -march=native depends on: this host's CPU model and feature
+    flags (the first processor's lines of /proc/cpuinfo)."""
+    with open("/proc/cpuinfo", "rb") as f:
+        lines = f.read().split(b"\n\n", 1)[0].splitlines()
+    return b"\n".join(
+        ln for ln in lines if ln.split(b":")[0].strip() in (b"model name", b"flags")
+    )
+
+
+def _library_target() -> str:
+    """Where this host's build of the current sources lives: keyed by source
+    hash (edits rebuild, repeat imports reuse) and by host CPU (a _build/
+    directory that travelled with the tree from another machine is not
+    loaded)."""
     h = hashlib.sha256()
-    paths = [os.path.join(_HERE, s) for s in _SOURCES]
-    for path in paths:
-        with open(path, "rb") as f:
+    for s in _SOURCES:
+        with open(os.path.join(_HERE, s), "rb") as f:
             h.update(f.read())
-    build_dir = os.path.join(_HERE, "_build")
-    os.makedirs(build_dir, exist_ok=True)
-    so_path = os.path.join(build_dir, f"liboryx_native_{h.hexdigest()[:16]}.so")
+    h.update(_host_cpu())
+    return os.path.join(_HERE, "_build", f"liboryx_native_{h.hexdigest()[:16]}.so")
+
+
+def _build_library() -> str | None:
+    """Compile the native sources to one .so, unless this host built these
+    sources before."""
+    so_path = _library_target()
     if os.path.exists(so_path):
         return so_path
+    paths = [os.path.join(_HERE, s) for s in _SOURCES]
+    os.makedirs(os.path.dirname(so_path), exist_ok=True)
+    # build aside, rename into place: a process starting meanwhile never
+    # loads a half-written library
+    tmp_path = f"{so_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        "-o", so_path, *paths, "-lpthread",
+        "-o", tmp_path, *paths, "-lpthread",
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
@@ -56,6 +79,7 @@ def _build_library() -> str | None:
             e, (err or b"").decode("utf-8", "replace")[:500],
         )
         return None
+    os.replace(tmp_path, so_path)
     return so_path
 
 
@@ -128,6 +152,13 @@ def find_asan_runtime() -> str | None:
     if out and os.path.isabs(out) and os.path.exists(out):
         return os.path.realpath(out)
     return None
+
+
+def library_path() -> str | None:
+    """Path of the native library this process loaded, or None when it
+    runs the pure-Python twins (disabled, not yet needed, or the build
+    failed). Never triggers a build: health endpoints report it."""
+    return _lib._name if _lib is not None else None
 
 
 def get_library() -> ctypes.CDLL | None:

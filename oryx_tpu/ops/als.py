@@ -45,6 +45,7 @@ Design (TPU-first, not a port):
 from __future__ import annotations
 
 import functools
+import logging
 from dataclasses import dataclass
 from typing import Optional
 
@@ -53,7 +54,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from oryx_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
+from oryx_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple, shard_layout
+
+log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -118,14 +121,9 @@ last_phase_seconds: dict[str, float] = {}
 
 
 def _pcast_varying(x):
-    """Mark an array device-varying inside shard_map where the running
-    jax has varying types (>= 0.6 ``jax.lax.pcast``); identity on older
-    versions, whose shard_map has no varying-type system and needs no
-    annotation for the scan carries to line up."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (DATA_AXIS,), to="varying")
-    return x
+    """Mark an array device-varying inside shard_map so scan carries that
+    mix it with ppermute outputs have one type."""
+    return jax.lax.pcast(x, (DATA_AXIS,), to="varying")
 
 
 def _mask_from_deg(shape, deg):
@@ -411,9 +409,7 @@ def train_als(
         if init_y is not None:
             # feature count or item universe changed under us: warm-start
             # is an optimization, never a correctness dependency
-            import logging
-
-            logging.getLogger(__name__).info(
+            log.info(
                 "init_y shape %s != (%d, %d); cold-starting",
                 np.shape(init_y), num_items, features,
             )
@@ -454,6 +450,10 @@ def train_als(
         u_arrs = to_arrs(u_buckets, row_sharded, row_sharded2)
         i_arrs = to_arrs(i_buckets, row_sharded, row_sharded2)
         y0 = jax.device_put(np.asarray(y0), repl)
+        log.info(
+            "ALS over %d devices, factors replicated, widest user bucket shards: %s",
+            num_shards, shard_layout(u_arrs[-1][1]),
+        )
         x, y = run_c(u_arrs, i_arrs, y0, lam_t, alpha_t)
     else:
         x, y = run_c(
@@ -518,10 +518,7 @@ def _train_als_sharded(
     matmul_dtype=None, packing=None,
 ) -> ALSModel:
     """shard_map ALS with factors sharded over the mesh (see module doc)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     s = int(np.prod(mesh.devices.shape))
     u_buckets = build_neighbor_buckets(
@@ -659,6 +656,7 @@ def _train_als_sharded(
         for t in i_arrs
     ]
     x_p, y_p = run_c(u_dev, i_dev, jax.device_put(y0, sh2))
+    log.info("ALS over %d devices, item factor shards: %s", s, shard_layout(y_p))
 
     x = np.zeros((num_users, features), np.float32)
     y = np.zeros((num_items, features), np.float32)
@@ -942,7 +940,6 @@ def _calibrate_fold_backend(yty, xtx, xu, xu_valid, yi, yi_valid, values, implic
     the host result (already computed — no work wasted). The device is
     timed on a second call so compile time doesn't poison the measurement."""
     global _auto_fold_choice
-    import logging
     import time as _time
 
     t0 = _time.perf_counter()
@@ -950,19 +947,18 @@ def _calibrate_fold_backend(yty, xtx, xu, xu_valid, yi, yi_valid, values, implic
         yty, xtx, xu, xu_valid, yi, yi_valid, values, implicit, backend="host"
     )
     t_host = _time.perf_counter() - t0
-    try:
-        fold_in_batch(  # compile + first dispatch, untimed
-            yty, xtx, xu, xu_valid, yi, yi_valid, values, implicit, backend="device"
-        )
-        t0 = _time.perf_counter()
-        fold_in_batch(
-            yty, xtx, xu, xu_valid, yi, yi_valid, values, implicit, backend="device"
-        )
-        t_device = _time.perf_counter() - t0
-    except Exception:  # device backend unusable: host it is
-        t_device = float("inf")
+    # a device error here propagates: electing the host on a failed
+    # device would hide that the process is not running where it says
+    fold_in_batch(  # compile + first dispatch, untimed
+        yty, xtx, xu, xu_valid, yi, yi_valid, values, implicit, backend="device"
+    )
+    t0 = _time.perf_counter()
+    fold_in_batch(
+        yty, xtx, xu, xu_valid, yi, yi_valid, values, implicit, backend="device"
+    )
+    t_device = _time.perf_counter() - t0
     _auto_fold_choice = "device" if t_device < t_host else "host"
-    logging.getLogger(__name__).info(
+    log.info(
         "fold-in auto backend: host %.3fs vs device %.3fs at n=%d -> %s",
         t_host, t_device, len(values), _auto_fold_choice,
     )
@@ -991,8 +987,7 @@ def fold_in_batch(
     'host' (float64 BLAS), or 'auto' — measured, not guessed: the first
     large enough batch runs both backends once, times them, and locks in
     the winner for the process. A size heuristic cannot know the
-    deployment's dispatch latency — a locally-attached TPU and a
-    tunneled one differ by ~100x per call, and guessing wrong costs 2-3x
+    deployment's dispatch latency, and guessing wrong costs 2-3x
     sustained speed-layer throughput."""
     n, k = xu.shape
     if backend == "auto":
@@ -1062,6 +1057,9 @@ class FoldInSession:
         self.xtx = xtx
         self.implicit = implicit
         self.backend = backend
+        # which side computed the last solve()'s result: "device" or "host"
+        # (set by the branch that ran, not read from the switch)
+        self.ran: str | None = None
         self._blocks: list[tuple] = []
         self._pending = 0
         from oryx_tpu.common import ledger
@@ -1119,6 +1117,7 @@ class FoldInSession:
             # all-device micro-batch: concatenate + pad on device and call
             # the jitted kernel with the resident Gramians directly — the
             # only host traffic is the [n,k] results coming back
+            self.ran = "device"
             xu, xu_valid, yi, yi_valid, values = (
                 b[0] if len(blocks) == 1 else jnp.concatenate([blk[i] for blk in blocks])
                 for i, b in enumerate(zip(*blocks))
@@ -1142,6 +1141,8 @@ class FoldInSession:
             b[0] if len(blocks) == 1 else np.concatenate([np.asarray(blk[i]) for blk in blocks])
             for i, b in enumerate(zip(*blocks))
         ]
+        # "auto" still calibrating returns the host's result
+        self.ran = "device" if backend == "device" else "host"
         return fold_in_batch(
             np.asarray(self.yty),
             np.asarray(self.xtx),

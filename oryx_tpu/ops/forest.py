@@ -362,10 +362,7 @@ def _grow_level_trees_mesh(mesh, axis_name: str):
     """shard_map'd whole-forest level pass: rows sharded over ``axis_name``
     (tree axis replicated in layout, scanned in compute), histograms
     psum'd per tree inside the scan."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rows = P(axis_name, None)
@@ -633,10 +630,7 @@ def train_forest(
                 hist_mode,
             )
             for a in (sb, gains, node_tot):
-                try:
-                    a.copy_to_host_async()
-                except AttributeError:  # pragma: no cover - older array types
-                    pass
+                a.copy_to_host_async()
             level_out.append((level_start, num_level, sf, sb, gains, node_tot))
             # exact level-wise early exit: no split at this level means
             # every deeper level is all-leaf — don't dispatch it

@@ -152,11 +152,14 @@ def bucket_geometry(
     paths so shape signatures (and the compile cache they key) never
     depend on which path packed the bucket.
 
-    The chunk size keeps the [chunk, D, k] gather workspace under
-    ``workspace_elems`` elements; ``stable_shapes`` rounds the slot count
+    The chunk size keeps the [chunk, D, k] gather workspace AND the
+    [chunk, k, k] normal equations it reduces to under ``workspace_elems``
+    elements each (at k = 250 a narrow bucket's 65536-row chunk of k x k
+    systems alone is 16 GB); ``stable_shapes`` rounds the slot count
     to a power of two so consecutive generations of a growing
     factorization reuse the compiled sweep (see ops/als.py)."""
-    chunk = max(1, workspace_elems // (width * max(features, 1)))
+    features = max(features, 1)
+    chunk = max(1, workspace_elems // (max(width, features) * features))
     chunk = 1 << (chunk.bit_length() - 1)  # floor to power of two
     chunk = min(chunk, 1 << 16)
     if stable_shapes and num_shards & (num_shards - 1) == 0:

@@ -82,6 +82,15 @@ def data_sharding(mesh: Mesh, ndim: int, axis: str = DATA_AXIS) -> NamedSharding
     return NamedSharding(mesh, P(axis, *([None] * (ndim - 1))))
 
 
+def shard_layout(arr) -> str:
+    """Where an array's data actually is: 'dev0:(r, c) dev1:(r, c) ...'
+    from its addressable shards (logged by the sharded paths, so a run can
+    show the data is not all on device 0)."""
+    return " ".join(
+        f"dev{s.device.id}:{tuple(s.data.shape)}" for s in arr.addressable_shards
+    )
+
+
 def pad_to_multiple(n: int, multiple: int) -> int:
     """Smallest m >= n with m % multiple == 0 (shard-evenly helper)."""
     return ((n + multiple - 1) // multiple) * multiple

@@ -602,6 +602,7 @@ class TopNBatcher:
                 return
             nprobe = self._group_nprobe(entries)
             queries = np.stack([e.query for e in entries])
+            _metrics.counter("serving.scan.vector.queries").inc(len(entries))
             # tiered item store: hint the cells this group will probe so
             # the store's disk->RAM promotions overlap the dispatch below
             # instead of stalling the stage-1 gather (advisory; no-op on
@@ -650,6 +651,7 @@ class TopNBatcher:
         try:
             nprobe = self._group_nprobe(entries)
             rows = np.asarray([e.row for e in entries], dtype=np.int32)
+            _metrics.counter("serving.scan.indexed.queries").inc(len(entries))
             kk = _k_bucket(max(e.k for e in entries))
             pad = _b_bucket(len(rows)) - len(rows)
             if pad:  # bucketed shapes: row 0 repeats, results discarded

@@ -32,6 +32,7 @@ from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, HTTPServer
 from urllib.parse import parse_qs, urlsplit
 
+from oryx_tpu import native
 from oryx_tpu.bus.core import get_broker
 from oryx_tpu.common import metrics, profiling, tracing
 from oryx_tpu.common.config import Config
@@ -278,6 +279,8 @@ def _healthz(ctx: ServingContext, req: Request) -> Response:
     health = ctx.health
     if health is None:
         return Response(200, {"alive": True}, content_type="application/json")
+    from oryx_tpu.parallel.distributed import claim_devices
+
     stage = ctx.admission.stage if ctx.admission is not None else _overload.STAGE_FULL
     if not health.alive:
         status = "down"
@@ -296,6 +299,10 @@ def _healthz(ctx: ServingContext, req: Request) -> Response:
         "staleness_seconds": health.staleness(),
         "live_generation": health.live_generation,
         "challenger_generation": health.challenger_generation,
+        # what this replica runs on (cached at layer start), and the native
+        # library it loaded — null means the pure-Python twins are serving
+        "device": claim_devices(),
+        "native_library": native.library_path(),
     }
     # multi-tenant serving: the model manager is a TenantServingMux and
     # each tenant has its own live generation (cli health renders the
@@ -576,9 +583,13 @@ def _model_ready(ctx: ServingContext) -> bool:
 class ServingLayer:
     def __init__(self, config: Config) -> None:
         self.config = config
-        from oryx_tpu.parallel.distributed import maybe_enable_compile_cache
+        # take the device now: a replica that cannot get the platform its
+        # launcher named fails here, not at its first request (imported
+        # here: the package import pulls in jax)
+        from oryx_tpu.parallel.distributed import claim_devices, enable_compile_cache
 
-        maybe_enable_compile_cache(config)  # device scans cache like training
+        self.device = claim_devices()
+        enable_compile_cache(config)  # device scans cache like training
         tracing.configure_from(config)
         self.port = config.get_int("oryx.serving.api.port")
         self.context_path = config.get_string("oryx.serving.api.context-path").rstrip("/")
@@ -1218,8 +1229,10 @@ class ServingLayer:
                 rt.health.mark_update()
 
     def await_termination(self, timeout: float | None = None) -> None:
-        if self._server_thread is not None:
-            self._server_thread.join(timeout)
+        """Block until close(). (Not a join of the HTTP server thread: the
+        native front has none, and `python -m oryx_tpu serving` returned
+        from here, and exited, right after start.)"""
+        self._stop_event.wait(timeout)
 
     # -- drain-aware shutdown -----------------------------------------------
 

@@ -119,3 +119,38 @@ def test_time_ordered_split():
     train, test = update.split_new_data_to_train_test(data)
     assert [r.message for r in train] == ["u,i,1.0,10", "u,i,1.0,20", "u,i,1.0,30"]
     assert [r.message for r in test] == ["u,i,1.0,40"]
+
+
+def test_published_known_items_survive_the_serving_columnar_parse(tmp_path):
+    """The batch layer's X rows and the serving layer's columnar UP parse
+    must agree on the wire form of the known-items list. They did not:
+    the rows were written '["a", "b"]' and the byte-level parse splits on
+    '","', so every known list of two or more ids past the first consumed
+    block became ONE garbage id and known items stopped being excluded.
+    The first block went through the per-record JSON path, which is why
+    small tests never saw it: here the rows are applied as a later block."""
+    from oryx_tpu.app.als.serving_model import ALSServingModelManager
+
+    data, _, _ = synthetic_data()
+    update = ALSUpdate(make_config())
+    broker = bus.get_broker("inproc://als-batch-known")
+    broker.create_topic("OryxUpdate", 1)
+    tail = broker.consumer("OryxUpdate", from_beginning=True)
+    with broker.producer("OryxUpdate") as producer:
+        update.run_update(1000, data, [], str(tmp_path / "model"), producer)
+    msgs = tail.poll(max_records=10_000, timeout=2.0)
+    expected: dict[str, set] = {}
+    for rec in data:
+        user, item = rec.message.split(",")[:2]
+        expected.setdefault(user, set()).add(item)
+    assert max(len(v) for v in expected.values()) >= 2
+
+    manager = ALSServingModelManager(C.get_default())
+    manager.consume(iter(m for m in msgs if m.key == "MODEL"))
+    ups = [m.message.encode("utf-8") for m in msgs if m.key == "UP"]
+    manager._apply_up_batch(ups)  # the columnar path of every later block
+    for user, items in expected.items():
+        assert manager.model.get_known_items(user) == items, user
+    # a spaced list from another producer still parses (json path)
+    manager._apply_up_batch([b'["X","U0",[0.0,0.0,0.0,0.0,0.0],["I1", "I2"]]'])
+    assert {"I1", "I2"} <= manager.model.get_known_items("U0")

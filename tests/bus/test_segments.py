@@ -92,3 +92,85 @@ def test_large_record_spans_roll_boundary(tmp_path):
     got = broker.consumer("T", from_beginning=True).poll(max_records=10, timeout=1.0)
     assert [m.message for m in got] == ["small-1", big, "small-2"]
     assert got[1].key == "k"
+
+
+def test_outsized_record_travels_in_a_block_of_its_own(tmp_path):
+    """A columnar block is a fixed-width array, as wide as its longest
+    record for every record: an inline MODEL document (hundreds of KB to
+    MBs) in front of thousands of factor rows made the first block of
+    every update-topic replay gigabytes (7.5 GB and 112 s for a 750 KB
+    document, PR 21). poll_block delivers such a record alone, with its
+    trace header, in order, exactly once."""
+    from oryx_tpu.bus.blockcodec import SOLO_RECORD_BYTES as _SOLO_RECORD_BYTES
+    from oryx_tpu.bus.filebus import FileBroker
+
+    broker = FileBroker(str(tmp_path / "bus"))
+    broker.create_topic("T", 1)
+    big = "M" * (3 * _SOLO_RECORD_BYTES)
+    with broker.producer("T") as p:
+        p.send_many([("UP", f"row-{i}") for i in range(50)])
+        p.send_many([("@trc", "-;ts=1"), ("MODEL", big)] + [("UP", f"row-{i}") for i in range(50, 90)])
+        p.send_many([("MODEL", big), ("MODEL", big)])
+        p.send_many([("UP", f"row-{i}") for i in range(90, 100)])
+    c = broker.consumer("T", from_beginning=True)
+    blocks = []
+    while (b := c.poll_block(max_records=1000, timeout=0.05)) is not None:
+        blocks.append(b)
+    shapes = [(len(b), b.messages.dtype.itemsize > _SOLO_RECORD_BYTES) for b in blocks]
+    assert shapes == [(50, False), (1, True), (40, False), (1, True), (1, True), (10, False)]
+    assert blocks[1].trace == "-;ts=1" and blocks[0].trace is None
+    got = [km.message for b in blocks for km in b.iter_key_messages()]
+    want = (
+        [f"row-{i}" for i in range(50)] + [big] + [f"row-{i}" for i in range(50, 90)]
+        + [big, big] + [f"row-{i}" for i in range(90, 100)]
+    )
+    assert got == want
+    assert c.positions() == {0: 104}  # 103 records + the trace control record
+
+
+def test_outsized_record_of_a_later_partition(tmp_path):
+    """With several partitions a block ends before an outsized record that
+    is not first in it; the next poll delivers it alone."""
+    from oryx_tpu.bus.blockcodec import SOLO_RECORD_BYTES as _SOLO_RECORD_BYTES
+    from oryx_tpu.bus.filebus import FileBroker
+
+    broker = FileBroker(str(tmp_path / "bus"))
+    broker.create_topic("T", 3)
+    big = "M" * (2 * _SOLO_RECORD_BYTES)
+    with broker.producer("T") as p:
+        # send() routes by key hash: find one key per partition
+        from oryx_tpu.bus.core import partition_for
+
+        key_of = {}
+        i = 0
+        while len(key_of) < 3:
+            key_of.setdefault(partition_for(f"k{i}", 3), f"k{i}")
+            i += 1
+        for part in (0, 1):
+            p.send_many([(key_of[part], f"p{part}-{j}") for j in range(5)])
+        p.send_many([(key_of[2], big), (key_of[2], "p2-after")])
+    c = broker.consumer("T", from_beginning=True)
+    seen = []
+    for _ in range(6):
+        b = c.poll_block(max_records=1000, timeout=0.05)
+        if b is None:
+            break
+        seen.append([km.message for km in b.iter_key_messages()])
+    flat = [m for blk in seen for m in blk]
+    assert sorted(flat) == sorted(
+        [f"p{part}-{j}" for part in (0, 1) for j in range(5)] + [big, "p2-after"]
+    )
+    assert [big] in seen  # alone in its block
+
+
+def test_joinable_keeps_an_outsized_record_and_its_header_apart():
+    from oryx_tpu.bus.blockcodec import SOLO_RECORD_BYTES, TRACE_LINE_PREFIX, joinable
+
+    small, big, trc = b"UP\tx", b"M" * (SOLO_RECORD_BYTES + 1), TRACE_LINE_PREFIX + b"-"
+    assert joinable([small] * 3, 7) == (3, False)
+    assert joinable([small, small, big, small], 0) == (2, True)
+    assert joinable([small, trc, big], 0) == (1, True)  # the header waits with it
+    assert joinable([big, small], 0) == (1, True)  # alone
+    assert joinable([trc, big, small], 0) == (2, True)
+    assert joinable([big, small], 4) == (0, True)  # the gathered block goes first
+    assert joinable([trc, big], 4) == (0, True)

@@ -290,3 +290,30 @@ def test_oversized_frame_rejected(tmp_path):
     with broker.producer("T") as p:
         with pytest.raises(ValueError, match="exceeds half"):
             p.send(None, "x" * (1 << 18))
+
+
+def test_outsized_record_travels_in_a_block_of_its_own(tmp_path):
+    """The rule of blockcodec.joinable, as on the file bus: a fixed-width
+    block holding a 200 KB document beside small rows would give every
+    row its width."""
+    from oryx_tpu.bus.blockcodec import SOLO_RECORD_BYTES
+
+    broker = make_broker(tmp_path)
+    broker.create_topic("T", 1)
+    big = "M" * (3 * SOLO_RECORD_BYTES)
+    with broker.producer("T") as p:
+        p.send_many(
+            [("UP", f"row-{i}") for i in range(5)] + [("MODEL", big)]
+            + [("UP", f"row-{i}") for i in range(5, 9)]
+        )
+    c = broker.consumer("T", from_beginning=True)
+    blocks = []
+    while (b := c.poll_block(max_records=1000, timeout=0.05)) is not None:
+        blocks.append(b)
+    assert [(len(b), b.messages.dtype.itemsize > SOLO_RECORD_BYTES) for b in blocks] == [
+        (5, False), (1, True), (4, False)
+    ]
+    got = [km.message for b in blocks for km in b.iter_key_messages()]
+    assert got == [f"row-{i}" for i in range(5)] + [big] + [f"row-{i}" for i in range(5, 9)]
+    assert c.positions() == {0: 10}
+    c.close()

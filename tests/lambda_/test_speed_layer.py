@@ -105,6 +105,60 @@ def test_layer_ui_port_serves_metrics(tmp_path):
             body = json.loads(r.read())
         assert body["layer"]["name"] == "speed"
         assert body["layer"]["stopped"] is False
+        # what the process runs on, and the readiness signals a feeder of
+        # the input topic needs (a new consumer group starts at latest)
+        import jax
+
+        device = {
+            "platform": "cpu",
+            "device_kind": jax.devices()[0].device_kind,
+            "n_devices": len(jax.devices()),
+        }
+        assert body["layer"]["device"] == device
+        assert body["layer"]["input_attached"] is True
+        assert body["layer"]["model_fraction_loaded"] == 0.0  # no MODEL yet
+        assert body["layer"]["batches"] == 0
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{layer.ui_port}/healthz", timeout=5
+        ) as r:
+            assert json.loads(r.read())["device"] == device
+    finally:
+        layer.close()
+
+
+def test_batch_layer_status_reports_device_and_input(tmp_path):
+    import json
+    import urllib.request
+
+    from oryx_tpu.common import config as C
+    from oryx_tpu.lambda_.batch import BatchLayer
+
+    cfg = C.get_default().with_overlay(
+        f"""
+        oryx {{
+          input-topic.broker = "inproc://batch-ui-test"
+          update-topic.broker = "inproc://batch-ui-test"
+          batch {{
+            streaming.generation-interval-sec = 3600
+            update-class = "oryx_tpu.example.batch:ExampleBatchLayerUpdate"
+            storage.data-dir = "{tmp_path}/data"
+            storage.model-dir = "{tmp_path}/model"
+            ui.port = 0
+          }}
+        }}
+        """
+    )
+    layer = BatchLayer(cfg)
+    assert layer.status() == {"input_attached": False, "generations": 0}
+    layer.prepare()
+    try:
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{layer.ui_port}/status", timeout=5
+        ) as r:
+            status = json.loads(r.read())["layer"]
+        assert status["device"]["platform"] == "cpu"
+        assert set(status["device"]) == {"platform", "device_kind", "n_devices"}
+        assert status["input_attached"] is True and status["generations"] == 0
     finally:
         layer.close()
 

@@ -1,6 +1,7 @@
 """Native C++ feature store: parity with the Python FeatureVectors and
 concurrency behavior (reference FeatureVectorsTest semantics)."""
 
+import os
 import threading
 
 import numpy as np
@@ -341,3 +342,18 @@ def test_format_update_messages_multi_sliced_buffer():
     assert sliced == whole
     p = json.loads(sliced[199])
     assert p[1] == "U199" and p[3] == knowns[199]
+
+
+def test_library_is_keyed_by_host_cpu_as_well_as_sources(monkeypatch):
+    """-march=native code from another machine must not be loaded: the
+    artefact name changes with the CPU model/flags, so a _build/ directory
+    that travelled with the tree is rebuilt, not reused."""
+    from oryx_tpu import native
+
+    here = native._library_target()
+    assert here == native._library_target()  # stable on one host
+    assert b"flags" in native._host_cpu()
+    monkeypatch.setattr(native, "_host_cpu", lambda: b"model name: other\nflags: sse2")
+    elsewhere = native._library_target()
+    assert elsewhere != here
+    assert os.path.dirname(elsewhere) == os.path.dirname(here)

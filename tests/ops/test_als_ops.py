@@ -478,3 +478,52 @@ def test_partitioned_set_gramians_swaps_every_slice():
         assert part.session(s).xtx is xtx2
     with pytest.raises(ValueError):
         als_ops.PartitionedFoldInSession(np.eye(3), np.eye(3), False, 0)
+
+
+def test_auto_fold_backend_propagates_a_device_failure(monkeypatch):
+    """The auto calibration times host against device on the first large
+    batch. A device error there used to elect the host silently; it must
+    surface, or a layer that lost its device looks healthy."""
+    from oryx_tpu.ops import als as als_ops
+
+    gen = np.random.default_rng(0)
+    n, k = 4096, 128  # n*k >= 500_000: large enough to calibrate
+    y = gen.standard_normal((400, k))
+    yty = y.T @ y
+    xu = gen.standard_normal((n, k)).astype(np.float32)
+    yi = gen.standard_normal((n, k)).astype(np.float32)
+    valid = np.ones(n, bool)
+    values = np.ones(n, np.float32)
+
+    def boom(*a, **kw):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(als_ops, "_auto_fold_choice", None)
+    monkeypatch.setattr(als_ops, "_fold_in_batch_jit", boom)
+    with pytest.raises(RuntimeError, match="device lost"):
+        als_ops.fold_in_batch(yty, yty, xu, valid, yi, valid, values, True, backend="auto")
+    assert als_ops._auto_fold_choice is None
+    with pytest.raises(RuntimeError, match="device lost"):
+        als_ops.fold_in_batch(yty, yty, xu, valid, yi, valid, values, True, backend="device")
+
+
+def test_fold_session_says_which_side_ran(monkeypatch):
+    from oryx_tpu.ops import als as als_ops
+
+    monkeypatch.setattr(als_ops, "_auto_fold_choice", None)
+
+    gen = np.random.default_rng(1)
+    n, k = 300, 8
+    y = gen.standard_normal((50, k))
+    yty = y.T @ y
+    xu = gen.standard_normal((n, k)).astype(np.float32)
+    yi = gen.standard_normal((n, k)).astype(np.float32)
+    valid = np.ones(n, bool)
+    values = np.ones(n, np.float32)
+    for backend, ran in (("device", "device"), ("host", "host"), ("auto", "host")):
+        # auto below the calibration size resolves to the host
+        session = als_ops.FoldInSession(yty, yty, True, backend=backend)
+        assert session.ran is None
+        session.add_block(xu, valid, yi, valid, values)
+        session.solve()
+        assert session.ran == ran

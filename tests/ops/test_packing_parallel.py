@@ -181,3 +181,21 @@ def test_bounded_rss_streaming_5m():
         f"packing RSS grew {grew_mb:.0f} MB "
         f"(outputs {outputs_mb:.0f} MB) — streaming bound broken"
     )
+
+
+def test_chunk_bounds_the_normal_equations_too():
+    """At 250 features a narrow bucket's chunk used to be sized by its
+    [chunk, 8, 250] gather workspace alone (65536 rows): the [chunk, 250,
+    250] f32 systems it reduces to are then 16 GB, past one chip's HBM.
+    Both workspaces stay under the budget; wide buckets are unchanged."""
+    from oryx_tpu.ops.packing import bucket_geometry
+
+    budget = 1 << 27
+    for width in (8, 64, 256, 4096):
+        n, chunk = bucket_geometry(1_000_000, width, 1, budget, 250, True)
+        assert chunk * width * 250 <= budget
+        assert chunk * 250 * 250 <= budget
+        assert n % chunk == 0
+    # width >= features: the gather workspace was, and is, the bound
+    assert bucket_geometry(1_000_000, 4096, 1, budget, 250, True)[1] == 128
+    assert bucket_geometry(1_000_000, 8, 1, budget, 250, True)[1] == 2048

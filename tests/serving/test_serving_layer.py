@@ -176,3 +176,53 @@ def test_head_routes_like_get_with_empty_body():
 def test_username_without_password_refused():
     with pytest.raises(ValueError):
         ServingLayer(make_config("inproc://serve-badauth", **{"api.user-name": '"u"'}))
+
+
+def test_healthz_reports_device_and_native_library():
+    """/healthz says what the replica runs on (platform, device_kind,
+    n_devices, as logged at start) and which native library it loaded;
+    null there means the pure-Python twins are serving."""
+    import jax
+
+    from oryx_tpu import native
+
+    layer = ServingLayer(make_config("inproc://serve-device"))
+    layer.start()
+    try:
+        status, body, _ = http("GET", f"http://127.0.0.1:{layer.port}/healthz")
+        health = json.loads(body)
+        assert status == 200
+        assert health["device"] == {
+            "platform": "cpu",
+            "device_kind": jax.devices()[0].device_kind,
+            "n_devices": len(jax.devices()),
+        }
+        assert health["device"] == layer.device
+        assert health["native_library"] == native.library_path()
+        if native.native_enabled() and native.get_library() is not None:
+            assert health["native_library"].endswith(".so")
+    finally:
+        layer.close()
+
+
+def test_await_termination_blocks_until_close_with_either_front():
+    """`python -m oryx_tpu serving` is start() + await_termination(): with
+    the native front there is no HTTP server thread to join, and the
+    process used to exit right after start."""
+    import threading
+
+    for native_enabled in ("false", "auto"):
+        layer = ServingLayer(
+            make_config(f"inproc://serve-await-{native_enabled}",
+                        **{"native.enabled": native_enabled})
+        )
+        layer.start()
+        try:
+            t0 = time.monotonic()
+            layer.await_termination(timeout=0.3)
+            assert time.monotonic() - t0 >= 0.25, native_enabled
+            threading.Timer(0.2, layer.close).start()
+            layer.await_termination(timeout=10)
+            assert time.monotonic() - t0 < 5
+        finally:
+            layer.close()

@@ -672,6 +672,9 @@ def serve_replica(work_dir: str, slot_dir: str) -> int:
     return 0
 
 
+REPLICA_PLATFORM = "cpu"
+
+
 class ReplicaProcess:
     """One serving replica as a child process: spawn, readiness, SIGKILL,
     respawn — the crash campaign's unit of failure."""
@@ -690,6 +693,10 @@ class ReplicaProcess:
         (self.slot_dir / "port").unlink(missing_ok=True)
         env = dict(os.environ)
         env["PYTHONPATH"] = str(REPO_ROOT) + os.pathsep + env.get("PYTHONPATH", "")
+        # several replicas share one host and a chip has one owner: the
+        # crash campaign measures recovery, not the device, so every
+        # replica is a host-only process (the report says so)
+        env["JAX_PLATFORMS"] = REPLICA_PLATFORM
         self.proc = subprocess.Popen(
             [
                 sys.executable, str(Path(__file__).resolve()),
@@ -883,6 +890,7 @@ def run_crash_campaign(
     recovery = [e["recovery_seconds"] for e in fleet.crash_events]
     return {
         "replicas": replicas,
+        "replica_platform": REPLICA_PLATFORM,
         "crashes": len(fleet.crash_events),
         "recovery_seconds": recovery,
         "recovery_budget_s": recovery_budget_s,

@@ -10,7 +10,7 @@ export and model promotion — recording per-phase wall and peak RSS.
 Usage:
     python tools/scale_ingest_benchmark.py [--ratings 100000000]
         [--users 2000000] [--items 200000] [--rank 16] [--iterations 1]
-        [--out tools/scale_ingest_evidence.txt]
+        [--out evidence.txt]
 
 The micro-batches and model land under --workdir (a temp dir by
 default) and are deleted afterwards unless --keep.
@@ -23,7 +23,7 @@ and recording throughput + the live RSS curve:
 
     python tools/scale_ingest_benchmark.py --pack-bench \
         --ratings 50000000 --users 2500000 --items 250000 \
-        --workers-list 1,2,4 --out tools/scale_ingest_evidence.txt
+        --workers-list 1,2,4 --out evidence.txt
 """
 
 from __future__ import annotations

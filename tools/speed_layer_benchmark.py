@@ -409,7 +409,9 @@ def main() -> None:
                         "--users", str(args.users),
                         "--items", str(args.items),
                         "--nparts", str(nparts),
-                    ]
+                    ],
+                    # producers only write the bus: host-only children
+                    env={**os.environ, "JAX_PLATFORMS": "cpu"},
                 )
                 for _ in range(args.producers)
             ]
@@ -494,7 +496,8 @@ def main() -> None:
             else "producers replay a pre-rendered record list"
         )
         mode = (
-            f"live: {args.producers} producer process(es) racing the layer "
+            f"live: {args.producers} host-only (JAX_PLATFORMS=cpu) "
+            f"producer process(es) racing the layer "
             f"for {args.seconds:.0f}s windows; {split}; layer core pays the "
             f"full parse->fold->publish path"
             + (f"; {args.shards}-shard pipeline on"
