@@ -32,7 +32,7 @@ from oryx_tpu.app import pmml as app_pmml
 from oryx_tpu.app.als.common import apply_up_lines, consume_blocks_columnar
 from oryx_tpu.bus.core import KeyMessage
 from oryx_tpu.common.config import Config
-from oryx_tpu.common import metrics, tracing
+from oryx_tpu.common import metrics, profiling, tracing
 from oryx_tpu.common.lang import ReadWriteLock
 from oryx_tpu.common.text import read_json
 from oryx_tpu.common.vectormath import Solver, get_solver
@@ -368,6 +368,7 @@ class ALSServingModel(ServingModel):
                             self._y_matrix = topn_ops.upload(mat, dtype=dtype)
                     else:
                         self._y_matrix = None
+                    profiling.record_device_memory_peak()
                     if self.lsh is not None:
                         self._y_host = mat
                         self._y_partitions = (
@@ -453,6 +454,7 @@ class ALSServingModel(ServingModel):
                 )
             else:
                 staged, cap = None, 0
+            profiling.record_device_memory_peak()
             with self._cache_lock:
                 if self._x_epoch != epoch:
                     return  # rotation landed mid-build: discard the snapshot

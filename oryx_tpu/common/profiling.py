@@ -11,6 +11,11 @@ profile plugin or xprof; without one the context manager is a no-op
 
 Step-time breakdowns are separate: layers wrap their phases in
 ``metrics.timed`` histograms, exported at /metrics.
+
+``annotate`` puts a host span into whatever profiler trace is recording
+(``capture`` below, or the benchmark's), on the same timeline as the
+device's operations; ``record_device_memory_peak`` publishes the
+allocator's high-water mark as a gauge.
 """
 
 from __future__ import annotations
@@ -20,6 +25,55 @@ import logging
 import time
 
 log = logging.getLogger(__name__)
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+_memory_peak_set = False  # a staging site has read a peak from this process's device
+
+
+def annotate(name: str, **attrs):
+    """Context manager marking ``name`` (with ``attrs`` as its stats) on
+    the host plane of a recording profiler trace. With no trace recording
+    it costs the construction of the object (about a microsecond) and
+    nothing is kept; without JAX it is a null context."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        except ImportError:
+            _trace_annotation = _null_annotation
+    return _trace_annotation(name, **attrs)
+
+
+def _null_annotation(name: str, **attrs):
+    return contextlib.nullcontext()
+
+
+def record_device_memory_peak(refresh: bool = False) -> None:
+    """Set the gauge ``device.memory.peak-bytes`` from the first local
+    device's allocator; left unset where the backend reports none (the
+    CPU). The staging sites call it right after an upload, when the
+    process owns its device. With ``refresh`` (the ``/metrics`` scrape) it
+    reads only where a staging site has set the gauge before, so a scrape
+    never initialises a backend. A gauge must not fail a model load or a
+    scrape: a backend that raises is logged, nothing is set."""
+    global _memory_peak_set
+    if refresh and not _memory_peak_set:
+        return
+    try:
+        import jax
+
+        stats = jax.local_devices()[0].memory_stats() or {}
+    except Exception:
+        log.warning("device memory stats unavailable", exc_info=True)
+        return
+    peak = stats.get("peak_bytes_in_use")
+    if peak is not None:
+        from oryx_tpu.common import metrics
+
+        metrics.registry.gauge("device.memory.peak-bytes").set(int(peak))
+        _memory_peak_set = True
 
 
 @contextlib.contextmanager

@@ -621,6 +621,7 @@ def _streaming_topk_impl(
             ],
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
+            name="oryx_topn_candidates",
         )(q, mat_t, aux)
         allv = jnp.moveaxis(vals_c, 0, 1).reshape(b, grid * m)
         alli = jnp.moveaxis(idx_c, 0, 1).reshape(b, grid * m)
@@ -656,6 +657,7 @@ def _streaming_topk_impl(
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="oryx_topn_scan",
     )(q, mat_t, aux)
     return finish(vals, idxs)
 

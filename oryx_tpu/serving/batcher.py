@@ -41,9 +41,17 @@ dispatch many small power-of-two-padded groups (each mostly padding).
 Instead the dispatcher keeps absorbing arrivals in 1 ms waits while it
 is blocked anyway, so one full batch goes out where several fragments
 would have — ``serving.batcher.coalesced`` counts the requests that
-piggybacked this way, and ``serving.batcher.queue_depth`` /
-``inflight`` / ``batch_size`` gauges expose the live scheduler state
-through ``oryx_tpu.common.metrics``.
+piggybacked this way.
+
+The unit of work is the PASS: one device dispatch serving one coalesced
+group. Every pass is on the record three ways at the same boundaries
+(docs/observability.md): always-on counters and histograms
+(``serving.batcher.passes``, ``pass.rows`` / ``pass.padded-rows``,
+``pass.inflight-depth-sum``, ``queue-wait.seconds``, ``pass.seconds``,
+``deliver.seconds``), a ``serving.pass`` span in the tracer's ring when a
+request of the pass is sampled, and ``serving.pass.submit`` /
+``serving.pass.wait`` annotations on the profiler's timeline while a
+device trace records. None of it feeds a scheduling decision.
 """
 
 from __future__ import annotations
@@ -59,7 +67,7 @@ import numpy as np
 
 from collections import deque
 
-from oryx_tpu.common import tracing
+from oryx_tpu.common import profiling, tracing
 from oryx_tpu.common.metrics import registry as _metrics
 from oryx_tpu.ops import topn as topn_ops
 from oryx_tpu.serving.overload import active_probe_fraction
@@ -143,7 +151,42 @@ def _b_bucket(n: int) -> int:
     return max(8, 1 << (int(n) - 1).bit_length())
 
 
-def _record_entry_spans(e: _Entry, t_done: float) -> None:
+@dataclass
+class _Pass:
+    """One device dispatch on its way from the dispatcher to the completer."""
+
+    handle: object
+    entries: list[_Entry]
+    t_submit: float  # perf_counter when the host had submitted it
+    seq: int  # per-batcher number of the dispatch attempt
+    padded_rows: int  # rows the device is given
+    k_bucket: int
+    inflight: int  # passes in flight once this one held its slot
+
+
+def _record_pass_spans(p: _Pass, t_done: float) -> None:
+    """The sampled requests of one pass, and the pass itself once: a
+    ``serving.pass`` span (submit -> results back) beside the spans of the
+    first sampled request. A pass serves requests of several traces, so
+    the others find it by the ``pass`` attribute their ``serving.scan``
+    span carries, not by parentage."""
+    sampled = [e for e in p.entries if e.trace_ctx is not None]
+    if not sampled:
+        return
+    for e in sampled:
+        _record_entry_spans(e, t_done, p.seq)
+    first = sampled[0]
+    tracing.record_span(
+        "serving.pass", first.trace_ctx.child(), first.trace_ctx.span_id,
+        first.t_submit, t_done - first.t_submit,
+        {
+            "pass": p.seq, "rows": len(p.entries), "padded_rows": p.padded_rows,
+            "k_bucket": p.k_bucket, "inflight": p.inflight,
+        },
+    )
+
+
+def _record_entry_spans(e: _Entry, t_done: float, pass_seq: int) -> None:
     """One request's batching lifecycle as three sibling spans under the
     request span — explicit timestamps because the phases were measured by
     three different threads, none of which carries the ambient context:
@@ -152,20 +195,20 @@ def _record_entry_spans(e: _Entry, t_done: float) -> None:
                              inflight-slot wait: backpressure is queueing)
         serving.assemble     grouping / padding / device submit
         serving.scan         device scan (submit -> results back); carries
-                             the IVF probe count when the scanned matrix
-                             is an IVF index
+                             its pass's number, and the IVF probe count
+                             when the scanned matrix is an IVF index
     """
     ctx = e.trace_ctx
-    attrs = None
+    attrs = {"pass": pass_seq}
     if e.nprobe_applied is not None:
-        attrs = {"nprobe": e.nprobe_applied, "probe_fraction": e.probe_fraction}
+        attrs.update(nprobe=e.nprobe_applied, probe_fraction=e.probe_fraction)
     else:
         resolve_nprobe = getattr(e.uploaded, "resolve_nprobe", None)
         if resolve_nprobe is not None:
             try:
-                attrs = {"nprobe": int(resolve_nprobe())}
+                attrs["nprobe"] = int(resolve_nprobe())
             except Exception:
-                attrs = None
+                pass
     tracing.record_span(
         "serving.queue-wait", ctx.child(), ctx.span_id,
         e.t_enqueue, e.t_dispatch - e.t_enqueue,
@@ -355,6 +398,17 @@ class TopNBatcher:
         # every entry is untenanted (docs/multi-tenancy.md)
         self._queue = _FairQueue(tenant_weights, fair_quantum)
         self._pending: queue.Queue = queue.Queue()
+        # the pass on the record: handles taken once, observed per pass
+        # (per request for the queue wait); none is read back here
+        self._pass_seq = 0  # dispatcher thread only
+        self._m_queue_wait = _metrics.histogram("serving.batcher.queue-wait.seconds")
+        self._m_passes = _metrics.counter("serving.batcher.passes")
+        self._m_pass_rows = _metrics.counter("serving.batcher.pass.rows")
+        self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
+        self._m_pass_depth_sum = _metrics.counter("serving.batcher.pass.inflight-depth-sum")
+        self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
+        self._m_pass_seconds = _metrics.histogram("serving.batcher.pass.seconds")
+        self._m_deliver_seconds = _metrics.histogram("serving.batcher.deliver.seconds")
         # inflight tracked under a Condition (not a Semaphore) so the
         # adaptive cap can move while dispatches are blocked on it
         self._flight_cv = threading.Condition()
@@ -476,9 +530,7 @@ class TopNBatcher:
             batch.append(e)
         if coalesced:
             _metrics.counter("serving.batcher.coalesced").inc(coalesced)
-        _metrics.gauge("serving.batcher.queue_depth").set(self._queue.qsize())
         _metrics.gauge("serving.batcher.queue.depth").set(self._queue.qsize())
-        _metrics.gauge("serving.batcher.batch_size").set(len(batch))
         return batch
 
     def _dispatch_loop(self) -> None:
@@ -501,12 +553,15 @@ class TopNBatcher:
             for (_, cosine, _xk, _pf), entries in groups.items():
                 self._submit_group(entries, cosine)
 
-    def _acquire_slot(self) -> None:
+    def _acquire_slot(self) -> int:
+        """Block until an inflight slot is free, take it, and return the
+        number of passes now in flight (this one included)."""
         with self._flight_cv:
             while self._inflight_count >= self._inflight_cap:
                 self._flight_cv.wait(timeout=1.0)
             self._inflight_count += 1
             _metrics.gauge("serving.batcher.inflight").set(self._inflight_count)
+            return self._inflight_count
 
     def _release_slot(self, latency_s: float | None = None) -> None:
         with self._flight_cv:
@@ -531,9 +586,12 @@ class TopNBatcher:
         )
         _metrics.gauge("serving.batcher.dispatch_ewma_ms").set(self._ewma_ms)
         if self._adaptive_inflight:
-            self._inflight_cap = int(
+            cap = int(
                 min(max(LATENCY_BUDGET_MS / max(self._ewma_ms, 1e-3) + 2, MIN_INFLIGHT), MAX_INFLIGHT)
             )
+            if cap != self._inflight_cap:
+                self._inflight_cap = cap
+                self._m_cap_changes.inc()
         if self._adaptive_batch:
             if self._ewma_ms > LATENCY_BUDGET_MS and self.max_batch > MIN_ADAPTIVE_BATCH:
                 self.max_batch //= 2
@@ -562,9 +620,15 @@ class TopNBatcher:
 
     def _observe_queue_wait(self, entries: list[_Entry]) -> None:
         """EWMA the worst enqueue->dispatch wait of the group — the
-        admission controller's primary pressure signal."""
+        admission controller's primary pressure signal — and put every
+        entry's own wait, between the same instants, into the histogram."""
         now = time.monotonic()
-        wait_ms = max(now - e.t_q for e in entries) * 1000.0
+        worst = 0.0
+        for e in entries:
+            wait = now - e.t_q
+            self._m_queue_wait.observe(wait)
+            worst = max(worst, wait)
+        wait_ms = worst * 1000.0
         with self._flight_cv:
             self._queue_wait_ewma_ms = (
                 WAIT_EWMA_ALPHA * wait_ms
@@ -589,91 +653,100 @@ class TopNBatcher:
         return ewma * 0.5 ** ((idle - WAIT_DECAY_GRACE_S) / WAIT_DECAY_HALF_LIFE_S)
 
     def _submit_group(self, entries: list[_Entry], cosine: bool) -> None:
-        self._acquire_slot()
+        inflight = self._acquire_slot()
         # queue-wait ends here: the entry has a dispatcher AND an inflight
         # slot (slot contention is backpressure, i.e. still queueing)
         self._observe_queue_wait(entries)
         for e in entries:
             if e.trace_ctx is not None:
                 e.t_dispatch = time.time()
+        n = len(entries)
+        indexed = entries[0].row is not None
+        if indexed or n <= self.MULTI_THRESHOLD:
+            padded = _b_bucket(n)
+        else:  # the fused vector path: _group_pad's multiple of the scan batch
+            padded = -(-n // self.MULTI_THRESHOLD) * self.MULTI_THRESHOLD
+        self._pass_seq += 1  # numbers dispatch attempts: a failed one leaves a gap
+        seq = self._pass_seq
         try:
-            if entries[0].row is not None:
-                self._submit_indexed(entries, cosine)
-                return
-            nprobe = self._group_nprobe(entries)
-            queries = np.stack([e.query for e in entries])
-            _metrics.counter("serving.scan.vector.queries").inc(len(entries))
-            # tiered item store: hint the cells this group will probe so
-            # the store's disk->RAM promotions overlap the dispatch below
-            # instead of stalling the stage-1 gather (advisory; no-op on
-            # flat-plane indexes)
-            prefetch = getattr(entries[0].uploaded, "prefetch_for_queries", None)
-            if prefetch is not None:
-                try:
-                    prefetch(queries, nprobe=nprobe, cosine=cosine)
-                except Exception:  # never let a hint fail a dispatch
-                    pass
-            kk = _k_bucket(max(e.k for e in entries))
-            if len(entries) > self.MULTI_THRESHOLD:
-                # fused multi-scan: pads to a multiple of scan_batch
-                # internally, so compiled shapes stay one-per-K
-                handle = topn_ops.submit_top_k_multi(
-                    entries[0].uploaded,
-                    queries,
-                    kk,
-                    cosine=cosine,
-                    scan_batch=self.MULTI_THRESHOLD,
-                    nprobe=nprobe,
-                )
-            else:
-                pad_rows = _b_bucket(len(entries)) - len(entries)
-                if pad_rows:
-                    queries = np.concatenate(
-                        [queries, np.zeros((pad_rows, queries.shape[1]), queries.dtype)]
-                    )
-                handle = topn_ops.submit_top_k(
-                    entries[0].uploaded, queries, kk, cosine=cosine, nprobe=nprobe
-                )
+            with profiling.annotate(
+                "serving.pass.submit", **{"pass": seq, "rows": n, "padded_rows": padded}
+            ):
+                kk = _k_bucket(max(e.k for e in entries))
+                nprobe = self._group_nprobe(entries)
+                if indexed:
+                    handle = self._submit_indexed(entries, cosine, kk, nprobe, padded)
+                else:
+                    handle = self._submit_vectors(entries, cosine, kk, nprobe, padded)
             for e in entries:
                 if e.trace_ctx is not None:
                     e.t_submit = time.time()
-            self._pending.put((handle, entries, time.perf_counter()))
+            self._pending.put(
+                _Pass(handle, entries, time.perf_counter(), seq, padded, kk, inflight)
+            )
         except BaseException as exc:  # deliver the failure to the waiters
             self._release_slot()
             for e in entries:
                 e.error = exc
                 e.done.set()
+            return
+        self._m_passes.inc()
+        self._m_pass_rows.inc(n)
+        self._m_pass_padded_rows.inc(padded)
+        self._m_pass_depth_sum.inc(inflight)
 
-    def _submit_indexed(self, entries: list[_Entry], cosine: bool) -> None:
-        """Dispatch one coalesced index-entry group (caller holds the
-        inflight slot; errors deliver to waiters exactly like the vector
-        path)."""
-        try:
-            nprobe = self._group_nprobe(entries)
-            rows = np.asarray([e.row for e in entries], dtype=np.int32)
-            _metrics.counter("serving.scan.indexed.queries").inc(len(entries))
-            kk = _k_bucket(max(e.k for e in entries))
-            pad = _b_bucket(len(rows)) - len(rows)
-            if pad:  # bucketed shapes: row 0 repeats, results discarded
-                rows = np.concatenate([rows, np.zeros(pad, np.int32)])
-            handle = topn_ops.submit_top_k_multi_indexed(
+    def _submit_vectors(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
+        """Dispatch one coalesced group of uploaded query vectors (caller
+        holds the inflight slot and delivers errors); returns the handle."""
+        queries = np.stack([e.query for e in entries])
+        _metrics.counter("serving.scan.vector.queries").inc(len(entries))
+        # tiered item store: hint the cells this group will probe so
+        # the store's disk->RAM promotions overlap the dispatch below
+        # instead of stalling the stage-1 gather (advisory; no-op on
+        # flat-plane indexes)
+        prefetch = getattr(entries[0].uploaded, "prefetch_for_queries", None)
+        if prefetch is not None:
+            try:
+                prefetch(queries, nprobe=nprobe, cosine=cosine)
+            except Exception:  # never let a hint fail a dispatch
+                pass
+        if len(entries) > self.MULTI_THRESHOLD:
+            # fused multi-scan: pads to a multiple of scan_batch
+            # internally, so compiled shapes stay one-per-K
+            return topn_ops.submit_top_k_multi(
                 entries[0].uploaded,
-                entries[0].x_dev,
-                rows,
+                queries,
                 kk,
                 cosine=cosine,
                 scan_batch=self.MULTI_THRESHOLD,
                 nprobe=nprobe,
             )
-            for e in entries:
-                if e.trace_ctx is not None:
-                    e.t_submit = time.time()
-            self._pending.put((handle, entries, time.perf_counter()))
-        except BaseException as exc:  # deliver the failure to the waiters
-            self._release_slot()
-            for e in entries:
-                e.error = exc
-                e.done.set()
+        pad_rows = padded - len(entries)
+        if pad_rows:
+            queries = np.concatenate(
+                [queries, np.zeros((pad_rows, queries.shape[1]), queries.dtype)]
+            )
+        return topn_ops.submit_top_k(
+            entries[0].uploaded, queries, kk, cosine=cosine, nprobe=nprobe
+        )
+
+    def _submit_indexed(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
+        """Dispatch one coalesced index-entry group (caller holds the
+        inflight slot and delivers errors); returns the handle."""
+        rows = np.asarray([e.row for e in entries], dtype=np.int32)
+        _metrics.counter("serving.scan.indexed.queries").inc(len(entries))
+        pad = padded - len(rows)
+        if pad:  # bucketed shapes: row 0 repeats, results discarded
+            rows = np.concatenate([rows, np.zeros(pad, np.int32)])
+        return topn_ops.submit_top_k_multi_indexed(
+            entries[0].uploaded,
+            entries[0].x_dev,
+            rows,
+            kk,
+            cosine=cosine,
+            scan_batch=self.MULTI_THRESHOLD,
+            nprobe=nprobe,
+        )
 
     # -- completer -----------------------------------------------------------
 
@@ -682,11 +755,14 @@ class TopNBatcher:
             item = self._pending.get()
             if item is None:
                 return
-            handle, entries, t_submit = item
+            entries = item.entries
             latency = None
+            t_ready = 0.0
             try:
-                idx, vals = handle.result()
-                latency = time.perf_counter() - t_submit
+                with profiling.annotate("serving.pass.wait", **{"pass": item.seq}):
+                    idx, vals = item.handle.result()
+                t_ready = time.perf_counter()
+                latency = t_ready - item.t_submit
                 for row, e in enumerate(entries):
                     e.idx = idx[row, : e.k]
                     e.vals = vals[row, : e.k]
@@ -695,11 +771,12 @@ class TopNBatcher:
                     e.error = exc
             finally:
                 self._release_slot(latency)
-                t_done = time.time()
+                _record_pass_spans(item, time.time())
                 for e in entries:
-                    if e.trace_ctx is not None:
-                        _record_entry_spans(e, t_done)
                     e.done.set()
+                if latency is not None:
+                    self._m_pass_seconds.observe(latency)
+                    self._m_deliver_seconds.observe(time.perf_counter() - t_ready)
 
     # -- lifecycle -----------------------------------------------------------
 

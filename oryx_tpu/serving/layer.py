@@ -339,6 +339,7 @@ def _metrics(ctx: ServingContext, req: Request) -> Response:
     if ledger.enabled():
         # resources.<kind>.live gauges: the leak alarm for week-long runs
         ledger.ledger.refresh()
+    profiling.record_device_memory_peak(refresh=True)
     snap = metrics.registry.snapshot()
     if ctx.instance_metrics is not None:
         # instance-scoped values shadow the process-global ones: in a
@@ -1642,7 +1643,8 @@ def _dispatch_parsed(layer, ctx, method: str, raw_path: str, headers, body,
     # parent, joined by trace id); header-less requests roll the
     # root sampling dice. Untraced requests skip all of it.
     incoming = tracing.parse_traceparent(headers.get("traceparent"))
-    with _tenancy.tenant_scope(tenant):
+    # the same interval on the profiler's timeline while a trace records
+    with _tenancy.tenant_scope(tenant), profiling.annotate("serving.request", path=path):
         if incoming is not None and incoming.sampled:
             with tracing.use(incoming):
                 with tracing.span("serving.request", attrs=attrs) as sp:

@@ -144,3 +144,126 @@ def test_large_group_routes_through_fused_multi(monkeypatch):
         assert calls["multi"] >= 1
     finally:
         b.close()
+
+
+def _pass_record() -> dict:
+    """What the batcher's pass instruments hold now (they live in the
+    process-global registry, so tests read deltas)."""
+    snap = batcher_mod._metrics.snapshot()
+    name = "serving.batcher."
+    return {
+        "passes": snap[name + "passes"]["value"],
+        "rows": snap[name + "pass.rows"]["value"],
+        "padded": snap[name + "pass.padded-rows"]["value"],
+        "waits": snap[name + "queue-wait.seconds"].get("count", 0),
+        "pass_seconds": snap[name + "pass.seconds"].get("count", 0),
+        "deliveries": snap[name + "deliver.seconds"].get("count", 0),
+        "depth_sum": snap[name + "pass.inflight-depth-sum"]["value"],
+    }
+
+
+@pytest.mark.parametrize("indexed", [False, True], ids=["vectors", "indexed"])
+def test_every_pass_is_on_the_record_and_the_counts_agree(indexed):
+    """N requests through a batcher: one queue-wait observation a request,
+    one pass.seconds / deliver.seconds observation a pass, rows = N, and the device is never given fewer rows than asked."""
+    y, up = _make(n=400, kf=8, seed=11)
+    queries = np.random.default_rng(12).standard_normal((48, 8)).astype(np.float32)
+    x_dev = topn_ops.upload_queries(queries)
+    b = TopNBatcher(max_batch=16)
+    before = _pass_record()
+    results = {}
+
+    def worker(j):
+        if indexed:
+            results[j] = b.score_indexed(up, x_dev, j, 5)
+        else:
+            results[j] = b.score(up, queries[j], 5)
+
+    threads = [threading.Thread(target=worker, args=(j,)) for j in range(len(queries))]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        b.close()  # joins the completer: every pass has been observed
+    assert len(results) == len(queries)
+    for j, (idx, _vals) in results.items():
+        np.testing.assert_array_equal(idx, topn_ops.top_k_scores(up, queries[j], 5)[0])
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    n = len(queries)
+    assert got["rows"] == n and got["waits"] == n
+    assert 1 <= got["passes"] <= n
+    assert got["pass_seconds"] == got["deliveries"] == got["passes"]
+    assert got["padded"] >= got["rows"] and got["padded"] % 8 == 0
+    # each pass took a slot, and the cap never passes 32: the mean depth at submit
+    assert got["passes"] <= got["depth_sum"] <= 32 * got["passes"]
+
+
+def test_fused_vector_path_counts_the_multiple_of_the_scan_batch():
+    """Above MULTI_THRESHOLD the vector path pads to a multiple of the
+    scan batch (ops.topn._group_pad), not to a power of two."""
+    y, up = _make(n=300, kf=8, seed=13)
+    b = TopNBatcher()
+    b.MULTI_THRESHOLD = 8
+    queries = np.random.default_rng(14).standard_normal((20, 8)).astype(np.float32)
+    entries = [batcher_mod._Entry(up, q, 4, False) for q in queries]
+    before = _pass_record()
+    try:
+        b._submit_group(entries, False)
+        for e in entries:
+            assert e.done.wait(30) and e.error is None
+    finally:
+        b.close()
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    assert (got["passes"], got["rows"], got["padded"]) == (1, 20, 24)
+
+
+def test_a_dispatch_that_raises_releases_its_slot_and_counts_no_pass(monkeypatch):
+    y, up = _make(n=100, kf=8, seed=15)
+
+    def boom(*a, **k):
+        raise RuntimeError("device refused the dispatch")
+
+    monkeypatch.setattr(batcher_mod.topn_ops, "submit_top_k", boom)
+    b = TopNBatcher()
+    before = _pass_record()
+    try:
+        with pytest.raises(RuntimeError, match="device refused"):
+            b.score(up, np.ones(8, np.float32), 3)
+        assert b._inflight_count == 0
+        monkeypatch.undo()
+        # the slot came back: the next request is served
+        idx, _ = b.score(up, np.ones(8, np.float32), 3)
+        assert len(idx) == 3
+    finally:
+        b.close()
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    # the failed attempt waited in the queue like any other and was no pass
+    assert got["waits"] == 2 and got["passes"] == 1 and got["rows"] == 1
+    assert got["pass_seconds"] == 1
+
+
+def test_inflight_cap_changes_are_counted_when_the_cap_moves():
+    b = TopNBatcher()
+    try:
+        counter = b._m_cap_changes
+        start = counter.value
+        with b._flight_cv:
+            b._observe_latency(10.0)  # 50 / 10 + 2 = 7: a step from the initial 4
+            assert b._inflight_cap == 7 and counter.value == start + 1
+            b._observe_latency(10.0)  # same cap: no step
+            assert counter.value == start + 1
+            for _ in range(40):
+                b._observe_latency(100.0)  # EWMA -> 100 ms: 50 / 100 + 2 = 2
+            assert b._inflight_cap == 2 and counter.value > start + 1
+    finally:
+        b.close()
+    pinned = TopNBatcher(max_inflight=3)
+    try:
+        start = pinned._m_cap_changes.value
+        with pinned._flight_cv:
+            pinned._observe_latency(1.0)
+        assert pinned._inflight_cap == 3 and pinned._m_cap_changes.value == start
+    finally:
+        pinned.close()

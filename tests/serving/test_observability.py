@@ -304,3 +304,50 @@ def test_batcher_records_request_lifecycle_spans():
     finally:
         b2.close()
     assert len(tracing.spans()) == before
+
+
+def test_a_sampled_request_finds_its_pass_by_attribute():
+    """One `serving.pass` span a sampled pass, and the request's
+    `serving.scan` span names the same pass number: a pass is shared by
+    several traces, so the join is the attribute, not parentage."""
+    import threading
+
+    from oryx_tpu.ops import topn as topn_ops
+    from oryx_tpu.serving.batcher import TopNBatcher
+
+    y = np.random.default_rng(1).standard_normal((200, 8), dtype=np.float32)
+    up = topn_ops.upload(y, streaming=False)
+    b = TopNBatcher()
+    roots = [tracing.sample_root() for _ in range(6)]
+    gate = threading.Event()
+    take = b._take_batch
+    b._take_batch = lambda: gate.wait(5) and take()  # let all six coalesce
+
+    def ask(ctx):
+        with tracing.use(ctx):
+            b.score(up, np.arange(8, dtype=np.float32), 5)
+
+    threads = [threading.Thread(target=ask, args=(ctx,)) for ctx in roots]
+    try:
+        for t in threads:
+            t.start()
+        time.sleep(0.3)
+        gate.set()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        b.close()
+    passes = [s for s in tracing.spans() if s["name"] == "serving.pass"]
+    scans = [s for s in tracing.spans() if s["name"] == "serving.scan"]
+    assert len(scans) == 6 and 1 <= len(passes) <= 6
+    by_number = {p["attrs"]["pass"]: p for p in passes}
+    assert len(by_number) == len(passes)  # one span a pass, however many it served
+    for s in scans:
+        p = by_number[s["attrs"]["pass"]]
+        assert p["attrs"]["rows"] <= p["attrs"]["padded_rows"]
+        assert p["attrs"]["k_bucket"] == 16 and p["attrs"]["inflight"] >= 1
+        # the pass's span covers the stretch the request's scan span covers
+        assert p["ts"] <= s["ts"] + 1e-3 and p["ts"] + p["dur"] >= s["ts"] + s["dur"] - 1e-3
+    assert sum(p["attrs"]["rows"] for p in passes) == 6
+    # it lives in the trace of a request it served, as a sibling of its spans
+    assert {p["trace"] for p in passes} <= {ctx.trace_id for ctx in roots}
