@@ -14,11 +14,18 @@ reading the item matrix itself. This module fuses the two:
 - each step computes ``[b, BLOCK_N]`` scores on the MXU with float32
   accumulation (items may be stored bfloat16 or row-quantized int8,
   halving / quartering HBM traffic);
-- each block reduces to its own top-k candidates on-chip — either into a
-  running VMEM scratch (small scan batches, with a threshold gate that
-  skips selection for blocks that cannot enter the top-k) or as
-  block-local ``[b, k]`` candidate tiles (large scan batches, merged by
-  one tiny ``lax.top_k`` over ``[b, num_blocks * k]`` afterwards);
+- each block reduces to top-k candidates on-chip. Scan batches of up to
+  LOCAL_TOPK_BATCH rows keep one sorted running top-k in VMEM scratch:
+  its k-th best is a threshold, a score tile in which no row beats its
+  own is skipped, and in a tile that is not, only the scores above the
+  threshold are taken, each inserted straight into the sorted state
+  (``_insert_beaten``: one round an entry, so the pass hardly depends on
+  how many distinct rows a batch holds; TPU v5e, PR 25: 20M x 50 float32,
+  k 32, 6.18 ms for 8 copies of one row and 6.38 ms for 16 distinct rows,
+  against 5.5 ms to stream the 4.48 GB as stored; 5M x 250: 6.93-6.95 ms
+  against 6.25 ms). Larger batches write block-local ``[b, k]`` candidate
+  tiles instead (``_tile_topk`` + ``_merge_topk``, k rounds each a tile,
+  merged by one ``lax.top_k`` over ``[b, num_blocks * k]`` afterwards);
 - only candidates ever reach HBM — the full score matrix never does.
 
 HBM traffic per batch drops from ``n*k_feat*4 + 2*b*n*4`` bytes to
@@ -54,10 +61,10 @@ import os as _os
 SCORE_TILE = int(_os.environ.get("ORYX_TOPN_BLOCK", 4096))
 # Sub-tiles streamed per grid step: the item block per step is
 # [k_feat, SCORE_TILE * SUBTILES] (bf16, ~1.6 MB at 4) while the
-# score/iota tiles stay SCORE_TILE wide — grid-step orchestration costs
-# ~20us on a v5e, so fewer, fatter steps is most of the kernel's speed
-# (measured 5.5 ms -> 0.17 ms per 1M x 50 scan going 1 -> 4). 8 exceeds
-# the 16 MB scoped-VMEM limit at b=256.
+# score/iota tiles stay SCORE_TILE wide: every grid step has a fixed
+# cost, so fewer, fatter steps (not re-measured on the v5e since the
+# kernels were brought up there, PR 21). 8 exceeds the 16 MB
+# scoped-VMEM limit at b=256.
 SUBTILES = int(_os.environ.get("ORYX_TOPN_SUBTILES", 4))
 BLOCK_N = SCORE_TILE * SUBTILES  # items consumed per grid step
 
@@ -259,7 +266,8 @@ def _score_tile(q, mat_s, aux_s, qn, *, cosine, quantized):
 
 
 def _tile_topk(sc, local_cols, base, k, int_max, neg_inf):
-    """Iterative max: the tile's top-k as [b, k] (scores, item ids), best
+    """Candidates kernel only (no running threshold bounds its rounds).
+    Iterative max: the tile's top-k as [b, k] (scores, item ids), best
     first (ties -> lowest item id, like a stable host scan). The k rounds
     are a rolled loop, so the program holds ONE round: unrolled, Mosaic
     took 10-95 s per kernel at k = 16 and did not finish within half an
@@ -283,8 +291,9 @@ def _tile_topk(sc, local_cols, base, k, int_max, neg_inf):
 
 
 def _merge_topk(cur_v, cur_i, tile_v, tile_i, k, int_max, neg_inf):
-    """Merge a tile's [b, k] top-k into the running [b, k] state: k rounds
-    over the two lists (tiny), rolled like ``_tile_topk``. Ties prefer the
+    """Candidates kernel only. Merge a tile's [b, k] top-k into the
+    block's [b, k] list: k rounds over the two lists, rolled like
+    ``_tile_topk``. Ties prefer the
     smaller item index, which is always the earlier tile — same result as
     a stable global merge."""
     b = cur_v.shape[0]
@@ -313,19 +322,65 @@ def _merge_topk(cur_v, cur_i, tile_v, tile_i, k, int_max, neg_inf):
     return new_v, new_i
 
 
+# The scratch kernel's running top-k is held in whole 128-lane vregs, so
+# shifting a row is a native lane roll: one vreg a row up to MAX_KERNEL_K
+# (the fused multi dispatches do not cap k: a k bucket of 256 takes two).
+_STATE_LANES = 128
+
+
+def _insert_beaten(sc, m, local_cols, base, vstate, istate, *, k, int_max, neg_inf, counts):
+    """Fold a gated score tile into the sorted running top-k, one entry a
+    round, for as many rounds as some row still holds a score strictly
+    above its running k-th best (the caller's gate saw at least one).
+
+    A round takes each row's largest remaining score ``m`` (ties: lowest
+    column) and puts it where a stable sort would: behind every state
+    entry ``>= m``, the entries below it moving one lane up. A row whose
+    ``m`` does not beat its k-th best changes no lane under k, so rows
+    with nothing to insert, padded rows among them, ride along. Entries
+    and order are those of a stable global sort by (score desc, item id
+    asc): equal scores of one tile come out lowest column first and land
+    behind their equals, and a score equal to the k-th best stays out,
+    the earlier item having the lower id. A tile costs at most k rounds:
+    k entries from one tile lift the k-th best to the tile's own k-th.
+    Lanes from k up hold what fell off the end (all <= the k-th best, so
+    never counted as ``>= m``) and are never read or written out."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, vstate.shape, 1)
+
+    def one_round(carry):
+        sc, m, _ = carry
+        at = jnp.min(jnp.where(sc == m, local_cols, int_max), axis=1, keepdims=True)
+        v, i = vstate[...], istate[...]
+        up_v = pltpu.roll(v, 1, 1)  # lane j holds lane j - 1
+        stays = v >= m  # a prefix of the row: the state is sorted
+        lands = (up_v >= m) | (lane == 0)  # first lane past that prefix
+        vstate[...] = jnp.where(stays, v, jnp.where(lands, m, up_v))
+        istate[...] = jnp.where(
+            stays, i, jnp.where(lands, at + base, pltpu.roll(i, 1, 1))
+        )
+        if counts is not None:
+            counts[0, 1] += 1
+        sc = jnp.where(local_cols == at, neg_inf, sc)
+        m = jnp.max(sc, axis=1, keepdims=True)
+        return sc, m, jnp.any(m > vstate[:, k - 1 : k])
+
+    jax.lax.while_loop(lambda carry: carry[2], one_round, (sc, m, True))
+
+
 def _topn_kernel(
-    q_ref, mat_ref, aux_ref, vals_ref, idx_ref, vstate, istate, *,
+    q_ref, mat_ref, aux_ref, vals_ref, idx_ref, *rest,
     k, n_items, cosine, quantized, grid, subtiles
 ):
     """One grid step: score a [k_feat, BLOCK_N] item block and fold it
     into the running top-k carried in VMEM scratch across grid steps.
 
-    The k-pass selection is ~40 VPU ops per score — 10x the cost of the
-    matmul that produced them — so the kernel keeps the running k-th-best
-    as a threshold and SKIPS selection for blocks whose max cannot enter
-    the top-k. With a randomly ordered item matrix only O(k log grid) of
-    the blocks pass the gate, which turns the scan from selection-bound
-    (~4ms at 1M x 50) into matmul/HBM-bound."""
+    The running k-th best is a threshold: a score tile in which no row
+    beats its own is skipped, and in one that is not, selection runs one
+    round for each entry made (``_insert_beaten``), not k rounds.
+    ``rest`` is the two scratch refs, behind a third, SMEM output where
+    the call asked for one: [gated tiles, rounds] of the pass."""
+    *counts, vstate, istate = rest
+    counts = counts[0] if counts else None
     block = pl.program_id(0)
     b = q_ref.shape[0]
     neg_inf = jnp.float32(-jnp.inf)
@@ -333,8 +388,11 @@ def _topn_kernel(
 
     @pl.when(block == 0)
     def _():
-        vstate[...] = jnp.full((b, k), neg_inf, jnp.float32)
-        istate[...] = jnp.zeros((b, k), jnp.int32)
+        vstate[...] = jnp.full(vstate.shape, neg_inf, jnp.float32)
+        istate[...] = jnp.zeros(istate.shape, jnp.int32)
+        if counts is not None:
+            counts[0, 0] = 0
+            counts[0, 1] = 0
 
     q = q_ref[:]  # [b, k_feat]
     qn = None
@@ -357,24 +415,23 @@ def _topn_kernel(
             quantized=quantized,
         )
         scores = jnp.where(local_cols < n_items - base, scores, neg_inf)
-        kth = vstate[...][:, k - 1 : k]  # worst of the running top-k, [b, 1]
-        need = jnp.any(jnp.max(scores, axis=1, keepdims=True) > kth)
+        kth = vstate[:, k - 1 : k]  # worst of the running top-k, [b, 1]
+        best = jnp.max(scores, axis=1, keepdims=True)
+        need = jnp.any(best > kth)
 
         @pl.when(need)
-        def _(scores=scores, base=base):
-            tile_v, tile_i = _tile_topk(
-                scores, local_cols, base, k, int_max, neg_inf
+        def _(scores=scores, best=best, base=base):
+            if counts is not None:
+                counts[0, 0] += 1
+            _insert_beaten(
+                scores, best, local_cols, base, vstate, istate,
+                k=k, int_max=int_max, neg_inf=neg_inf, counts=counts,
             )
-            v, i = _merge_topk(
-                vstate[...], istate[...], tile_v, tile_i, k, int_max, neg_inf
-            )
-            vstate[...] = v
-            istate[...] = i
 
     @pl.when(block == grid - 1)
     def _():
-        vals_ref[...] = vstate[...]
-        idx_ref[...] = istate[...]
+        vals_ref[...] = vstate[:, :k]
+        idx_ref[...] = istate[:, :k]
 
 
 def _topn_candidates_kernel(
@@ -572,8 +629,12 @@ def _pad_queries(q, k_feat: int):
 
 def _streaming_topk_impl(
     mat_t, norms, scales, resid, resid_scales, queries, *,
-    k, n_items, cosine, interpret,
+    k, n_items, cosine, interpret, count_rounds=False,
 ):
+    """(vals [b, k], idxs [b, k]) of one scan batch. ``count_rounds``
+    (trace-time; tests and tools/scan_rounds.py only, no served program
+    sets it) appends the running-scratch kernel's int32 [1, 2] count of
+    (score tiles that passed the gate, selection rounds run in them)."""
     k_feat, n_pad = mat_t.shape
     b = queries.shape[0]
     quantized = scales is not None
@@ -595,6 +656,8 @@ def _streaming_topk_impl(
         )
     common = {} if interpret else dict(memory_space=pltpu.VMEM)
     if b > LOCAL_TOPK_BATCH:
+        if count_rounds:
+            raise ValueError("the candidates kernel has no gate and no rounds to count")
         # block-local candidates: per-block [b, k] tiles + one final merge
         tile = _candidates_tile_for(k_feat, b, mat_t.dtype.itemsize)
         step = tile * SUBTILES
@@ -637,8 +700,20 @@ def _streaming_topk_impl(
         _topn_kernel, k=m, n_items=n_items, cosine=cosine, quantized=quantized,
         grid=grid, subtiles=subtiles,
     )
-    scratch = [pltpu.VMEM((b, m), jnp.float32), pltpu.VMEM((b, m), jnp.int32)]
-    vals, idxs = pl.pallas_call(
+    out_specs = [
+        pl.BlockSpec((b, m), lambda i: (0, 0), **common),
+        pl.BlockSpec((b, m), lambda i: (0, 0), **common),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((b, m), jnp.float32),
+        jax.ShapeDtypeStruct((b, m), jnp.int32),
+    ]
+    if count_rounds:
+        out_specs.append(
+            pl.BlockSpec(**({} if interpret else dict(memory_space=pltpu.SMEM)))
+        )
+        out_shape.append(jax.ShapeDtypeStruct((1, 2), jnp.int32))
+    vals, idxs, *counts = pl.pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[
@@ -646,20 +721,17 @@ def _streaming_topk_impl(
             pl.BlockSpec((k_feat, step), lambda i: (0, i), **common),
             pl.BlockSpec((1, step), lambda i: (0, i), **common),
         ],
-        out_specs=[
-            pl.BlockSpec((b, m), lambda i: (0, 0), **common),
-            pl.BlockSpec((b, m), lambda i: (0, 0), **common),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((b, _ceil_to(m, _STATE_LANES)), jnp.float32),
+            pltpu.VMEM((b, _ceil_to(m, _STATE_LANES)), jnp.int32),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, m), jnp.float32),
-            jax.ShapeDtypeStruct((b, m), jnp.int32),
-        ],
-        scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="oryx_topn_scan",
     )(q, mat_t, aux)
-    return finish(vals, idxs)
+    return (*finish(vals, idxs), *counts)
 
 
 # -- XLA twin of the blocked scan (non-TPU backends) --------------------------
@@ -948,8 +1020,9 @@ def _xla_streaming_topk_multi_indexed(
     return vals, idxs
 
 
-# above this k the kernel's unrolled per-block selection stops paying for
-# itself (and compile time grows with k); fall back to one XLA top_k
+# the single-dispatch entry's kernel k (the running top-k is then one
+# 128-lane row of VMEM state, and a tile's selection at most k rounds);
+# past it, fall back to one XLA top_k
 MAX_KERNEL_K = 128
 
 
