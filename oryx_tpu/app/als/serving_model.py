@@ -61,9 +61,10 @@ class ALSServingModel(ServingModel):
         # an int32 row index instead of a query vector (index submit);
         # only meaningful for the exact-device-scan path
         self.device_user_matrix = device_user_matrix
-        self._x_staging = bool(device_user_matrix) and sample_rate >= 1.0 and not shard_items
-        # row-shard Y over all local devices (per-device top-k +
-        # all_gather merge): the >1-HBM serving mode
+        self._x_staging = bool(device_user_matrix) and sample_rate >= 1.0
+        # row-shard Y over all local devices (the kernel on every shard,
+        # candidates merged across chips; X staged on each): the serving
+        # mode of a catalog past one chip's memory, docs/serving-scan.md
         self.shard_items = shard_items
         # item-matrix dtype for device scoring: bfloat16 halves HBM traffic
         # (the serving bottleneck at millions of items) at ~1e-2 relative
@@ -278,6 +279,15 @@ class ALSServingModel(ServingModel):
 
     # -- device-side scoring ---------------------------------------------------
 
+    def _shard_mesh(self):
+        """The mesh a shard-items model spreads over (all local devices,
+        one ``data`` axis; equal by value at every call), else None."""
+        if not self.shard_items:
+            return None
+        from oryx_tpu.parallel.mesh import get_mesh
+
+        return get_mesh()
+
     def _try_incremental_refresh(self, dirty: list[str]) -> bool:
         """Scatter-update only the dirty rows of the device-resident Y
         (caller holds the cache lock). Returns False when a full rebuild
@@ -328,7 +338,6 @@ class ALSServingModel(ServingModel):
                     self._y_matrix is not None
                     and not self._y_full_rebuild
                     and self.lsh is None
-                    and not self.shard_items  # sharded layout rebuilds whole
                     and bool(dirty)
                     and self._try_incremental_refresh(dirty)
                 )
@@ -344,10 +353,8 @@ class ALSServingModel(ServingModel):
                             "int8": jnp.int8,
                         }.get(self.score_dtype, jnp.float32)
                         if self.shard_items:
-                            from oryx_tpu.parallel.mesh import get_mesh
-
                             self._y_matrix = topn_ops.upload_sharded(
-                                mat, get_mesh(), dtype=dtype
+                                mat, self._shard_mesh(), dtype=dtype
                             )
                         elif (
                             self.score_dtype == "int8"
@@ -450,7 +457,8 @@ class ALSServingModel(ServingModel):
                 cap = max(64, int(len(ids) * 1.25))
                 pad = np.zeros((cap - len(ids), self.features), np.float32)
                 staged = topn_ops.upload_queries(
-                    np.concatenate([mat, pad]) if cap > len(ids) else mat
+                    np.concatenate([mat, pad]) if cap > len(ids) else mat,
+                    mesh=self._shard_mesh(),
                 )
             else:
                 staged, cap = None, 0
@@ -554,9 +562,7 @@ class ALSServingModel(ServingModel):
             x_mat, row = self._user_scan_row(user)
             if row is not None:
                 ids, _index, y_mat, _h, _p = self._ensure_y_matrix()
-                if y_mat is not None and not isinstance(
-                    y_mat, topn_ops.ShardedItemMatrix
-                ):
+                if y_mat is not None:
                     return self._select_loop(
                         ids,
                         len(ids),
@@ -600,11 +606,6 @@ class ALSServingModel(ServingModel):
         def score_fn(k: int):
             if lsh_rows is not None:
                 return _host_top_k(y_host, lsh_rows, query, k, cosine=cosine)
-            if isinstance(y_mat, topn_ops.ShardedItemMatrix):
-                # mesh-sharded scan: per-device top-k + all_gather merge
-                metrics.registry.counter("serving.scan.sharded.queries").inc()
-                bi, bv = topn_ops.top_k_sharded(y_mat, query, k, cosine=cosine)
-                return bi[0], bv[0]
             # continuous batching: concurrent requests against the same
             # Y snapshot coalesce into one device call
             return score_default(y_mat, query, k, cosine=cosine)

@@ -51,9 +51,10 @@ def _null_annotation(name: str, **attrs):
 
 
 def record_device_memory_peak(refresh: bool = False) -> None:
-    """Set the gauge ``device.memory.peak-bytes`` from the first local
-    device's allocator; left unset where the backend reports none (the
-    CPU). The staging sites call it right after an upload, when the
+    """Set the gauge ``device.memory.peak-bytes`` from the local devices'
+    allocators, the fullest of them (a sharded model's chips differ);
+    left unset where the backend reports none (the CPU). The staging
+    sites call it right after an upload, when the
     process owns its device. With ``refresh`` (the ``/metrics`` scrape) it
     reads only where a staging site has set the gauge before, so a scrape
     never initialises a backend. A gauge must not fail a model load or a
@@ -64,11 +65,13 @@ def record_device_memory_peak(refresh: bool = False) -> None:
     try:
         import jax
 
-        stats = jax.local_devices()[0].memory_stats() or {}
+        peaks = [
+            (dev.memory_stats() or {}).get("peak_bytes_in_use") for dev in jax.local_devices()
+        ]
     except Exception:
         log.warning("device memory stats unavailable", exc_info=True)
         return
-    peak = stats.get("peak_bytes_in_use")
+    peak = max((p for p in peaks if p is not None), default=None)
     if peak is not None:
         from oryx_tpu.common import metrics
 

@@ -203,12 +203,8 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.fs_recent_count.argtypes = [c.c_void_p]
     lib.fs_pack.restype = c.c_int64
     lib.fs_pack.argtypes = [
-        c.c_void_p, c.POINTER(c.c_float), c.c_int64, c.c_char_p, c.c_int64,
-        c.POINTER(c.c_int64), c.POINTER(c.c_int64), c.c_int,
-    ]
-    lib.fs_ids.restype = c.c_int64
-    lib.fs_ids.argtypes = [
-        c.c_void_p, c.c_char_p, c.c_int64, c.POINTER(c.c_int64), c.c_int,
+        c.c_void_p, c.POINTER(c.c_float), c.c_char_p, c.POINTER(c.c_int64),
+        c.c_int64, c.c_int64, c.POINTER(c.c_int64), c.POINTER(c.c_int64), c.c_int,
     ]
     lib.fs_vtv.argtypes = [c.c_void_p, c.POINTER(c.c_double)]
     lib.fs_retain.argtypes = [c.c_void_p, c.POINTER(c.c_int64), c.c_char_p, c.c_int64]

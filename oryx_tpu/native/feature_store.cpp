@@ -7,7 +7,7 @@
 // Per SURVEY.md: "any remaining CPU-side hot path that genuinely needs it
 // (e.g. the serving layer's concurrent hash-partitioned vector store) gets a
 // C++ implementation bound into Python". Vectors live in per-shard
-// contiguous slabs so packing a snapshot for device upload is a straight
+// slot-major slabs so packing a snapshot for device upload is a straight
 // memcpy sweep, and readers take per-shard shared locks so lookups/scans run
 // in parallel with writes to other shards (ctypes releases the GIL around
 // every call).
@@ -19,6 +19,7 @@
 #include <sys/stat.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <atomic>
 #include <cerrno>
 #include <charconv>
@@ -31,9 +32,11 @@
 #include <deque>
 #include <functional>
 #include <list>
+#include <memory>
 #include <mutex>
 #include <shared_mutex>
 #include <string>
+#include <string_view>
 #include <thread>
 #include <unordered_map>
 #include <unordered_set>
@@ -41,13 +44,23 @@
 
 namespace {
 
+// Slots a slab block holds. A shard's vectors live slot-major in blocks of
+// this many: it grows by a block and never moves what it holds (one
+// doubling vector a shard copied a 20 GB model twice over on its way in and
+// held old and new side by side).
+constexpr int64_t kBlockSlots = 1024;
+
 struct Shard {
   mutable std::shared_mutex mu;
   std::unordered_map<std::string, int64_t> index;  // id -> slot
   std::vector<std::string> slot_ids;               // slot -> id ("" = free)
-  std::vector<float> slab;                         // slot-major vector data
+  std::vector<std::unique_ptr<float[]>> slab;      // blocks of kBlockSlots
   std::vector<int64_t> free_slots;
   std::unordered_set<std::string> recent;
+
+  float* row(int64_t slot, int64_t dim) const {
+    return slab[slot / kBlockSlots].get() + (slot % kBlockSlots) * dim;
+  }
 };
 
 struct Store {
@@ -59,6 +72,52 @@ struct Store {
     return shards[std::hash<std::string>{}(id) % num_shards];
   }
 };
+
+// Insert or overwrite one vector; the caller holds the shard's unique lock.
+inline void put_locked(Shard& sh, int64_t dim, const std::string& key,
+                       const float* vec) {
+  auto it = sh.index.find(key);
+  int64_t slot;
+  if (it != sh.index.end()) {
+    slot = it->second;
+  } else if (!sh.free_slots.empty()) {
+    slot = sh.free_slots.back();
+    sh.free_slots.pop_back();
+    sh.slot_ids[slot] = key;
+    sh.index.emplace(key, slot);
+  } else {
+    slot = static_cast<int64_t>(sh.slot_ids.size());
+    sh.slot_ids.push_back(key);
+    if (slot % kBlockSlots == 0) sh.slab.emplace_back(new float[kBlockSlots * dim]);
+    sh.index.emplace(key, slot);
+  }
+  std::memcpy(sh.row(slot, dim), vec, dim * sizeof(float));
+  sh.recent.insert(key);
+}
+
+// Below this many rows a bulk call stays on the caller's thread: starting
+// workers costs more than they save (the speed layer's micro-batches).
+constexpr int64_t kBulkRows = 1 << 15;
+
+// fn(task) for every task in [0, tasks), on up to `tasks` hardware threads
+// (on the caller's alone when `serial`); returns when all are done.
+template <typename F>
+void for_each_task(int64_t tasks, bool serial, F fn) {
+  int64_t hw = static_cast<int64_t>(std::thread::hardware_concurrency());
+  int64_t workers = serial ? 1 : std::min(tasks, std::max<int64_t>(hw, 1));
+  if (workers <= 1) {
+    for (int64_t t = 0; t < tasks; ++t) fn(t);
+    return;
+  }
+  std::atomic<int64_t> next{0};
+  auto run = [&] {
+    for (int64_t t; (t = next.fetch_add(1)) < tasks;) fn(t);
+  };
+  std::vector<std::thread> threads;
+  for (int64_t w = 1; w < workers; ++w) threads.emplace_back(run);
+  run();
+  for (auto& th : threads) th.join();
+}
 
 }  // namespace
 
@@ -82,23 +141,7 @@ void fs_set(void* p, const char* id, int64_t id_len, const float* vec) {
   std::string key(id, id_len);
   Shard& sh = s->shard_for(key);
   std::unique_lock lock(sh.mu);
-  auto it = sh.index.find(key);
-  int64_t slot;
-  if (it != sh.index.end()) {
-    slot = it->second;
-  } else if (!sh.free_slots.empty()) {
-    slot = sh.free_slots.back();
-    sh.free_slots.pop_back();
-    sh.slot_ids[slot] = key;
-    sh.index.emplace(key, slot);
-  } else {
-    slot = static_cast<int64_t>(sh.slot_ids.size());
-    sh.slot_ids.push_back(key);
-    sh.slab.resize(sh.slab.size() + s->dim);
-    sh.index.emplace(key, slot);
-  }
-  std::memcpy(sh.slab.data() + slot * s->dim, vec, s->dim * sizeof(float));
-  sh.recent.insert(key);
+  put_locked(sh, s->dim, key, vec);
 }
 
 int fs_get(void* p, const char* id, int64_t id_len, float* out) {
@@ -108,7 +151,7 @@ int fs_get(void* p, const char* id, int64_t id_len, float* out) {
   std::shared_lock lock(sh.mu);
   auto it = sh.index.find(key);
   if (it == sh.index.end()) return 0;
-  std::memcpy(out, sh.slab.data() + it->second * s->dim, s->dim * sizeof(float));
+  std::memcpy(out, sh.row(it->second, s->dim), s->dim * sizeof(float));
   return 1;
 }
 
@@ -149,112 +192,83 @@ int64_t fs_recent_count(void* p) {
   return n;
 }
 
-// IDs cross the ABI as a length-prefixed stream: [u32 len][bytes]... — ids
-// are arbitrary strings off the wire (JSON), so a newline/NUL-delimited
-// protocol would corrupt the id<->row mapping for ids containing the
-// delimiter.
-static char* write_id(char* out, const std::string& id) {
-  uint32_t len = static_cast<uint32_t>(id.size());
-  std::memcpy(out, &len, sizeof(len));
-  out += sizeof(len);
-  std::memcpy(out, id.data(), id.size());
-  return out + id.size();
-}
-
-static int64_t id_stream_size(const std::string& id) {
-  return static_cast<int64_t>(sizeof(uint32_t) + id.size());
-}
-
-// Pack a consistent snapshot: all shard locks are held (shared) for the
-// duration. Returns n on success, -1 when a buffer is too small (caller
-// re-sizes from *mat_needed / *ids_needed and retries), with the needed
-// capacities always reported.
-int64_t fs_pack(void* p, float* mat_out, int64_t mat_cap, char* ids_out,
-                int64_t ids_cap, int64_t* mat_needed, int64_t* ids_needed,
-                int recent_only) {
+// Pack a consistent snapshot: every shard's lock is held (shared) for the
+// duration. Ids cross the ABI as (offsets[n+1], utf-8 bytes with a NUL
+// after each id), id i = ids_out[offs_out[i]..offs_out[i+1] - 1): the caller
+// splits one decoded string at the NULs, and cuts by the offsets only when
+// an id holds a NUL itself (ids are arbitrary strings off the wire, so no
+// delimiter is safe alone). Row i of mat_out is id i's
+// vector; mat_out may be null (ids only: /user/allIDs-style calls and
+// rotation bookkeeping). Rows come shard by shard in slot order, so each
+// shard's slab is read front to back, and the shards are packed side by
+// side on the host's threads. Returns n, or -1 when a buffer is too small
+// (rows_cap rows of mat_out, rows_cap + 1 offsets, ids_cap bytes); the
+// needed sizes are reported either way and the caller retries.
+int64_t fs_pack(void* p, float* mat_out, char* ids_out, int64_t* offs_out,
+                int64_t rows_cap, int64_t ids_cap, int64_t* rows_needed,
+                int64_t* ids_needed, int recent_only) {
   auto* s = static_cast<Store*>(p);
+  const int64_t dim = s->dim, ns = s->num_shards;
   std::vector<std::shared_lock<std::shared_mutex>> locks;
-  locks.reserve(s->shards.size());
-  for (auto& sh : s->shards) locks.emplace_back(sh.mu);
-
-  int64_t n = 0, ids_len = 0;
+  locks.reserve(ns);
+  int64_t total = 0;
   for (auto& sh : s->shards) {
-    if (recent_only) {
-      for (const auto& id : sh.recent) {
-        if (sh.index.count(id)) {
-          n++;
-          ids_len += id_stream_size(id);
-        }
-      }
-    } else {
-      n += static_cast<int64_t>(sh.index.size());
-      for (const auto& kv : sh.index) ids_len += id_stream_size(kv.first);
-    }
+    locks.emplace_back(sh.mu);
+    total += static_cast<int64_t>(sh.index.size());
   }
-  *mat_needed = n * s->dim;
-  *ids_needed = ids_len;
-  if (n * s->dim > mat_cap || ids_len > ids_cap) return -1;
+  const bool serial = total < kBulkRows;
 
-  int64_t row = 0;
-  char* idp = ids_out;
-  for (auto& sh : s->shards) {
+  // which slots go out (a free slot's id is empty, and so may a live id be:
+  // the index decides), and each shard's rows and id bytes
+  std::vector<std::vector<char>> live(ns);
+  std::vector<int64_t> row0(ns + 1, 0), byte0(ns + 1, 0);
+  for_each_task(ns, serial, [&](int64_t t) {
+    const Shard& sh = s->shards[t];
+    auto& on = live[t];
+    on.assign(sh.slot_ids.size(), 0);
+    int64_t rows = 0, bytes = 0;
+    auto take = [&](const std::string& id, int64_t slot) {
+      on[slot] = 1;
+      rows++;
+      bytes += static_cast<int64_t>(id.size()) + 1;
+    };
     if (recent_only) {
       for (const auto& id : sh.recent) {
         auto it = sh.index.find(id);
-        if (it == sh.index.end()) continue;
-        std::memcpy(mat_out + row * s->dim, sh.slab.data() + it->second * s->dim,
-                    s->dim * sizeof(float));
-        idp = write_id(idp, id);
-        row++;
+        if (it != sh.index.end()) take(id, it->second);
       }
     } else {
-      for (const auto& kv : sh.index) {
-        std::memcpy(mat_out + row * s->dim, sh.slab.data() + kv.second * s->dim,
-                    s->dim * sizeof(float));
-        idp = write_id(idp, kv.first);
-        row++;
-      }
+      for (const auto& kv : sh.index) take(kv.first, kv.second);
     }
+    row0[t + 1] = rows;
+    byte0[t + 1] = bytes;
+  });
+  for (int64_t t = 0; t < ns; ++t) {
+    row0[t + 1] += row0[t];
+    byte0[t + 1] += byte0[t];
   }
-  return row;
-}
+  const int64_t n = row0[ns];
+  *rows_needed = n;
+  *ids_needed = byte0[ns];
+  if (n > rows_cap || byte0[ns] > ids_cap) return -1;
 
-// IDs only, without copying vector data (the /user/allIDs-style calls and
-// rotation bookkeeping need just the key set).
-int64_t fs_ids(void* p, char* ids_out, int64_t ids_cap, int64_t* ids_needed,
-               int recent_only) {
-  auto* s = static_cast<Store*>(p);
-  std::vector<std::shared_lock<std::shared_mutex>> locks;
-  locks.reserve(s->shards.size());
-  for (auto& sh : s->shards) locks.emplace_back(sh.mu);
-
-  int64_t n = 0, ids_len = 0;
-  for (auto& sh : s->shards) {
-    if (recent_only) {
-      for (const auto& id : sh.recent) {
-        if (sh.index.count(id)) {
-          n++;
-          ids_len += id_stream_size(id);
-        }
+  for_each_task(ns, serial, [&](int64_t t) {
+    const Shard& sh = s->shards[t];
+    const auto& on = live[t];
+    int64_t row = row0[t], at = byte0[t];
+    for (size_t slot = 0; slot < on.size(); ++slot) {
+      if (!on[slot]) continue;
+      const std::string& id = sh.slot_ids[slot];
+      if (mat_out) {
+        std::memcpy(mat_out + row * dim, sh.row(slot, dim), dim * sizeof(float));
       }
-    } else {
-      n += static_cast<int64_t>(sh.index.size());
-      for (const auto& kv : sh.index) ids_len += id_stream_size(kv.first);
+      std::memcpy(ids_out + at, id.data(), id.size());
+      offs_out[row++] = at;
+      at += static_cast<int64_t>(id.size()) + 1;
+      ids_out[at - 1] = '\0';
     }
-  }
-  *ids_needed = ids_len;
-  if (ids_len > ids_cap) return -1;
-
-  char* idp = ids_out;
-  for (auto& sh : s->shards) {
-    if (recent_only) {
-      for (const auto& id : sh.recent) {
-        if (sh.index.count(id)) idp = write_id(idp, id);
-      }
-    } else {
-      for (const auto& kv : sh.index) idp = write_id(idp, kv.first);
-    }
-  }
+  });
+  offs_out[n] = byte0[ns];
   return n;
 }
 
@@ -266,7 +280,7 @@ void fs_vtv(void* p, double* out) {
   for (auto& sh : s->shards) {
     std::shared_lock lock(sh.mu);
     for (const auto& kv : sh.index) {
-      const float* v = sh.slab.data() + kv.second * k;
+      const float* v = sh.row(kv.second, k);
       for (int64_t i = 0; i < k; i++) {
         const double vi = v[i];
         double* row = out + i * k;
@@ -312,34 +326,53 @@ void fs_retain(void* p, const int64_t* offs, const char* payload, int64_t n) {
 // Same slot logic as fs_set, but the whole batch runs without returning
 // to Python — the speed layer's self-consume thread applies 100K+
 // deltas/s through here (one ctypes fs_set per delta cost ~60us on a
-// 1-core host; the batch call amortizes it away).
+// 1-core host; the batch call amortizes it away). A bulk load (a model's
+// 20M rows arrive in batches of a million) is split by shard and the
+// shards are filled side by side, each under its lock once; within a
+// shard the rows go in batch order, so a later duplicate still wins.
 void fs_set_batch(void* p, const int64_t* offs, const char* payload,
                   int64_t n, const float* mat) {
   auto* s = static_cast<Store*>(p);
-  std::string key;
-  for (int64_t i = 0; i < n; ++i) {
-    key.assign(payload + offs[i], static_cast<size_t>(offs[i + 1] - offs[i]));
-    Shard& sh = s->shard_for(key);
-    std::unique_lock lock(sh.mu);
-    auto it = sh.index.find(key);
-    int64_t slot;
-    if (it != sh.index.end()) {
-      slot = it->second;
-    } else if (!sh.free_slots.empty()) {
-      slot = sh.free_slots.back();
-      sh.free_slots.pop_back();
-      sh.slot_ids[slot] = key;
-      sh.index.emplace(key, slot);
-    } else {
-      slot = static_cast<int64_t>(sh.slot_ids.size());
-      sh.slot_ids.push_back(key);
-      sh.slab.resize(sh.slab.size() + s->dim);
-      sh.index.emplace(key, slot);
+  const int64_t dim = s->dim, ns = s->num_shards;
+  auto id_at = [&](int64_t i) {
+    return std::string_view(payload + offs[i],
+                            static_cast<size_t>(offs[i + 1] - offs[i]));
+  };
+  if (n < kBulkRows || ns == 1) {
+    std::string key;
+    for (int64_t i = 0; i < n; ++i) {
+      key.assign(id_at(i));
+      Shard& sh = s->shard_for(key);
+      std::unique_lock lock(sh.mu);
+      put_locked(sh, dim, key, mat + i * dim);
     }
-    std::memcpy(sh.slab.data() + slot * s->dim, mat + i * s->dim,
-                s->dim * sizeof(float));
-    sh.recent.insert(key);
+    return;
   }
+  // the shard of every row (std::hash of a string_view equals that of the
+  // string), then the rows of each shard in batch order
+  std::vector<int32_t> shard_of(n);
+  const int64_t chunks = (n + kBulkRows - 1) / kBulkRows;
+  for_each_task(chunks, false, [&](int64_t c) {
+    const int64_t hi = std::min(n, (c + 1) * kBulkRows);
+    for (int64_t i = c * kBulkRows; i < hi; ++i) {
+      shard_of[i] = static_cast<int32_t>(
+          std::hash<std::string_view>{}(id_at(i)) % ns);
+    }
+  });
+  std::vector<int64_t> first(ns + 1, 0);
+  for (int64_t i = 0; i < n; ++i) first[shard_of[i] + 1]++;
+  for (int64_t t = 0; t < ns; ++t) first[t + 1] += first[t];
+  std::vector<int64_t> rows(n), fill(first.begin(), first.end() - 1);
+  for (int64_t i = 0; i < n; ++i) rows[fill[shard_of[i]]++] = i;
+  for_each_task(ns, false, [&](int64_t t) {
+    Shard& sh = s->shards[t];
+    std::unique_lock lock(sh.mu);
+    std::string key;
+    for (int64_t j = first[t]; j < first[t + 1]; ++j) {
+      key.assign(id_at(rows[j]));
+      put_locked(sh, dim, key, mat + rows[j] * dim);
+    }
+  });
 }
 
 // Batched lookup: ids as (offsets, payload), vectors written to
@@ -358,7 +391,7 @@ int64_t fs_get_batch(void* p, const int64_t* offs, const char* payload,
     if (it == sh.index.end()) {
       out_valid[i] = 0;
     } else {
-      std::memcpy(out_mat + i * s->dim, sh.slab.data() + it->second * s->dim,
+      std::memcpy(out_mat + i * s->dim, sh.row(it->second, s->dim),
                   s->dim * sizeof(float));
       out_valid[i] = 1;
     }

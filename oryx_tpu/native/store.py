@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import struct
 import threading
 from typing import Callable, Iterable
 
@@ -21,17 +20,17 @@ from oryx_tpu.common import metrics
 from oryx_tpu.native import get_library
 
 
-def _decode_ids(buf: bytes) -> list[str]:
-    """Parse the length-prefixed id stream ([u32 len][bytes]...)."""
-    ids = []
-    pos = 0
-    end = len(buf)
-    while pos + 4 <= end:
-        (n,) = struct.unpack_from("<I", buf, pos)
-        pos += 4
-        ids.append(buf[pos : pos + n].decode("utf-8"))
-        pos += n
-    return ids
+def _cut_ids(offs: np.ndarray, payload: bytes) -> list[str]:
+    """Ids as the native store packs them: utf-8 bytes with a NUL after
+    each id, id i at payload[offs[i] : offs[i + 1] - 1]. One decode and one
+    split for the lot (a Python step an id took a minute at 20M ids); by
+    the offsets only if an id holds a NUL itself."""
+    ids = payload.decode("utf-8").split("\0")
+    ids.pop()  # what follows the last NUL
+    if len(ids) == len(offs) - 1:
+        return ids
+    off = offs.tolist()
+    return [payload[a : b - 1].decode("utf-8") for a, b in zip(off, off[1:])]
 
 
 def _offsets_payload(ids: list[str]) -> tuple[np.ndarray, bytes]:
@@ -156,47 +155,43 @@ class NativeFeatureVectors:
             key = id_.encode("utf-8")
             self._lib.fs_remove(self._ptr, key, len(key))
 
-    def _pack(self, recent_only: bool = False) -> tuple[list[str], np.ndarray]:
+    def _pack(
+        self, recent_only: bool = False, vectors: bool = True
+    ) -> tuple[list[str], np.ndarray | None]:
+        """(ids, [n, dim] rows in the ids' order) of one consistent
+        snapshot (fs_pack); ``vectors=False`` packs the ids alone. The
+        rows are a view of the buffer the store filled: no second copy of
+        a matrix that may be tens of gigabytes."""
         if self._ptr is None:
-            return [], np.zeros((0, 0), dtype=np.float32)
-        mat_cap = max(1, self.size() + 64) * self._dim
-        ids_cap = max(1024, (self.size() + 64) * 64)
+            return [], np.zeros((0, 0), dtype=np.float32) if vectors else None
+        rows_cap = self.size() + 64
+        ids_cap = max(1024, rows_cap * 32)
+        rows_needed = ctypes.c_int64()
+        ids_needed = ctypes.c_int64()
         while True:
-            mat = np.empty(mat_cap, dtype=np.float32)
-            ids_buf = ctypes.create_string_buffer(ids_cap)
-            mat_needed = ctypes.c_int64()
-            ids_needed = ctypes.c_int64()
+            mat = np.empty((rows_cap, self._dim), dtype=np.float32) if vectors else None
+            payload = np.empty(ids_cap, dtype=np.uint8)
+            offs = np.empty(rows_cap + 1, dtype=np.int64)
             n = self._lib.fs_pack(
                 self._ptr,
-                mat.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-                mat_cap,
-                ids_buf,
+                mat.ctypes.data_as(ctypes.POINTER(ctypes.c_float)) if vectors else None,
+                payload.ctypes.data_as(ctypes.c_char_p),
+                _offsets_ptr(offs),
+                rows_cap,
                 ids_cap,
-                ctypes.byref(mat_needed),
+                ctypes.byref(rows_needed),
                 ctypes.byref(ids_needed),
                 1 if recent_only else 0,
             )
             if n >= 0:
-                ids = _decode_ids(ids_buf.raw[: ids_needed.value])
-                return ids, mat[: n * self._dim].reshape(n, self._dim).copy()
-            mat_cap = max(mat_needed.value, self._dim)
+                ids = _cut_ids(offs[: n + 1], payload[: ids_needed.value].tobytes())
+                return ids, mat[:n] if vectors else None
+            rows_cap = max(rows_needed.value, 1)
             ids_cap = max(ids_needed.value, 1024)
 
     def _pack_ids(self, recent_only: bool = False) -> list[str]:
-        """IDs without copying vector data (fs_ids)."""
-        if self._ptr is None:
-            return []
-        ids_cap = max(4096, (self.size() + 64) * 64)
-        while True:
-            ids_buf = ctypes.create_string_buffer(ids_cap)
-            ids_needed = ctypes.c_int64()
-            n = self._lib.fs_ids(
-                self._ptr, ids_buf, ids_cap, ctypes.byref(ids_needed),
-                1 if recent_only else 0,
-            )
-            if n >= 0:
-                return _decode_ids(ids_buf.raw[: ids_needed.value])
-            ids_cap = max(ids_needed.value, 4096)
+        """IDs without copying vector data."""
+        return self._pack(recent_only, vectors=False)[0]
 
     def to_matrix(self) -> tuple[list[str], np.ndarray]:
         return self._pack()

@@ -46,6 +46,7 @@ scores; ``interpret=True`` forces the Pallas kernel under the interpreter
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import jax
@@ -192,6 +193,52 @@ def _quantize_residual(
     return q2, s2
 
 
+# Laying a packed matrix out for the kernel is a transposing copy of every
+# byte: done in bands of rows on a few threads (numpy's copy loops release
+# the GIL), a band a task. On one thread it was most of a 5 GB shard's
+# upload, and the norms made a temporary as large as the shard.
+_LAYOUT_BAND = 1 << 16
+_LAYOUT_THREADS = min(16, _os.cpu_count() or 1)
+
+
+def _in_bands(fn, n: int) -> None:
+    """fn(lo, hi) for every band of rows [lo, hi) of n."""
+    bands = [(lo, min(n, lo + _LAYOUT_BAND)) for lo in range(0, n, _LAYOUT_BAND)]
+    if len(bands) <= 1:
+        for band in bands:
+            fn(*band)
+        return
+    with ThreadPoolExecutor(max_workers=_LAYOUT_THREADS) as pool:
+        list(pool.map(lambda band: fn(*band), bands))
+
+
+def feature_major(rows: np.ndarray, cols: int, dtype, height: int | None = None) -> np.ndarray:
+    """[n, k] row-major -> the kernel's [height, cols] feature-major plane
+    (``out[:k, :n] = rows.T``, zero elsewhere), cast to `dtype`."""
+    n, k = rows.shape
+    out = np.empty((height or k, cols), dtype=dtype)
+    out[:, n:] = 0
+    out[k:, :n] = 0
+
+    def band(lo, hi):
+        out[:k, lo:hi] = rows[lo:hi].T
+
+    _in_bands(band, n)
+    return out
+
+
+def row_norms(rows: np.ndarray) -> np.ndarray:
+    """[n] float32 L2 norms of the rows, bit for bit ``np.linalg.norm(rows,
+    axis=1)`` (a row's sum does not depend on its neighbours)."""
+    out = np.empty(rows.shape[0], dtype=np.float32)
+
+    def band(lo, hi):
+        out[lo:hi] = np.linalg.norm(rows[lo:hi], axis=1)
+
+    _in_bands(band, rows.shape[0])
+    return out
+
+
 def upload_streaming(matrix: np.ndarray, dtype=jnp.float32) -> StreamingItemMatrix:
     """Pad items up to a BLOCK_N multiple and move [k, n] to device.
 
@@ -202,15 +249,13 @@ def upload_streaming(matrix: np.ndarray, dtype=jnp.float32) -> StreamingItemMatr
     n_pad = max(BLOCK_N, _ceil_to(n, BLOCK_N))
     mat = np.asarray(matrix, dtype=np.float32)
     norms = np.zeros((1, n_pad), dtype=np.float32)
-    norms[0, :n] = np.linalg.norm(mat, axis=1)
+    norms[0, :n] = row_norms(mat)
     if _is_int8(dtype):
         q, s = _quantize_rows(mat)
         q2, s2 = _quantize_residual(mat, q, s)
         kf_pad = _ceil_to(k_feat, _INT8_FEAT_MULTIPLE)
-        mat_t = np.zeros((kf_pad, n_pad), dtype=np.int8)
-        mat_t[:k_feat, :n] = q.T
-        resid = np.zeros((kf_pad, n_pad), dtype=np.int8)
-        resid[:k_feat, :n] = q2.T
+        mat_t = feature_major(q, n_pad, np.int8, kf_pad)
+        resid = feature_major(q2, n_pad, np.int8, kf_pad)
         scales = np.ones((1, n_pad), dtype=np.float32)
         scales[0, :n] = s
         rscales = np.ones((1, n_pad), dtype=np.float32)
@@ -224,10 +269,8 @@ def upload_streaming(matrix: np.ndarray, dtype=jnp.float32) -> StreamingItemMatr
             resid=jnp.asarray(resid),
             resid_scales=jnp.asarray(rscales),
         )
-    mat_t = np.zeros((k_feat, n_pad), dtype=np.float32)
-    mat_t[:, :n] = mat.T
     return StreamingItemMatrix(
-        mat_t=jnp.asarray(mat_t, dtype=dtype),
+        mat_t=jnp.asarray(feature_major(mat, n_pad, np.float32), dtype=dtype),
         norms=jnp.asarray(norms),
         n_items=n,
     )
@@ -368,7 +411,7 @@ def _insert_beaten(sc, m, local_cols, base, vstate, istate, *, k, int_max, neg_i
 
 
 def _topn_kernel(
-    q_ref, mat_ref, aux_ref, vals_ref, idx_ref, *rest,
+    q_ref, mat_ref, aux_ref, *rest,
     k, n_items, cosine, quantized, grid, subtiles
 ):
     """One grid step: score a [k_feat, BLOCK_N] item block and fold it
@@ -377,9 +420,15 @@ def _topn_kernel(
     The running k-th best is a threshold: a score tile in which no row
     beats its own is skipped, and in one that is not, selection runs one
     round for each entry made (``_insert_beaten``), not k rounds.
-    ``rest`` is the two scratch refs, behind a third, SMEM output where
-    the call asked for one: [gated tiles, rounds] of the pass."""
-    *counts, vstate, istate = rest
+    ``rest`` is the two outputs and the two scratch refs, with a third,
+    SMEM output between them where the call asked for one ([gated tiles,
+    rounds] of the pass), and in front of them an SMEM input where
+    ``n_items`` is None: the valid item count, known only on the device
+    (a mesh shard's own row count, ops/topn.py)."""
+    if n_items is None:
+        n_ref, *rest = rest
+        n_items = n_ref[0]
+    vals_ref, idx_ref, *counts, vstate, istate = rest
     counts = counts[0] if counts else None
     block = pl.program_id(0)
     b = q_ref.shape[0]
@@ -435,7 +484,7 @@ def _topn_kernel(
 
 
 def _topn_candidates_kernel(
-    q_ref, mat_ref, aux_ref, vals_ref, idx_ref, *,
+    q_ref, mat_ref, aux_ref, *rest,
     k, n_items, cosine, quantized, subtiles, tile
 ):
     """Block-local top-k: each grid step reduces its own item block to
@@ -445,7 +494,11 @@ def _topn_candidates_kernel(
     [b, SCORE_TILE] and stops fitting VMEM past ~256 rows). A final
     [b, grid * k] lax.top_k outside the kernel merges the blocks; the
     candidate traffic is k/tile of the score matrix, so HBM stays
-    item-bound."""
+    item-bound. ``n_items`` None: as in ``_topn_kernel``."""
+    if n_items is None:
+        n_ref, *rest = rest
+        n_items = n_ref[0]
+    vals_ref, idx_ref = rest
     block = pl.program_id(0)
     b = q_ref.shape[0]
     neg_inf = jnp.float32(-jnp.inf)
@@ -629,12 +682,16 @@ def _pad_queries(q, k_feat: int):
 
 def _streaming_topk_impl(
     mat_t, norms, scales, resid, resid_scales, queries, *,
-    k, n_items, cosine, interpret, count_rounds=False,
+    k, n_items, cosine, interpret, count_rounds=False, n_valid=None,
 ):
     """(vals [b, k], idxs [b, k]) of one scan batch. ``count_rounds``
     (trace-time; tests and tools/scan_rounds.py only, no served program
     sets it) appends the running-scratch kernel's int32 [1, 2] count of
-    (score tiles that passed the gate, selection rounds run in them)."""
+    (score tiles that passed the gate, selection rounds run in them).
+    ``n_valid`` (int32 [1], a device value) masks the columns from it up
+    where the count is not known when the program is traced: every shard
+    of a mesh runs one program on its own rows (ops/topn.py). The kernel
+    then reads it from SMEM, and ``n_items`` only sizes the rescore."""
     k_feat, n_pad = mat_t.shape
     b = queries.shape[0]
     quantized = scales is not None
@@ -655,6 +712,11 @@ def _streaming_topk_impl(
             k=k, cosine=cosine,
         )
     common = {} if interpret else dict(memory_space=pltpu.VMEM)
+    smem = {} if interpret else dict(memory_space=pltpu.SMEM)
+    # the mask's bound: the static count, or a fourth, SMEM operand
+    masked_from = n_items if n_valid is None else None
+    valid_spec = [] if n_valid is None else [pl.BlockSpec(**smem)]
+    valid_arg = [] if n_valid is None else [n_valid.astype(jnp.int32).reshape(1)]
     if b > LOCAL_TOPK_BATCH:
         if count_rounds:
             raise ValueError("the candidates kernel has no gate and no rounds to count")
@@ -663,7 +725,7 @@ def _streaming_topk_impl(
         step = tile * SUBTILES
         grid = n_pad // step
         kernel = functools.partial(
-            _topn_candidates_kernel, k=m, n_items=n_items, cosine=cosine,
+            _topn_candidates_kernel, k=m, n_items=masked_from, cosine=cosine,
             quantized=quantized, subtiles=SUBTILES, tile=tile,
         )
         vals_c, idx_c = pl.pallas_call(
@@ -673,6 +735,7 @@ def _streaming_topk_impl(
                 pl.BlockSpec((b, k_feat), lambda i: (0, 0), **common),
                 pl.BlockSpec((k_feat, step), lambda i: (0, i), **common),
                 pl.BlockSpec((1, step), lambda i: (0, i), **common),
+                *valid_spec,
             ],
             out_specs=[
                 pl.BlockSpec((1, b, m), lambda i: (i, 0, 0), **common),
@@ -685,7 +748,7 @@ def _streaming_topk_impl(
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
             name="oryx_topn_candidates",
-        )(q, mat_t, aux)
+        )(q, mat_t, aux, *valid_arg)
         allv = jnp.moveaxis(vals_c, 0, 1).reshape(b, grid * m)
         alli = jnp.moveaxis(idx_c, 0, 1).reshape(b, grid * m)
         vals, pos = jax.lax.top_k(allv, m)
@@ -697,7 +760,7 @@ def _streaming_topk_impl(
     step = SCORE_TILE * subtiles
     grid = n_pad // step
     kernel = functools.partial(
-        _topn_kernel, k=m, n_items=n_items, cosine=cosine, quantized=quantized,
+        _topn_kernel, k=m, n_items=masked_from, cosine=cosine, quantized=quantized,
         grid=grid, subtiles=subtiles,
     )
     out_specs = [
@@ -709,9 +772,7 @@ def _streaming_topk_impl(
         jax.ShapeDtypeStruct((b, m), jnp.int32),
     ]
     if count_rounds:
-        out_specs.append(
-            pl.BlockSpec(**({} if interpret else dict(memory_space=pltpu.SMEM)))
-        )
+        out_specs.append(pl.BlockSpec(**smem))
         out_shape.append(jax.ShapeDtypeStruct((1, 2), jnp.int32))
     vals, idxs, *counts = pl.pallas_call(
         kernel,
@@ -720,6 +781,7 @@ def _streaming_topk_impl(
             pl.BlockSpec((b, k_feat), lambda i: (0, 0), **common),
             pl.BlockSpec((k_feat, step), lambda i: (0, i), **common),
             pl.BlockSpec((1, step), lambda i: (0, i), **common),
+            *valid_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -730,7 +792,7 @@ def _streaming_topk_impl(
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="oryx_topn_scan",
-    )(q, mat_t, aux)
+    )(q, mat_t, aux, *valid_arg)
     return (*finish(vals, idxs), *counts)
 
 
@@ -748,7 +810,8 @@ def _xla_scan_step(n_pad: int) -> int:
 
 
 def _xla_streaming_topk_impl(
-    mat_t, norms, scales, resid, resid_scales, queries, *, k, n_items, cosine
+    mat_t, norms, scales, resid, resid_scales, queries, *, k, n_items, cosine,
+    n_valid=None,
 ):
     """Fused XLA blocked scan over the feature-major layout: lax.scan
     streams [k_feat, block] item slices and reduces each block on the
@@ -763,7 +826,8 @@ def _xla_streaming_topk_impl(
     chunks by max provably contain the top-m items, and only those
     chunks' columns gather both int8 planes for an exact ~14-bit rescore
     after the scan. HIGHEST precision keeps the f32 GEMM on the fast CPU
-    path (the DEFAULT-precision CPU kernel is ~2x slower, measured)."""
+    path (the DEFAULT-precision CPU kernel is ~2x slower, measured).
+    ``n_valid``: as in ``_streaming_topk_impl``."""
     k_feat, n_pad = mat_t.shape
     b = queries.shape[0]
     quantized = scales is not None
@@ -786,8 +850,9 @@ def _xla_streaming_topk_impl(
     # add of a constant-folded [-inf over padded cols] row fuses like the
     # scale multiply does. Padded columns are all-zero so their dot is
     # finite (0) and 0 + -inf = -inf, never NaN.
+    masked_from = n_items if n_valid is None else n_valid.reshape(())
     bias = jnp.where(
-        jnp.arange(n_pad, dtype=jnp.int32) < n_items, 0.0, -jnp.inf
+        jnp.arange(n_pad, dtype=jnp.int32) < masked_from, 0.0, -jnp.inf
     )[None, :].astype(jnp.float32)
 
     def scores_for(i):
@@ -838,7 +903,7 @@ def _xla_streaming_topk_impl(
     pooli = jnp.moveaxis(cps, 0, 1).reshape(b, grid * kc)
     return _chunk_tail(
         mat_t, resid, scales, resid_scales, norms, q, qn, poolv, pooli,
-        k=k, kc=kc, n_items=n_items, cosine=cosine,
+        k=k, kc=kc, n_items=masked_from, cosine=cosine,
     )
 
 

@@ -26,6 +26,7 @@ round-trip per request.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 from dataclasses import dataclass
@@ -158,168 +159,235 @@ def top_k_scores_batch(uploaded, queries: np.ndarray, k: int, cosine: bool = Fal
 
 @dataclass
 class ShardedItemMatrix:
-    """Item matrix row-sharded over a device mesh: each device holds an
-    [n/d, k] slice plus its norms. The multi-chip serving layout — a
-    40M x 200 f32 model is 32 GB replicated but 2 GB/chip on a v5e-16
-    (SURVEY §2.12 request parallelism; the reference shards the same way
-    across LSH thread partitions on one host)."""
+    """Item matrix row-sharded over a device mesh, each shard in the
+    streaming kernel's layout: device s holds rows ``starts[s]`` to
+    ``starts[s] + counts[s]`` feature-major, ``[k_feat, cols]`` with
+    ``cols`` a BLOCK_N multiple, plus their norms (and, for int8, scales
+    and the residual plane). The serving layout of a catalog past one
+    chip's memory: 20M x 250 float32 is 20 GB, 5 GB a chip on a v5e host's
+    four. One pass scans every shard with the single-device kernel and
+    merges the ``[b, k]`` candidates across chips (``_sharded_scan_fn``).
+    Rows split evenly (the first ``n % d`` shards hold one more), so no
+    shard is empty once there is a row a device; the columns past a
+    shard's count are padding, masked in the kernel by that count."""
 
-    mat: jax.Array  # [n_pad, k], rows sharded over 'data'; f32/bf16/int8
-    norms: jax.Array  # [n_pad], sharded alike
+    mat_t: jax.Array  # [k_feat(_pad), d * cols], columns sharded over 'data'
+    norms: jax.Array  # [1, d * cols], sharded alike
     n_items: int
     mesh: object
-    scales: jax.Array | None = None  # [n_pad] per-row int8 dequant scale
-    resid: jax.Array | None = None  # [n_pad, k] int8 residual plane
-    resid_scales: jax.Array | None = None  # [n_pad] residual dequant scale
+    counts: tuple  # rows held by each shard; the last may grow into its padding
+    starts: tuple  # global row of each shard's first column
+    base: jax.Array  # [d] int32 = starts, one a shard
+    valid: jax.Array  # [d] int32 = counts, one a shard
+    scales: jax.Array | None = None  # [1, d * cols] per-row int8 dequant scale
+    resid: jax.Array | None = None  # [k_feat_pad, d * cols] int8 residual plane
+    resid_scales: jax.Array | None = None  # [1, d * cols]
+    features: int | None = None  # true feature count before int8 sublane padding
+
+    @property
+    def cols(self) -> int:
+        return self.mat_t.shape[1] // len(self.counts)
+
+
+def _shard_counts(n: int, d: int) -> tuple[tuple, tuple]:
+    counts = tuple(n // d + (1 if s < n % d else 0) for s in range(d))
+    starts = tuple(int(x) for x in np.cumsum((0,) + counts[:-1]))
+    return counts, starts
+
+
+def _per_shard(mesh, values) -> jax.Array:
+    """[d] int32, element s on device s."""
+    from oryx_tpu.parallel.mesh import shard_rows
+
+    return jax.device_put(np.asarray(values, dtype=np.int32), shard_rows(mesh))
+
+
+def _put_after(before: list, planes: list, device) -> list:
+    """Put one shard's host planes on its device once the slice before it
+    has left the host: one slice is on its way while the next is laid
+    out, so the host holds two slices (and a staging copy) beside the
+    matrix however many devices there are."""
+    jax.block_until_ready(before)
+    return [jax.device_put(p, device) for p in planes]
 
 
 def upload_sharded(matrix: np.ndarray, mesh, dtype=None) -> ShardedItemMatrix:
-    """Shard a packed [n, k] item matrix row-wise over `mesh`'s devices
-    (padded so every device gets an equal slice). ``dtype=int8``
-    row-quantizes exactly like the streaming handle: int8 codes sharded
-    with the rows, one f32 scale per row riding next to the norms."""
-    from oryx_tpu.parallel.mesh import (
-        data_sharding,
-        pad_to_multiple,
-        shard_layout,
-        shard_rows,
-    )
+    """Shard a packed [n, k] item matrix row-wise over `mesh`'s devices.
+    Each device's slice is cut from the host matrix, laid out for the
+    kernel and put on that device alone: no device ever holds the whole
+    matrix, and the host holds two slices beside the matrix (one on its
+    way to its device while the next is laid out), not a second matrix.
+    ``dtype=int8`` row-quantizes each slice exactly like the streaming
+    handle (codes, residual codes, one scale a row each)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    n, k = matrix.shape
-    d = mesh.devices.size
-    n_pad = pad_to_multiple(max(n, d), d)
-    mat = np.zeros((n_pad, k), dtype=np.float32)
-    mat[:n] = matrix
-    norms = np.linalg.norm(mat, axis=1)
-    if _is_int8(dtype):
-        q, s = _quantize_rows(mat)  # pad rows are all-zero -> scale 1.0
-        q2, s2 = _quantize_residual(mat, q, s)
-        up = ShardedItemMatrix(
-            mat=jax.device_put(jnp.asarray(q), data_sharding(mesh, 2)),
-            norms=jax.device_put(jnp.asarray(norms), shard_rows(mesh)),
-            n_items=n,
-            mesh=mesh,
-            scales=jax.device_put(jnp.asarray(s), shard_rows(mesh)),
-            resid=jax.device_put(jnp.asarray(q2), data_sharding(mesh, 2)),
-            resid_scales=jax.device_put(jnp.asarray(s2), shard_rows(mesh)),
+    from oryx_tpu.common.metrics import registry as metrics
+    from oryx_tpu.ops.pallas_topn import (
+        _INT8_FEAT_MULTIPLE,
+        BLOCK_N,
+        _ceil_to,
+        feature_major,
+        row_norms,
+    )
+    from oryx_tpu.parallel.mesh import DATA_AXIS
+
+    n, k_feat = matrix.shape
+    devices = list(mesh.devices.flat)
+    d = len(devices)
+    counts, starts = _shard_counts(n, d)
+    cols = max(BLOCK_N, _ceil_to(counts[0], BLOCK_N))
+    quantized = _is_int8(dtype)
+    kf = _ceil_to(k_feat, _INT8_FEAT_MULTIPLE) if quantized else k_feat
+
+    def a_row(values: np.ndarray, fill: float) -> np.ndarray:
+        out = np.full((1, cols), fill, dtype=np.float32)
+        out[0, : values.shape[0]] = values
+        return out
+
+    def planes_of(lo: int, cnt: int) -> dict[str, np.ndarray]:
+        rows = np.asarray(matrix[lo : lo + cnt], dtype=np.float32)
+        parts = {"norms": a_row(row_norms(rows), 0.0)}
+        if quantized:
+            q, s = _quantize_rows(rows)
+            q2, s2 = _quantize_residual(rows, q, s)
+            parts["mat_t"] = feature_major(q, cols, np.int8, kf)
+            parts["resid"] = feature_major(q2, cols, np.int8, kf)
+            parts["scales"] = a_row(s, 1.0)  # pad: scale 1.0
+            parts["resid_scales"] = a_row(s2, 1.0)
+        else:
+            parts["mat_t"] = feature_major(rows, cols, np.dtype(dtype or jnp.float32))
+        return parts
+
+    names = ("mat_t", "norms") + (("scales", "resid", "resid_scales") if quantized else ())
+    on_device: dict[str, list] = {name: [] for name in names}
+    on_its_way: list = []
+    for dev, lo, cnt in zip(devices, starts, counts):
+        parts = planes_of(lo, cnt)
+        on_its_way = _put_after(on_its_way, [parts[name] for name in names], dev)
+        for name, put in zip(names, on_its_way):
+            on_device[name].append(put)
+    jax.block_until_ready(on_its_way)
+
+    def assemble(name: str) -> jax.Array:
+        shards = on_device[name]
+        return jax.make_array_from_single_device_arrays(
+            (shards[0].shape[0], d * cols), NamedSharding(mesh, P(None, DATA_AXIS)), shards
         )
-    else:
-        up = ShardedItemMatrix(
-            mat=jax.device_put(
-                jnp.asarray(mat, dtype=dtype or jnp.float32), data_sharding(mesh, 2)
-            ),
-            norms=jax.device_put(jnp.asarray(norms), shard_rows(mesh)),
-            n_items=n,
-            mesh=mesh,
-        )
-    log.info("sharded item matrix, %d items, shards: %s", n, shard_layout(up.mat))
+
+    up = ShardedItemMatrix(
+        n_items=n, mesh=mesh, counts=counts, starts=starts,
+        base=_per_shard(mesh, starts), valid=_per_shard(mesh, counts),
+        features=k_feat if kf != k_feat else None,
+        **{name: assemble(name) for name in names},
+    )
+    metrics.gauge("serving.scan.shards").set(sum(1 for c in counts if c))
+    metrics.gauge("serving.scan.shard.rows-max").set(max(counts))
+    metrics.gauge("serving.scan.shard.rows-min").set(min(counts))
+    log.info("sharded item matrix, %d items, shards: %s", n, sharded_layout(up))
     return up
 
 
-def _sharded_topk_fn(mesh, k: int, cosine: bool, quantized: bool = False):
-    """shard_map'd scan: each device scores and top-k's its row shard,
-    then the tiny [b, k]-per-device candidates all-gather and a final
-    top-k merges them — the [b, n] score matrix never materializes
-    globally and no full-matrix collective ever runs. Quantized shards
-    upcast their int8 slice in-register and dequantize by the sharded
-    per-row scale after the dot."""
+def sharded_layout(up: ShardedItemMatrix) -> str:
+    """'dev0:(rows, features) dev1:(rows, features) ...': the rows each
+    device's slice holds, by the array's own addressable shards."""
+    feats = up.features if up.features is not None else up.mat_t.shape[0]
+    return " ".join(
+        f"dev{s.device.id}:({up.counts[(s.index[1].start or 0) // up.cols]}, {feats})"
+        for s in up.mat_t.addressable_shards
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_scan_fn(
+    mesh, k: int, cosine: bool, quantized: bool, indexed: bool, download_dtype,
+    interpret: bool | None = None,
+):
+    """The mesh's one scan program: under ``shard_map`` every device runs
+    the single-device dispatch of its backend on its own ``[k_feat, cols]``
+    slice (the Pallas kernel ``oryx_topn_scan`` on the TPU, its XLA twin
+    elsewhere; ``Precision.HIGHEST`` for float32 as there) for each group
+    of replicated query rows, adds its first row's global number to the
+    ids, and the ``[groups, b, k]`` candidates of all devices are gathered
+    and merged by one ``top_k`` over ``d * k``, on every device alike. No
+    ``[b, n]`` score matrix exists anywhere and nothing of the item matrix
+    crosses a chip. Candidates are gathered in shard order and ``top_k`` is
+    stable, so equal scores resolve to the lower global row, as they do on
+    one chip. Keyed by the mesh itself (``Mesh`` hashes by value).
+    ``interpret`` as in ``top_k_streaming_device``: None picks per backend,
+    False compiles the kernel (the compile rehearsal for a described mesh)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from jax import shard_map
-
+    from oryx_tpu.ops import pallas_topn as pt
     from oryx_tpu.parallel.mesh import DATA_AXIS
 
-    def local(mat, norms, scales, resid, resid_scales, queries, qn, shard_base, n_items_arr):
-        # mat: [n_local, k_feat]; shard_base: [1] global row offset;
-        # scales/resid/resid_scales: per-row dequant multipliers and the
-        # int8 residual plane (norms/mat dummies with the same sharding
-        # when not quantized, ignored below). Sharded scans sum both int8
-        # planes in full — per-shard candidate gathers aren't worth the
-        # collective plumbing, and the shards split the extra GEMM anyway.
-        m = mat.astype(jnp.float32) if quantized else mat
-        scores = jnp.dot(
-            queries, m.T, preferred_element_type=jnp.float32,
-            precision=_dot_precision(m.dtype),
-        )  # [b, n_local]
-        if quantized:
-            scores = scores * scales[None, :]
-            scores = scores + jnp.dot(
-                queries, resid.astype(jnp.float32).T,
-                preferred_element_type=jnp.float32,
-                precision=_dot_precision(mat.dtype),
-            ) * resid_scales[None, :]
-        if cosine:
-            scores = scores / jnp.maximum(norms[None, :] * qn, 1e-12)
-        # mask padding by global row position — NOT by zero norms, which
-        # would also drop genuine zero-vector items (cold rows score 0,
-        # same as the single-device path)
-        gcol = shard_base[0] + jnp.arange(mat.shape[0], dtype=jnp.int32)
-        scores = jnp.where(gcol[None, :] < n_items_arr[0], scores, -jnp.inf)
-        kk = min(k, mat.shape[0])
-        v, i = jax.lax.top_k(scores, kk)
-        i = i + shard_base[0]
-        # gather every device's candidates and merge: [b, d*kk] is tiny
-        v_all = jax.lax.all_gather(v, DATA_AXIS, axis=1, tiled=True)
-        i_all = jax.lax.all_gather(i, DATA_AXIS, axis=1, tiled=True)
-        vm, pos = jax.lax.top_k(v_all, min(k, v_all.shape[1]))
-        im = jnp.take_along_axis(i_all, pos, axis=1)
+    use_xla = pt._use_xla_scan(interpret)
+
+    def local(mat_t, norms, quant, base, valid, groups, x_dev):
+        scales, resid, resid_scales = quant if quantized else (None, None, None)
+        capacity = mat_t.shape[1]
+
+        def one(g):
+            q = (x_dev[g] if indexed else g).astype(jnp.float32)
+            shared = dict(k=k, n_items=capacity, cosine=cosine, n_valid=valid)
+            if use_xla:
+                return pt._xla_streaming_topk_impl(
+                    mat_t, norms, scales, resid, resid_scales, q, **shared
+                )
+            return pt._streaming_topk_impl(
+                mat_t, norms, scales, resid, resid_scales, q, interpret=bool(interpret), **shared
+            )
+
+        vals, idxs = jax.lax.map(one, groups)  # [groups, b, k], local ids
+        idxs = idxs + base[0]
+        v_all = jax.lax.all_gather(vals, DATA_AXIS, axis=2, tiled=True)
+        i_all = jax.lax.all_gather(idxs, DATA_AXIS, axis=2, tiled=True)
+        vm, pos = jax.lax.top_k(v_all, k)
+        im = jnp.take_along_axis(i_all, pos, axis=2)
+        if download_dtype is not None:
+            vm = vm.astype(download_dtype)
         return vm, im
 
+    cols_spec, shard_spec = P(None, DATA_AXIS), P(DATA_AXIS)
     in_specs = (
-        P(DATA_AXIS, None),
-        P(DATA_AXIS),
-        P(DATA_AXIS),  # per-row scales (or the norms dummy)
-        P(DATA_AXIS, None),  # residual plane (or the mat dummy)
-        P(DATA_AXIS),  # residual scales (or the norms dummy)
-        P(),  # queries replicated
-        P(),
-        P(DATA_AXIS),
-        P(),  # n_items replicated
+        cols_spec, cols_spec,
+        (cols_spec, cols_spec, cols_spec) if quantized else (),
+        shard_spec, shard_spec, P(), P() if indexed else (),
     )
-    out_specs = (P(), P())
-    # after the all_gather every device computes the same merge, but the
-    # replication checker can't infer that through top_k — disable it
-    smapped = shard_map(
-        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    # after the all_gather every device computes the same merge; the
+    # replication checker cannot see that through top_k
+    return jax.jit(
+        shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()), check_vma=False)
     )
-    return jax.jit(smapped)
+
+
+def _submit_sharded(up: ShardedItemMatrix, groups: np.ndarray, k: int, cosine: bool, x_dev=None):
+    """Enqueue one sharded pass for ``groups`` ([K, b, feat] query rows,
+    or [K, b] int32 rows of ``x_dev``); returns device (vals, idxs)
+    [K, b, k], replicated."""
+    from oryx_tpu.parallel.mesh import replicated
+
+    k = max(1, min(int(k), up.n_items))
+    quantized = up.scales is not None
+    fn = _sharded_scan_fn(
+        up.mesh, k, bool(cosine), quantized, x_dev is not None, _auto_download_dtype(up)
+    )
+    return fn(
+        up.mat_t, up.norms,
+        (up.scales, up.resid, up.resid_scales) if quantized else (),
+        up.base, up.valid,
+        jax.device_put(groups, replicated(up.mesh)),
+        x_dev if x_dev is not None else (),
+    )
 
 
 def top_k_sharded(
     up: ShardedItemMatrix, queries: np.ndarray, k: int, cosine: bool = False
 ) -> tuple[np.ndarray, np.ndarray]:
-    """(indices [b, k], scores [b, k]) over the mesh-sharded matrix."""
-    q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-    k = max(1, min(int(k), up.n_items))
-    qn = np.linalg.norm(q, axis=1, keepdims=True).astype(np.float32)
-    d = up.mesh.devices.size
-    per = up.mat.shape[0] // d
-    shard_base = jnp.arange(d, dtype=jnp.int32) * per
-    quantized = up.scales is not None
-    fn = _sharded_topk_cache(up.mesh, k, bool(cosine), quantized)
-    vals, idxs = fn(
-        up.mat,
-        up.norms,
-        up.scales if quantized else up.norms,
-        up.resid if quantized else up.mat,
-        up.resid_scales if quantized else up.norms,
-        jnp.asarray(q, dtype=jnp.float32 if quantized else up.mat.dtype),
-        jnp.asarray(qn),
-        shard_base,
-        jnp.asarray([up.n_items], dtype=jnp.int32),
-    )
-    return np.asarray(idxs), np.asarray(vals)
-
-
-_sharded_fns: dict = {}
-
-
-def _sharded_topk_cache(mesh, k: int, cosine: bool, quantized: bool = False):
-    key = (id(mesh), k, cosine, quantized)
-    fn = _sharded_fns.get(key)
-    if fn is None:
-        fn = _sharded_fns[key] = _sharded_topk_fn(mesh, k, cosine, quantized)
-    return fn
+    """(indices [b, k], scores [b, k]) over the mesh-sharded matrix: one
+    blocking pass (tools and tests; the serving path submits through the
+    batcher like any handle)."""
+    return submit_top_k(up, queries, k, cosine=cosine).result()
 
 
 # -- incremental updates ------------------------------------------------------
@@ -371,6 +439,66 @@ def _scatter_rows_t_q(
     return mat_t, norms, scales, resid, resid_scales
 
 
+@functools.lru_cache(maxsize=None)
+def _sharded_scatter_fn(mesh, quantized: bool):
+    """Row update of a sharded matrix: the touched rows' values travel to
+    every device, each writes the ones whose column lies in its own slice
+    (the rest fall outside and are dropped). Like the single-device
+    scatters it donates nothing: a pass in flight may hold the old slices."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from oryx_tpu.parallel.mesh import DATA_AXIS
+
+    def local(planes, cols, vals):
+        width = planes[0].shape[1]
+        at = cols - jax.lax.axis_index(DATA_AXIS) * width
+        at = jnp.where((at >= 0) & (at < width), at, width)  # outside: dropped
+        return tuple(
+            p.at[:, at].set(v.T.astype(p.dtype), mode="drop") for p, v in zip(planes, vals)
+        )
+
+    n = 5 if quantized else 2
+    spec = (P(None, DATA_AXIS),) * n
+    return jax.jit(
+        shard_map(
+            local, mesh=mesh, in_specs=(spec, P(), (P(),) * n),
+            out_specs=spec, check_vma=False,
+        )
+    )
+
+
+def _update_rows_sharded(up: ShardedItemMatrix, rows, values, new_norms, n_items):
+    """``update_rows`` for the sharded layout: each row lands on the shard
+    that holds it; rows past the catalog append into the last shard's
+    padding (``capacity``)."""
+    from oryx_tpu.parallel.mesh import replicated
+
+    count = up.n_items if n_items is None else int(n_items)
+    starts = np.asarray(up.starts)
+    shard = np.searchsorted(starts, rows, side="right") - 1
+    cols = (shard * up.cols + (rows - starts[shard])).astype(np.int32)
+    quantized = up.scales is not None
+    if quantized:
+        q, s = _quantize_rows(values)
+        q2, s2 = _quantize_residual(values, q, s)
+        kf = up.mat_t.shape[0]
+        pad = [(0, 0), (0, kf - q.shape[1])]  # int8 sublane padding
+        planes = (up.mat_t, up.norms, up.scales, up.resid, up.resid_scales)
+        vals = (np.pad(q, pad), new_norms[:, None], s[:, None], np.pad(q2, pad), s2[:, None])
+    else:
+        planes = (up.mat_t, up.norms)
+        vals = (values, new_norms[:, None])
+    out = _sharded_scatter_fn(up.mesh, quantized)(
+        planes, *jax.device_put((cols, vals), replicated(up.mesh))
+    )
+    counts = up.counts[:-1] + (count - up.starts[-1],)
+    grown = dict(zip(("mat_t", "norms", "scales", "resid", "resid_scales"), out))
+    return dataclasses.replace(
+        up, n_items=count, counts=counts, valid=_per_shard(up.mesh, counts), **grown
+    )
+
+
 def capacity(uploaded) -> int:
     """Row capacity of the handle (padding included); rows beyond
     ``n_items`` can be appended in place on the streaming layout. For an
@@ -381,6 +509,8 @@ def capacity(uploaded) -> int:
         return ivf_ops.capacity(uploaded)
     if isinstance(uploaded, StreamingItemMatrix):
         return uploaded.mat_t.shape[1]
+    if isinstance(uploaded, ShardedItemMatrix):
+        return uploaded.starts[-1] + uploaded.cols  # the last shard's padding
     mat, _ = uploaded
     return mat.shape[0]
 
@@ -412,6 +542,8 @@ def update_rows(uploaded, rows: np.ndarray, values: np.ndarray, n_items: int | N
         rows = np.concatenate([rows, np.repeat(rows[-1:], pad)])
         values = np.concatenate([values, np.repeat(values[-1:], pad, axis=0)])
     new_norms = np.linalg.norm(values, axis=1)
+    if isinstance(uploaded, ShardedItemMatrix):
+        return _update_rows_sharded(uploaded, rows, values, new_norms, n_items)
     if isinstance(uploaded, StreamingItemMatrix):
         count = uploaded.n_items if n_items is None else n_items
         if uploaded.scales is not None:
@@ -489,7 +621,8 @@ def _auto_download_dtype(uploaded) -> object | None:
     selection accumulates in f32 — shipping them back over a result-byte-
     bound link as bf16 cuts the per-hit payload from 8 B to 6 B without
     changing the on-device ranking. f32 matrices keep f32 results."""
-    mat = uploaded.mat_t if isinstance(uploaded, StreamingItemMatrix) else uploaded[0]
+    layouts = (StreamingItemMatrix, ShardedItemMatrix)
+    mat = uploaded.mat_t if isinstance(uploaded, layouts) else uploaded[0]
     # int8 scores carry ~0.4% quantization error already — bf16 wire dtype
     # loses nothing that selection kept
     return jnp.bfloat16 if mat.dtype in (jnp.bfloat16, jnp.int8) else None
@@ -538,6 +671,8 @@ def submit_top_k_multi(
         vals, ids = ivf_ops.top_k_device(uploaded, q, k, cosine=cosine, nprobe=nprobe)
         return _async_multi_handle(vals[None], ids[None], q.shape[0])
     q_kb, n = _group_pad(q, scan_batch)
+    if isinstance(uploaded, ShardedItemMatrix):
+        return _async_multi_handle(*_submit_sharded(uploaded, q_kb, k, cosine), n)
     dl = _auto_download_dtype(uploaded)
     if isinstance(uploaded, StreamingItemMatrix):
         vals, idxs = top_k_streaming_device_multi(
@@ -552,10 +687,16 @@ def submit_top_k_multi(
     return _async_multi_handle(vals, idxs, n)
 
 
-def upload_queries(queries: np.ndarray) -> jax.Array:
+def upload_queries(queries: np.ndarray, mesh=None) -> jax.Array:
     """Stage a [m, feat] query-vector matrix on device (float32), for
-    index-submitted scans."""
-    return jnp.asarray(np.atleast_2d(np.asarray(queries, np.float32)))
+    index-submitted scans; with a ``mesh`` (the sharded item layout) a
+    copy on each of its devices, so every shard gathers its rows itself."""
+    queries = np.atleast_2d(np.asarray(queries, np.float32))
+    if mesh is None:
+        return jnp.asarray(queries)
+    from oryx_tpu.parallel.mesh import replicated
+
+    return jax.device_put(queries, replicated(mesh))
 
 
 def upload_random(
@@ -714,6 +855,10 @@ def submit_top_k_multi_indexed(
         )
         return _async_multi_handle(vals[None], ids[None], len(idx))
     idx_kb_np, n = _group_pad(idx, scan_batch)
+    if isinstance(uploaded, ShardedItemMatrix):
+        return _async_multi_handle(
+            *_submit_sharded(uploaded, idx_kb_np, k, cosine, x_dev=x_dev), n
+        )
     idx_kb = jnp.asarray(idx_kb_np)
     dl = _auto_download_dtype(uploaded)
     if isinstance(uploaded, StreamingItemMatrix):
@@ -745,6 +890,11 @@ def submit_top_k(
         vals.copy_to_host_async()
         ids.copy_to_host_async()
         return TopNHandle(vals, ids)
+    if isinstance(uploaded, ShardedItemMatrix):
+        q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+        return _async_multi_handle(
+            *_submit_sharded(uploaded, q[None], k, cosine), q.shape[0]
+        )
     dl = _auto_download_dtype(uploaded)
     if isinstance(uploaded, StreamingItemMatrix):
         vals, idxs = top_k_streaming_device(

@@ -7,7 +7,6 @@ jax.sharding.Mesh and lets XLA insert ICI/DCN collectives.
 
 from oryx_tpu.parallel.mesh import (  # noqa: F401
     get_mesh,
-    data_sharding,
     replicated,
     shard_rows,
 )

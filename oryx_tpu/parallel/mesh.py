@@ -77,11 +77,6 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def data_sharding(mesh: Mesh, ndim: int, axis: str = DATA_AXIS) -> NamedSharding:
-    """Shard dim 0 over `axis` for an ndim-dim array."""
-    return NamedSharding(mesh, P(axis, *([None] * (ndim - 1))))
-
-
 def shard_layout(arr) -> str:
     """Where an array's data actually is: 'dev0:(r, c) dev1:(r, c) ...'
     from its addressable shards (logged by the sharded paths, so a run can

@@ -402,6 +402,7 @@ class TopNBatcher:
         # (per request for the queue wait); none is read back here
         self._pass_seq = 0  # dispatcher thread only
         self._m_queue_wait = _metrics.histogram("serving.batcher.queue-wait.seconds")
+        self._m_sharded_queries = _metrics.counter("serving.scan.sharded.queries")
         self._m_passes = _metrics.counter("serving.batcher.passes")
         self._m_pass_rows = _metrics.counter("serving.batcher.pass.rows")
         self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
@@ -674,6 +675,12 @@ class TopNBatcher:
             ):
                 kk = _k_bucket(max(e.k for e in entries))
                 nprobe = self._group_nprobe(entries)
+                if isinstance(entries[0].uploaded, topn_ops.ShardedItemMatrix):
+                    # beside the count by submit kind, which the submit
+                    # makes next (a reader's snapshot falls between the two
+                    # once in a great while, not once in six passes): these
+                    # rows are scanned on every shard and merged across chips
+                    self._m_sharded_queries.inc(n)
                 if indexed:
                     handle = self._submit_indexed(entries, cosine, kk, nprobe, padded)
                 else:

@@ -357,3 +357,117 @@ def test_library_is_keyed_by_host_cpu_as_well_as_sources(monkeypatch):
     elsewhere = native._library_target()
     assert elsewhere != here
     assert os.path.dirname(elsewhere) == os.path.dirname(here)
+
+
+# -- bulk paths (PR 26): a model's rows arrive in batches past the store's
+# bulk threshold (32,768 rows), are filled shard by shard on the host's
+# threads, and are packed the same way -------------------------------------
+
+BULK = 40_000
+
+
+def _bulk(seed: int, n: int = BULK, dim: int = 6, dups: int = 2_000):
+    gen = np.random.default_rng(seed)
+    ids = [f"i{j}" for j in range(n)]
+    # duplicates late in the batch: the later row must win, as in sequence
+    ids += [f"i{j}" for j in gen.integers(0, n, size=dups)]
+    return ids, gen.standard_normal((len(ids), dim)).astype(np.float32)
+
+
+@needs_native
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_bulk_set_batch_equals_the_python_store(seed):
+    ids, mat = _bulk(seed)
+    native, plain = NativeFeatureVectors(), FeatureVectors()
+    native.set_batch(ids, mat)
+    plain.set_batch(ids, mat)
+    assert native.size() == plain.size() == BULK
+    probe = ids[-50:] + ids[:50]
+    got, valid = native.get_batch(probe)
+    want, _ = plain.get_batch(probe)
+    assert valid.all()
+    np.testing.assert_array_equal(got, want)
+    # recency marked for every row of the batch
+    native.retain_recent_and_ids([])
+    assert native.size() == BULK
+
+
+@needs_native
+@pytest.mark.parametrize("shards", [1, 3, 16])
+def test_bulk_pack_rows_follow_their_ids(shards):
+    ids, mat = _bulk(7)
+    store = NativeFeatureVectors(num_shards=shards)
+    for lo in range(0, len(ids), 1 << 14):  # below the threshold: one thread
+        store.set_batch(ids[lo : lo + (1 << 14)], mat[lo : lo + (1 << 14)])
+    last = {id_: row for row, id_ in enumerate(ids)}
+    got_ids, got = store.to_matrix()
+    assert len(got_ids) == len(set(got_ids)) == BULK
+    np.testing.assert_array_equal(got, mat[[last[i] for i in got_ids]])
+    assert store.ids() == got_ids  # the same snapshot order with and without rows
+
+
+@needs_native
+def test_bulk_pack_skips_freed_slots_and_reuses_them():
+    ids, mat = _bulk(9, dups=0)
+    store = NativeFeatureVectors()
+    store.set_batch(ids, mat)
+    store.retain_recent_and_ids([])  # all recent: all stay; recency reset
+    keep = set(ids[::3])
+    store.retain_recent_and_ids(keep)  # two thirds of the slots are freed
+    got_ids, got = store.to_matrix()
+    assert set(got_ids) == keep
+    np.testing.assert_array_equal(got, mat[[int(i[1:]) for i in got_ids]])
+    new_ids = [f"n{j}" for j in range(BULK)]
+    store.set_batch(new_ids, mat + 1.0)  # fills the freed slots, then grows
+    got_ids, got = store.to_matrix()
+    assert set(got_ids) == keep | set(new_ids)
+    want = {**{i: mat[int(i[1:])] for i in keep}, **{f"n{j}": mat[j] + 1.0 for j in range(BULK)}}
+    np.testing.assert_array_equal(got, np.stack([want[i] for i in got_ids]))
+    recent: set[str] = set()
+    store.add_all_recent_to(recent)
+    assert recent == set(new_ids)
+
+
+@needs_native
+@pytest.mark.parametrize("odd", ["", "a\0b", "\0", "é\0ü", "日本"])
+def test_pack_keeps_ids_no_delimiter_is_safe_for(odd):
+    store = NativeFeatureVectors()
+    ids = ["plain", odd, "last"]
+    mat = np.arange(9, dtype=np.float32).reshape(3, 3)
+    store.set_batch(ids, mat)
+    got_ids, got = store.to_matrix()
+    assert sorted(got_ids) == sorted(ids)
+    np.testing.assert_array_equal(got, mat[[ids.index(i) for i in got_ids]])
+    assert sorted(store.ids()) == sorted(ids)
+    store.remove_vector(odd)
+    assert sorted(store.ids()) == ["last", "plain"]
+
+
+@needs_native
+def test_bulk_writers_and_packers_side_by_side():
+    """Bulk fills from two threads while a third packs: every snapshot is
+    whole (each id once, its row one of the values written for it)."""
+    ids, mat = _bulk(11, dups=0)
+    store = NativeFeatureVectors()
+    store.set_batch(ids, mat)
+    errors: list[str] = []
+
+    def write(shift):
+        for _ in range(3):
+            store.set_batch(ids, mat + shift)
+
+    def pack():
+        for _ in range(4):
+            got_ids, got = store.to_matrix()
+            base = mat[[int(i[1:]) for i in got_ids]]
+            delta = got - base
+            if len(set(got_ids)) != BULK or not np.isin(np.round(delta), (0.0, 1.0, 2.0)).all():
+                errors.append("torn snapshot")
+
+    threads = [threading.Thread(target=write, args=(s,)) for s in (1.0, 2.0)]
+    threads.append(threading.Thread(target=pack))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors

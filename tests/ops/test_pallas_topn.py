@@ -416,3 +416,39 @@ def test_counting_flag_off_leaves_the_kernel_two_outputs():
             up.mat_t, up.norms, None, None, None, jnp.zeros((ptn.LOCAL_TOPK_BATCH + 8, 8)),
             k=16, n_items=5000, cosine=False, interpret=True, count_rounds=True,
         )
+
+
+# -- host layout helpers (PR 26): the transposing copy and the norms run in
+# bands of rows on a few threads and must equal the one-thread numpy forms --
+
+
+@pytest.mark.parametrize(
+    "n,k,cols,dtype,height",
+    [
+        (5, 3, 8, np.float32, None),
+        (70_001, 7, 81_920, np.float32, None),  # two bands, padding past n
+        (131_072, 4, 131_072, np.float32, None),  # bands end on n, no padding
+        (70_001, 7, 81_920, jnp.bfloat16, None),
+        (66_000, 50, 81_920, np.int8, 64),  # int8 planes pad the features too
+    ],
+)
+def test_feature_major_equals_the_plain_transposing_copy(n, k, cols, dtype, height):
+    from oryx_tpu.ops.pallas_topn import feature_major
+
+    gen = np.random.default_rng(n)
+    rows = (gen.standard_normal((n, k)) * 40).astype(np.int8 if dtype is np.int8 else np.float32)
+    want = np.zeros((height or k, cols), dtype=dtype)
+    want[:k, :n] = rows.T
+    got = feature_major(rows, cols, dtype, height)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+@pytest.mark.parametrize("n,k", [(1, 3), (65_536, 5), (150_000, 250)])
+def test_row_norms_equal_numpy_bit_for_bit(n, k):
+    from oryx_tpu.ops.pallas_topn import row_norms
+
+    rows = np.random.default_rng(k).standard_normal((n, k)).astype(np.float32)
+    got = row_norms(rows)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, np.linalg.norm(rows, axis=1))

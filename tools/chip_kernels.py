@@ -314,11 +314,24 @@ class Checks:
             from oryx_tpu.parallel.mesh import get_mesh
 
             def sharded():
-                up = topn_ops.upload_sharded(mat, get_mesh(), dtype=jnp.float32)
-                shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat.addressable_shards]
+                # the served shapes: vector submit, then the same rows by
+                # index into the user matrix staged on every device, then
+                # a row updated on each shard
+                mesh = get_mesh()
+                up = topn_ops.upload_sharded(mat, mesh, dtype=jnp.float32)
+                shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat_t.addressable_shards]
                 q = x[:64]
                 idx, vals = topn_ops.top_k_sharded(up, q, k)
-                return {**verify("float32", False, q, idx, vals), "shards": shards}
+                by_row, _ = topn_ops.submit_top_k_multi_indexed(
+                    up, topn_ops.upload_queries(x, mesh=mesh), np.arange(64, dtype=np.int32), k
+                ).result()
+                expect(np.array_equal(idx, by_row), "indexed submit differs from vector submit")
+                last = np.asarray(up.starts) + np.asarray(up.counts) - 1
+                best = 50.0 * x[: len(last)]
+                top, _ = topn_ops.top_k_sharded(topn_ops.update_rows(up, last, best), best, 1)
+                expect(top[:, 0].tolist() == last.tolist(), "a row update missed its shard")
+                return {**verify("float32", False, q, idx, vals), "shards": shards,
+                        "layout": topn_ops.sharded_layout(up)}
 
             self.check(f"scan/{features}f/float32/dot/sharded", sharded)
 
