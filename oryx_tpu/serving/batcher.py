@@ -27,13 +27,15 @@ Under load the queue naturally fills while the device is busy, so batch
 size adapts to concurrency automatically (1 request → batch of 1,
 hundreds of concurrent requests → full batches).
 
-By default the scheduler is ADAPTIVE: instead of fixed ``max_batch`` /
-``max_inflight`` knobs, the completer keeps an EWMA of dispatch latency
-and sizes both from it — inflight depth targets a wall-clock latency
-budget (slow dispatches → shallower pipeline, so a queued request never
-sits behind seconds of device work), and the microbatch ceiling grows
-while dispatches come back faster than the budget. Passing explicit
-``max_batch`` / ``max_inflight`` pins the legacy fixed behavior.
+TWO passes are in flight at most: one executing and one queued behind
+it, the least at which the device does not wait for the host as long as
+the host refills a freed slot in less than one pass. A request then waits
+behind one pass, not behind a pipeline of them. Where the pass is shorter
+than the refill (a sub-millisecond pass on a small catalog) the host is
+what bounds the rate, and a deeper pipeline only cuts the same requests
+into more, emptier passes: measured, two is no slower there either
+(PERF.md section 6, PR 28). An explicit ``max_inflight`` pins another
+depth, ``max_batch`` another ceiling than ``DEFAULT_MAX_BATCH`` rows.
 
 The dispatcher also fixes bucket fragmentation under backpressure: when
 every inflight slot is taken, draining the queue in eager dribbles would
@@ -57,7 +59,6 @@ device trace records. None of it feeds a scheduling decision.
 from __future__ import annotations
 
 import logging
-import os
 import queue
 import threading
 import time
@@ -75,14 +76,12 @@ from oryx_tpu.tenancy.context import current_tenant
 
 log = logging.getLogger(__name__)
 
-# Adaptive-scheduler tuning (oryx.serving.scan.* in reference.conf maps
-# onto these env knobs via the serving layer).
-LATENCY_BUDGET_MS = float(os.environ.get("ORYX_BATCHER_LATENCY_BUDGET_MS", 50.0))
-EWMA_ALPHA = 0.25  # completer's dispatch-latency smoothing
-MIN_ADAPTIVE_BATCH = 256  # one full fused-scan group
-MAX_ADAPTIVE_BATCH = 4096
-MIN_INFLIGHT = 2  # always enough to overlap host prep with device work
-MAX_INFLIGHT = 32
+# Rows a pass may coalesce unless ``max_batch`` says otherwise: where the
+# former adaptive ceiling sat in every measured cell (PERF.md, PR 28).
+DEFAULT_MAX_BATCH = 4096
+# Passes in flight unless ``max_inflight`` says otherwise: one executing,
+# one queued behind it.
+MIN_INFLIGHT = 2
 
 # Queue-wait EWMA (the admission controller's pressure signal): smoothing
 # factor per dispatch, plus an idle decay so the signal fades once the
@@ -378,20 +377,12 @@ class TopNBatcher:
         tenant_weights: dict[str, float] | None = None,
         fair_quantum: float = 8.0,
     ) -> None:
-        # None => adaptive: the completer resizes the knob from its EWMA
-        # of dispatch latency; an explicit value pins it (legacy behavior,
-        # and what most unit tests use to force specific shapes)
-        self._adaptive_batch = max_batch is None
-        self._adaptive_inflight = max_inflight is None
-        self.max_batch = MIN_ADAPTIVE_BATCH if max_batch is None else int(max_batch)
-        self._inflight_cap = (
-            MIN_INFLIGHT + 2 if max_inflight is None else int(max_inflight)
-        )
+        self.max_batch = DEFAULT_MAX_BATCH if max_batch is None else int(max_batch)
+        self._inflight_cap = MIN_INFLIGHT if max_inflight is None else int(max_inflight)
         # bounded queue (oryx.serving.overload.max-queue): None = unbounded
         self._max_queue = None if max_queue is None else int(max_queue)
-        self._ewma_ms: float | None = None
         # queue-wait EWMA (the admission controller's primary pressure
-        # signal); guarded by _flight_cv like the dispatch EWMA
+        # signal); guarded by _flight_cv
         self._queue_wait_ewma_ms = 0.0
         self._last_wait_obs = time.monotonic()
         # DRR service across per-tenant sub-queues; FIFO-equivalent when
@@ -407,11 +398,12 @@ class TopNBatcher:
         self._m_pass_rows = _metrics.counter("serving.batcher.pass.rows")
         self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
         self._m_pass_depth_sum = _metrics.counter("serving.batcher.pass.inflight-depth-sum")
+        # counts the times the depth target takes a new value: the depth is
+        # fixed, so it stays 0; registered so that a reader of it reads a
+        # number and not nothing, as it would where the counter is missing
         self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
         self._m_pass_seconds = _metrics.histogram("serving.batcher.pass.seconds")
         self._m_deliver_seconds = _metrics.histogram("serving.batcher.deliver.seconds")
-        # inflight tracked under a Condition (not a Semaphore) so the
-        # adaptive cap can move while dispatches are blocked on it
         self._flight_cv = threading.Condition()
         self._inflight_count = 0
         self._state_lock = threading.Lock()  # serializes score-enqueue vs close
@@ -505,12 +497,7 @@ class TopNBatcher:
             return None
         batch = [first]
         coalesced = 0
-        # snapshot the adaptive ceiling under _flight_cv: the completer
-        # resizes it in _observe_latency while this dispatcher loop reads
-        # it (oryxlint lockset ORX104); one stable cap per batch-take
-        with self._flight_cv:
-            max_batch = self.max_batch
-        while len(batch) < max_batch:
+        while len(batch) < self.max_batch:
             try:
                 e = self._queue.get_nowait()
             except queue.Empty:
@@ -564,41 +551,11 @@ class TopNBatcher:
             _metrics.gauge("serving.batcher.inflight").set(self._inflight_count)
             return self._inflight_count
 
-    def _release_slot(self, latency_s: float | None = None) -> None:
+    def _release_slot(self) -> None:
         with self._flight_cv:
             self._inflight_count -= 1
             _metrics.gauge("serving.batcher.inflight").set(self._inflight_count)
-            if latency_s is not None:
-                self._observe_latency(latency_s * 1000.0)
             self._flight_cv.notify()
-
-    def _observe_latency(self, ms: float) -> None:
-        """EWMA the dispatch latency and resize the adaptive knobs from it
-        (caller holds ``_flight_cv``). Inflight depth targets the latency
-        budget — a queued request waits at most ``depth`` dispatches, so
-        depth ~ budget / per-dispatch cost (+2 keeps the host/device
-        overlap even when one dispatch blows the whole budget). The batch
-        ceiling widens while dispatches stay comfortably inside the
-        budget and narrows when they blow past it."""
-        self._ewma_ms = (
-            ms
-            if self._ewma_ms is None
-            else EWMA_ALPHA * ms + (1.0 - EWMA_ALPHA) * self._ewma_ms
-        )
-        _metrics.gauge("serving.batcher.dispatch_ewma_ms").set(self._ewma_ms)
-        if self._adaptive_inflight:
-            cap = int(
-                min(max(LATENCY_BUDGET_MS / max(self._ewma_ms, 1e-3) + 2, MIN_INFLIGHT), MAX_INFLIGHT)
-            )
-            if cap != self._inflight_cap:
-                self._inflight_cap = cap
-                self._m_cap_changes.inc()
-        if self._adaptive_batch:
-            if self._ewma_ms > LATENCY_BUDGET_MS and self.max_batch > MIN_ADAPTIVE_BATCH:
-                self.max_batch //= 2
-            elif self._ewma_ms < LATENCY_BUDGET_MS / 2 and self.max_batch < MAX_ADAPTIVE_BATCH:
-                self.max_batch *= 2
-            self.max_batch = max(MIN_ADAPTIVE_BATCH, min(self.max_batch, MAX_ADAPTIVE_BATCH))
 
     def _group_nprobe(self, entries: list[_Entry]) -> int | None:
         """Resolve a reduced-probe override into a concrete ``nprobe`` for
@@ -777,7 +734,7 @@ class TopNBatcher:
                 for e in entries:
                     e.error = exc
             finally:
-                self._release_slot(latency)
+                self._release_slot()
                 _record_pass_spans(item, time.time())
                 for e in entries:
                     e.done.set()
@@ -814,14 +771,15 @@ def configure_scheduler(
     """Pin the process-wide batcher's scheduler knobs (the serving layer
     maps ``oryx.serving.scan.*`` / ``oryx.serving.overload.max-queue``
     here at startup, before the default batcher spins up). ``None`` leaves
-    a knob adaptive (for ``max_queue``: unbounded)."""
-    global LATENCY_BUDGET_MS
+    a knob at its default (for ``max_queue``: unbounded).
+    ``latency_budget_ms`` is taken so that a configuration that sets it
+    keeps loading; nothing reads it since the depth stopped following a
+    latency budget."""
+    del latency_budget_ms
     with _default_lock:
         _default_init["max_batch"] = max_batch
         _default_init["max_inflight"] = max_inflight
         _default_init["max_queue"] = max_queue
-        if latency_budget_ms is not None:
-            LATENCY_BUDGET_MS = float(latency_budget_ms)
 
 
 def configure_fairness(

@@ -2,6 +2,7 @@
 submits without changing any per-request answer."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -131,8 +132,6 @@ def test_large_group_routes_through_fused_multi(monkeypatch):
         threads = [threading.Thread(target=run, args=(j,)) for j in range(len(queries))]
         for t in threads:
             t.start()
-        import time
-
         time.sleep(0.3)  # let every request enqueue
         gate.set()
         for t in threads:
@@ -196,8 +195,8 @@ def test_every_pass_is_on_the_record_and_the_counts_agree(indexed):
     assert 1 <= got["passes"] <= n
     assert got["pass_seconds"] == got["deliveries"] == got["passes"]
     assert got["padded"] >= got["rows"] and got["padded"] % 8 == 0
-    # each pass took a slot, and the cap never passes 32: the mean depth at submit
-    assert got["passes"] <= got["depth_sum"] <= 32 * got["passes"]
+    # each pass took a slot, and two are in flight at most: the mean depth at submit
+    assert got["passes"] <= got["depth_sum"] <= 2 * got["passes"]
 
 
 def test_fused_vector_path_counts_the_multiple_of_the_scan_batch():
@@ -244,26 +243,232 @@ def test_a_dispatch_that_raises_releases_its_slot_and_counts_no_pass(monkeypatch
     assert got["pass_seconds"] == 1
 
 
-def test_inflight_cap_changes_are_counted_when_the_cap_moves():
+# -- the in-flight depth ---------------------------------------------------------
+#
+# A stub device in place of `submit_top_k`: one pass at a time, `pass_s`
+# each, its results on the host when it ends; a submit costs the host
+# `submit_s`. No device, and every test sleeps well under 50 ms in all.
+
+
+class _StubDevice:
+    def __init__(self, pass_s: float = 0.0, submit_s: float = 0.0) -> None:
+        self.pass_s, self.submit_s = pass_s, submit_s
+        self.gate: threading.Event | None = None  # set: results wait for it (a stalled completer)
+        self.groups: list[np.ndarray] = []  # a pass's rows by the number they carry (padding: 0)
+        self._free_at = 0.0
+        self._lock = threading.Lock()
+
+    def submit(self, uploaded, queries, kk, cosine=False, nprobe=None):
+        if self.submit_s:
+            time.sleep(self.submit_s)
+        with self._lock:
+            self.groups.append(queries[:, 0].copy())
+            ready_at = self._free_at = max(time.perf_counter(), self._free_at) + self.pass_s
+        # row r answers with the number its query carries, so that a
+        # request handed another request's row would show
+        idx = np.repeat(queries[:, :1].astype(np.int64), kk, axis=1)
+        return _StubHandle(ready_at, self.gate, idx, idx.astype(np.float32))
+
+
+class _StubHandle:
+    def __init__(self, ready_at, gate, idx, vals) -> None:
+        self._ready_at, self._gate, self._out = ready_at, gate, (idx, vals)
+
+    def result(self):
+        if self._gate is not None:
+            assert self._gate.wait(10)
+        wait = self._ready_at - time.perf_counter()
+        if wait > 0:
+            time.sleep(wait)
+        return self._out
+
+
+def _ask(b, numbers, tenant=None, k=3) -> dict:
+    """One thread a number: each asks the batcher with a query that
+    carries its number. The threads and {number: served idx}, for `_join`."""
+    from oryx_tpu.tenancy.context import tenant_scope
+
+    got: dict = {}
+    uploaded = object()
+
+    def one(n):
+        with tenant_scope(tenant(n) if tenant else None):
+            got[n] = b.score(uploaded, np.full(4, n, np.float32), k)[0]
+
+    threads = [threading.Thread(target=one, args=(n,)) for n in numbers]
+    for t in threads:
+        t.start()
+    return {"threads": threads, "got": got}
+
+
+def _join(asked) -> None:
+    for t in asked["threads"]:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    for n, idx in asked["got"].items():
+        assert (idx == n).all()
+
+
+def _wait_until(cond, seconds=10.0) -> None:
+    deadline = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < deadline, "the batcher never got there"
+        time.sleep(0.0005)
+
+
+@pytest.fixture
+def stub(monkeypatch):
+    device = _StubDevice()
+    monkeypatch.setattr(batcher_mod.topn_ops, "submit_top_k", device.submit)
+    return device
+
+
+def _stall(b, stub) -> list:
+    """Fill every slot with a pass whose results wait for `stub.gate`."""
+    stub.gate = threading.Event()
+    held = []
+    for n in range(b._inflight_cap):
+        held.append(_ask(b, [1000 + n]))
+        _wait_until(lambda: b._inflight_count == n + 1)
+    return held
+
+
+@pytest.mark.parametrize(
+    "pass_ms, submit_ms",
+    [
+        (3.0, 0.0),  # a cell: the host refills a slot well inside a pass
+        (3.0, 1.5),  # the refill is half the pass
+        (0.2, 2.0),  # a small catalog: the pass ten times shorter than the refill
+        (0.0, 0.0),  # the CPU backend under tier-1: a dispatch that is done when it returns
+    ],
+    ids=["refill-inside-the-pass", "refill-half-the-pass", "pass-a-tenth-of-the-refill", "synchronous"],
+)
+def test_two_passes_are_in_flight_whatever_the_pass_and_the_refill_take(stub, pass_ms, submit_ms):
+    """The depth rests at two and `inflight-cap.changes` does not move:
+    measured on the chip, a deeper pipeline buys nothing where the pass is
+    shorter than the refill either (PERF.md, PR 28)."""
+    stub.pass_s, stub.submit_s = pass_ms / 1000.0, submit_ms / 1000.0
     b = TopNBatcher()
+    before = _pass_record()
     try:
-        counter = b._m_cap_changes
-        start = counter.value
-        with b._flight_cv:
-            b._observe_latency(10.0)  # 50 / 10 + 2 = 7: a step from the initial 4
-            assert b._inflight_cap == 7 and counter.value == start + 1
-            b._observe_latency(10.0)  # same cap: no step
-            assert counter.value == start + 1
-            for _ in range(40):
-                b._observe_latency(100.0)  # EWMA -> 100 ms: 50 / 100 + 2 = 2
-            assert b._inflight_cap == 2 and counter.value > start + 1
+        start = b._m_cap_changes.value
+        assert b._inflight_cap == batcher_mod.MIN_INFLIGHT == 2
+        _join(_ask(b, range(1, 13)))
+        assert b._inflight_cap == 2 and b._m_cap_changes.value == start
     finally:
         b.close()
-    pinned = TopNBatcher(max_inflight=3)
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    assert got["rows"] == 12 and got["passes"] <= got["depth_sum"] <= 2 * got["passes"]
+
+
+def test_the_third_group_waits_for_a_slot_and_takes_the_arrivals(stub):
+    b = TopNBatcher()
     try:
-        start = pinned._m_cap_changes.value
-        with pinned._flight_cv:
-            pinned._observe_latency(1.0)
-        assert pinned._inflight_cap == 3 and pinned._m_cap_changes.value == start
+        held = _stall(b, stub)
+        assert b._inflight_count == 2 and len(stub.groups) == 2
+        third = _ask(b, [3])
+        _wait_until(lambda: b._queue.qsize() == 0)  # the dispatcher has it and waits for a slot
+        late = _ask(b, [4, 5, 6])
+        _wait_until(lambda: b._queue.qsize() == 0)  # ... and takes what arrives meanwhile
+        assert b._inflight_count == 2 and len(stub.groups) == 2
+        stub.gate.set()
+        for asked in held + [third, late]:
+            _join(asked)
+        assert len(stub.groups) == 4  # 3 alone (its own matrix handle), then 4, 5 and 6 in one pass
     finally:
-        pinned.close()
+        b.close()
+
+
+@pytest.mark.parametrize("depth", [1, 3, 5])
+def test_an_explicit_max_inflight_pins_the_depth(stub, depth):
+    b = TopNBatcher(max_inflight=depth)
+    before = _pass_record()
+    try:
+        start = b._m_cap_changes.value
+        held = _stall(b, stub)
+        assert len(held) == depth
+        rest = _ask(b, [4, 5])
+        _wait_until(lambda: b._queue.qsize() == 0)  # the dispatcher holds them, waiting for a slot
+        assert b._inflight_count == depth and len(stub.groups) == depth
+        stub.gate.set()
+        for asked in held + [rest]:
+            _join(asked)
+        assert b._inflight_cap == depth and b._m_cap_changes.value == start
+    finally:
+        b.close()
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    assert got["passes"] == depth + 1 and got["depth_sum"] <= depth * got["passes"]
+
+
+def test_the_cap_changes_counter_is_there_and_reads_zero():
+    """`inflight_cap_changes.open/.x4` (benchmark/layer_metrics) read the
+    counter's delta: a missing counter reads nothing, not 0."""
+    b = TopNBatcher()
+    try:
+        snap = batcher_mod._metrics.snapshot()
+        assert "value" in snap["serving.batcher.inflight-cap.changes"]
+        assert b._m_cap_changes is batcher_mod._metrics.counter("serving.batcher.inflight-cap.changes")
+    finally:
+        b.close()
+
+
+def test_the_latency_budget_still_loads_and_governs_nothing():
+    batcher_mod.configure_scheduler(max_inflight=None, latency_budget_ms=5.0)
+    try:
+        b = batcher_mod.get_default_batcher()
+        assert b._inflight_cap == 2 and b.max_batch == batcher_mod.DEFAULT_MAX_BATCH
+    finally:
+        batcher_mod.close_default_batcher()
+        batcher_mod.configure_scheduler()
+
+
+@pytest.mark.parametrize("max_inflight", [None, 4], ids=["the-rule", "four-as-the-old-rule-held"])
+def test_a_backlog_behind_a_stalled_completer_leaves_in_two_passes(stub, max_inflight):
+    """128 entries arrive while every slot is taken and the completer is
+    stalled (a pause of the process): they leave in at most two passes,
+    and the wait the ladder is told of is the stall, as it was at the
+    depth the old rule held in the cells."""
+    waits = {}
+    for depth in (max_inflight, 4):
+        stub.groups.clear()
+        b = TopNBatcher(max_inflight=depth)
+        try:
+            slots = b._inflight_cap
+            first = _stall(b, stub)
+            t0 = time.monotonic()
+            backlog = _ask(b, range(128))
+            _wait_until(lambda: b._queue.qsize() == 0)
+            time.sleep(0.01 - min(0.01, time.monotonic() - t0))  # the stall: 10 ms
+            stub.gate.set()
+            _join(backlog)
+            for asked in first:
+                _join(asked)
+            assert len(stub.groups) - slots <= 2
+            waits[depth] = b.queue_wait_ewma_ms()
+        finally:
+            b.close()
+    assert waits[max_inflight] <= 1.25 * waits[4] + 2.0
+
+
+def test_two_tenants_of_unequal_weight_get_their_shares_at_depth_two(stub):
+    """The deficit round-robin feeds the same dispatcher: behind two
+    stalled passes, tenant a (weight 3) and tenant b (weight 1) leave in
+    passes of 16 rows that are three quarters a's."""
+    b = TopNBatcher(max_batch=16, tenant_weights={"a": 3.0, "b": 1.0}, fair_quantum=4.0)
+    try:
+        first = _stall(b, stub)
+        assert len(first) == 2
+        held = _ask(b, [2000])  # the dispatcher takes it and waits for a slot with it
+        _wait_until(lambda: b._queue.qsize() == 0)
+        backlog = _ask(b, range(1, 129), tenant=lambda n: "a" if n <= 64 else "b")
+        _wait_until(lambda: b._queue.qsize() >= 128 - 15)
+        stub.gate.set()
+        for asked in first + [held, backlog]:
+            _join(asked)
+    finally:
+        b.close()
+    served = np.concatenate(stub.groups)
+    served = served[(served >= 1) & (served <= 128)]  # without the padding rows and the first three
+    # the dispatcher took the first 15 arrivals as they came, beside the
+    # entry it held; what queued up behind left in the round-robin's order
+    assert len(served) == 128 and (served[15 : 15 + 64] <= 64).sum() == 48
