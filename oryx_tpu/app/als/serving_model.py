@@ -96,7 +96,9 @@ class ALSServingModel(ServingModel):
         # packed device copy of Y
         self._cache_lock = threading.Lock()
         self._y_dirty = True
-        self._y_built_at = 0.0
+        # never built: the first build is due whatever time.monotonic()
+        # reads (0.0 made a machine up for less than refresh_sec wait)
+        self._y_built_at = float("-inf")
         self._refresh_sec = refresh_sec
         self._y_ids: list[str] = []
         self._y_index: dict[str, int] = {}
@@ -126,7 +128,7 @@ class ALSServingModel(ServingModel):
         self._x_dirty_ids: set[str] = set()
         self._x_dirty = True
         self._x_full_rebuild = True
-        self._x_built_at = 0.0
+        self._x_built_at = float("-inf")  # never built, as _y_built_at
         self._x_capacity = 0
         self._x_building = False
         self._x_restage_thread: threading.Thread | None = None

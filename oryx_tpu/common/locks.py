@@ -25,9 +25,8 @@ Design constraints, in order: (1) the wrappers must be perfect drop-ins
 (including the ``_is_owned``/``_release_save``/``_acquire_restore``
 hooks Condition probes for) is provided; (2) near-zero overhead — the
 fast path is one threading.local lookup and a dict membership test per
-acquire (bench.py enforces the <=2% envelope); (3) zero imports from
-the rest of oryx_tpu — metrics/tracing themselves allocate locks, and
-instrumenting the instrumenter must not recurse.
+acquire; (3) zero imports from the rest of oryx_tpu — metrics/tracing
+themselves allocate locks, and instrumenting the instrumenter must not recurse.
 
 Locks created *before* ``instrument()`` (module singletons bound at
 import) keep their raw type and stay untracked; coverage targets the

@@ -115,8 +115,8 @@ from oryx_tpu.ops.packing import (  # noqa: E402  (re-export)
 # wall seconds of the most recent train_als call (replicated path), split
 # by phase ({"pack": s, "init": s, "iterate": s}: neighbor-bucket packing
 # vs the rest of setup (factor init, device_put) vs the compiled sweep
-# run); read by tools/train_benchmark.py for bench.py's per-phase rows.
-# Overwritten per call, never merged.
+# run); read by tools/train_benchmark.py. Overwritten per call, never
+# merged.
 last_phase_seconds: dict[str, float] = {}
 
 

@@ -64,8 +64,8 @@ _MM_FLOP_BUDGET = float(1 << 32)
 _MM_ELEM_BUDGET = float(1 << 28)
 
 # wall seconds of the most recent train_forest call, split by phase
-# ({"init": s, "iterate": s}); read by tools/train_benchmark.py for
-# bench.py's per-phase rows. Overwritten per call, never merged.
+# ({"init": s, "iterate": s}); read by tools/train_benchmark.py.
+# Overwritten per call, never merged.
 last_phase_seconds: dict[str, float] = {}
 
 

@@ -125,10 +125,10 @@ def configure_ann(
     tile_chunks=None,
     host_stage1=None,
 ):
-    """Set the IVF defaults (config: oryx.serving.scan.ann.*). Like
-    ``configure_scan``, call before the first dispatch — jitted programs
-    bake the derived static shapes in at trace time, and the host stage-1
-    plane only materializes at build time."""
+    """Set the IVF defaults (config: oryx.serving.scan.ann.*). Call
+    before the first dispatch — jitted programs bake the derived static
+    shapes in at trace time, and the host stage-1 plane only
+    materializes at build time."""
     global ANN_ENABLED, N_CELLS, NPROBE, PROBE_FRACTION
     global MIN_ITEMS, OVERLAY_CAPACITY, QUERY_BLOCK, TILE_CHUNKS, HOST_STAGE1
     if enabled is not None:
@@ -498,7 +498,7 @@ def build_ivf(
 
 
 @functools.partial(jax.jit, static_argnames=("nprobe", "cosine"))
-def _route_cells(cent_t, cnorms, q_bf, *, nprobe, cosine):
+def _route_cells(cent_t, cnorms, chunk_count, q_bf, *, nprobe, cosine):
     route = jnp.dot(
         q_bf,
         cent_t,
@@ -509,6 +509,10 @@ def _route_cells(cent_t, cnorms, q_bf, *, nprobe, cosine):
         # ||q|| is constant per row: dividing by centroid norms alone
         # preserves the per-query cosine routing order
         route = route / jnp.maximum(cnorms[None, :], 1e-12)
+    # a cell that holds nothing is probed last: k-means seeds that fell on
+    # duplicate rows leave empty cells whose centroid equals an occupied
+    # cell's, and a probe spent on one is a probe the occupied cell loses
+    route = jnp.where(chunk_count[None, :] > 0, route, -jnp.inf)
     _, cells = jax.lax.top_k(route, nprobe)
     return cells  # [b, nprobe]
 
@@ -953,6 +957,7 @@ def top_k_device(
         _route_cells(
             index.centroids_t,
             index.centroid_norms,
+            index.chunk_count,
             jnp.asarray(qpad),
             nprobe=np_,
             cosine=cosine,

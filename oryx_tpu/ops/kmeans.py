@@ -31,8 +31,8 @@ from oryx_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
 _ONEHOT_ELEM_BUDGET = 1 << 27
 
 # wall seconds of the most recent train_kmeans call, split by phase
-# ({"init": s, "iterate": s}); read by tools/train_benchmark.py for
-# bench.py's per-phase rows. Overwritten per call, never merged.
+# ({"init": s, "iterate": s}); read by tools/train_benchmark.py.
+# Overwritten per call, never merged.
 last_phase_seconds: dict[str, float] = {}
 
 

@@ -14,21 +14,24 @@ reading the item matrix itself. This module fuses the two:
 - each step computes ``[b, BLOCK_N]`` scores on the MXU with float32
   accumulation (items may be stored bfloat16 or row-quantized int8,
   halving / quartering HBM traffic);
-- each block reduces to top-k candidates on-chip. Scan batches of up to
-  LOCAL_TOPK_BATCH rows keep one sorted running top-k in VMEM scratch:
-  its k-th best is a threshold, a score tile in which no row beats its
-  own is skipped, and in a tile that is not, only the scores above the
+- ONE kernel (``_topn_kernel``, ``oryx_topn_scan`` on the device's
+  timeline) keeps one sorted running top-k in VMEM scratch: its k-th
+  best is a threshold, a score tile in which no row beats its own is
+  skipped, and in a tile that is not, only the scores above the
   threshold are taken, each inserted straight into the sorted state
   (``_insert_beaten``: one round an entry, so the pass hardly depends on
   how many distinct rows a batch holds; TPU v5e, PR 25: 20M x 50 float32,
   k 32, 6.18 ms for 8 copies of one row and 6.38 ms for 16 distinct rows,
   against 5.5 ms to stream the 4.48 GB as stored; 5M x 250: 6.93-6.95 ms
-  against 6.25 ms). Larger batches write block-local ``[b, k]`` candidate
-  tiles instead (``_tile_topk`` + ``_merge_topk``, k rounds each a tile,
-  merged by one ``lax.top_k`` over ``[b, num_blocks * k]`` afterwards);
-- only candidates ever reach HBM — the full score matrix never does.
+  against 6.25 ms). The state is ``ceil(k / 128)`` vregs a row, whatever
+  k is asked;
+- a scan is always ``[K, b]`` GROUPS of at most ``MAX_GROUP_ROWS`` query
+  rows, run one after another under ``lax.map`` inside one jitted
+  program (one group is K = 1): the ``[b, SCORE_TILE]`` score tile of a
+  larger group does not fit VMEM, and more rows are more groups;
+- only the k best ever reach HBM — the full score matrix never does.
 
-HBM traffic per batch drops from ``n*k_feat*4 + 2*b*n*4`` bytes to
+HBM traffic per group drops from ``n*k_feat*4 + 2*b*n*4`` bytes to
 ``n*k_feat*{1|2|4}`` — a 2-12x win for the bandwidth-bound scan.
 
 int8 handles store one f32 dequantization scale per item row
@@ -36,16 +39,17 @@ int8 handles store one f32 dequantization scale per item row
 cosine scoring folds the cached item norms into that same multiplier so
 the kernel never rescales twice.
 
-On non-TPU backends the public entry points run an XLA twin of the same
-blocked scan (``lax.scan`` over feature-major item blocks, block-local
-``lax.top_k``, final candidate merge) instead of materializing [b, n]
-scores; ``interpret=True`` forces the Pallas kernel under the interpreter
-(used by the CPU parity tests).
+On non-TPU backends the one public entry (``scan_groups``) runs an XLA
+twin of the same blocked scan (``lax.scan`` over feature-major item
+blocks, block-local ``lax.top_k``, final candidate merge) instead of
+materializing [b, n] scores; ``interpret=True`` forces the Pallas kernel
+under the interpreter (used by the CPU parity tests).
 """
 
 from __future__ import annotations
 
 import functools
+import os
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
@@ -57,71 +61,52 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Score-tile width. [b=256, 4096] f32 scores + the iota/mask temps fit
 # the 16 MB scoped-VMEM limit of a v5e; 8192 does not (measured 20.7 MB).
-import os as _os
-
-SCORE_TILE = int(_os.environ.get("ORYX_TOPN_BLOCK", 4096))
+SCORE_TILE = 4096
 # Sub-tiles streamed per grid step: the item block per step is
 # [k_feat, SCORE_TILE * SUBTILES] (bf16, ~1.6 MB at 4) while the
 # score/iota tiles stay SCORE_TILE wide: every grid step has a fixed
 # cost, so fewer, fatter steps (not re-measured on the v5e since the
 # kernels were brought up there, PR 21). 8 exceeds the 16 MB
 # scoped-VMEM limit at b=256.
-SUBTILES = int(_os.environ.get("ORYX_TOPN_SUBTILES", 4))
+SUBTILES = 4
 BLOCK_N = SCORE_TILE * SUBTILES  # items consumed per grid step
 
-# Scan batches past this row count switch the compiled kernel to the
-# block-local candidates form: the running-scratch kernel needs the full
+# Query rows of one scan group: the kernel keeps the whole
 # [b, SCORE_TILE] score tile resident, which stops fitting scoped VMEM
-# past ~256 rows, while the candidates kernel shrinks its tile instead.
-LOCAL_TOPK_BATCH = int(_os.environ.get("ORYX_TOPN_LOCAL_TOPK_BATCH", 256))
+# past ~256 rows. More rows are more groups (``group_rows``).
+MAX_GROUP_ROWS = 256
 
 # Items per lax.scan step of the XLA (non-TPU) blocked scan. Rounded down
 # to a BLOCK_N multiple that divides the padded item count. 16K keeps the
 # [b, block] score tile inside L2/L3 so the block-local top-k reads cache,
-# not DRAM (measured best of 4K..128K on the 1-core cpu bench host).
-XLA_SCAN_BLOCK = int(_os.environ.get("ORYX_XLA_SCAN_BLOCK", 16384))
+# not DRAM (measured best of 4K..128K on a one-core CPU host).
+XLA_SCAN_BLOCK = 16384
 
 # Oversampling factor for quantized scans: the int8 plane ranks the scan,
-# then the top (RESCORE_OVERSAMPLE * k) candidates are re-scored against
-# the residual plane (int8 codes of what the first plane dropped) before
-# the final top-k. 0 disables rescoring (raw int8 ranks).
-RESCORE_OVERSAMPLE = int(_os.environ.get("ORYX_TOPN_RESCORE", 4))
+# then the top (RESCORE_OVERSAMPLE * k) candidates, at most
+# OVERSAMPLE_CAP, are re-scored against the residual plane (int8 codes of
+# what the first plane dropped) before the final top-k.
+RESCORE_OVERSAMPLE = 4
+OVERSAMPLE_CAP = 128
 
 # Chunk width of the quantized XLA scan's candidate selection: the scan
 # reduces scores to per-chunk maxes (a reduce that fuses into the GEMM's
 # epilogue — wide lax.top_k inside the scan body does not), the top-m
 # chunks by max provably contain the top-m items, and only those chunks'
 # columns are gathered and scored exactly afterwards.
-_CHUNK = int(_os.environ.get("ORYX_TOPN_CHUNK", 32))
+_CHUNK = 32
 
 # How many chunks that selection keeps: the top-k chunks by primary-plane
 # max already provably contain the primary top-k items, and every kept
 # chunk drags in its _CHUNK-1 neighbors, so a modest factor over k yields
 # a ~30x item-level oversample for the exact two-plane rescore. The tail
 # (gather + rescore) is linear in this count — keep it lean.
-CHUNK_OVERSAMPLE = float(_os.environ.get("ORYX_TOPN_CHUNK_OVERSAMPLE", 1.25))
+CHUNK_OVERSAMPLE = 1.25
 
 
 def _chunk_k(k: int, chunks: int) -> int:
     return min(max(int(round(CHUNK_OVERSAMPLE * k)), k + 2), chunks)
 
-
-def configure_scan(
-    *,
-    oversample: int | None = None,
-    chunk: int | None = None,
-    block: int | None = None,
-) -> None:
-    """Apply ``oryx.serving.scan.*`` tuning (serving-layer startup). Must
-    run before the first dispatch: jitted scan programs bake these in at
-    trace time and are cached by shape, not by knob value."""
-    global RESCORE_OVERSAMPLE, _CHUNK, XLA_SCAN_BLOCK
-    if oversample is not None:
-        RESCORE_OVERSAMPLE = int(oversample)
-    if chunk is not None:
-        _CHUNK = int(chunk)
-    if block is not None:
-        XLA_SCAN_BLOCK = int(block)
 
 # int8 operand tiles are (32 sublanes, 128 lanes): the feature dim of a
 # quantized matrix pads to a 32 multiple (zero-filled; queries pad alike)
@@ -198,7 +183,7 @@ def _quantize_residual(
 # the GIL), a band a task. On one thread it was most of a 5 GB shard's
 # upload, and the norms made a temporary as large as the shard.
 _LAYOUT_BAND = 1 << 16
-_LAYOUT_THREADS = min(16, _os.cpu_count() or 1)
+_LAYOUT_THREADS = min(16, os.cpu_count() or 1)
 
 
 def _in_bands(fn, n: int) -> None:
@@ -308,66 +293,9 @@ def _score_tile(q, mat_s, aux_s, qn, *, cosine, quantized):
     return scores
 
 
-def _tile_topk(sc, local_cols, base, k, int_max, neg_inf):
-    """Candidates kernel only (no running threshold bounds its rounds).
-    Iterative max: the tile's top-k as [b, k] (scores, item ids), best
-    first (ties -> lowest item id, like a stable host scan). The k rounds
-    are a rolled loop, so the program holds ONE round: unrolled, Mosaic
-    took 10-95 s per kernel at k = 16 and did not finish within half an
-    hour at k = 64, b = 512 (TPU v5e, PR 21)."""
-    b = sc.shape[0]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
-
-    def one_round(j, carry):
-        sc, vals, idx = carry
-        m = jnp.max(sc, axis=1, keepdims=True)  # [b, 1]
-        at = jnp.min(jnp.where(sc == m, local_cols, int_max), axis=1, keepdims=True)
-        vals = jnp.where(slot == j, m, vals)
-        idx = jnp.where(slot == j, at + base, idx)
-        return jnp.where(local_cols == at, neg_inf, sc), vals, idx
-
-    _, vals, idx = jax.lax.fori_loop(
-        0, k, one_round,
-        (sc, jnp.full((b, k), neg_inf, jnp.float32), jnp.zeros((b, k), jnp.int32)),
-    )
-    return vals, idx
-
-
-def _merge_topk(cur_v, cur_i, tile_v, tile_i, k, int_max, neg_inf):
-    """Candidates kernel only. Merge a tile's [b, k] top-k into the
-    block's [b, k] list: k rounds over the two lists, rolled like
-    ``_tile_topk``. Ties prefer the
-    smaller item index, which is always the earlier tile — same result as
-    a stable global merge."""
-    b = cur_v.shape[0]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
-
-    def one_round(j, carry):
-        cv, tv, new_v, new_i = carry
-        m = jnp.maximum(
-            jnp.max(cv, axis=1, keepdims=True), jnp.max(tv, axis=1, keepdims=True)
-        )
-        sel = jnp.minimum(
-            jnp.min(jnp.where(cv == m, cur_i, int_max), axis=1, keepdims=True),
-            jnp.min(jnp.where(tv == m, tile_i, int_max), axis=1, keepdims=True),
-        )
-        new_v = jnp.where(slot == j, m, new_v)
-        new_i = jnp.where(slot == j, sel, new_i)
-        cv = jnp.where((cv == m) & (cur_i == sel), neg_inf, cv)
-        tv = jnp.where((tv == m) & (tile_i == sel), neg_inf, tv)
-        return cv, tv, new_v, new_i
-
-    _, _, new_v, new_i = jax.lax.fori_loop(
-        0, k, one_round,
-        (cur_v, tile_v, jnp.full((b, k), neg_inf, jnp.float32),
-         jnp.zeros((b, k), jnp.int32)),
-    )
-    return new_v, new_i
-
-
-# The scratch kernel's running top-k is held in whole 128-lane vregs, so
-# shifting a row is a native lane roll: one vreg a row up to MAX_KERNEL_K
-# (the fused multi dispatches do not cap k: a k bucket of 256 takes two).
+# The kernel's running top-k is held in whole 128-lane vregs, so shifting
+# a row is a native lane roll: one vreg a row up to k = 128, two for a k
+# bucket of 256.
 _STATE_LANES = 128
 
 
@@ -483,61 +411,12 @@ def _topn_kernel(
         idx_ref[...] = istate[:, :k]
 
 
-def _topn_candidates_kernel(
-    q_ref, mat_ref, aux_ref, *rest,
-    k, n_items, cosine, quantized, subtiles, tile
-):
-    """Block-local top-k: each grid step reduces its own item block to
-    [b, k] candidates written straight to its output slot — no cross-step
-    scratch and no threshold gate, so the score tile can narrow as the
-    scan batch grows (the running-scratch kernel is pinned to
-    [b, SCORE_TILE] and stops fitting VMEM past ~256 rows). A final
-    [b, grid * k] lax.top_k outside the kernel merges the blocks; the
-    candidate traffic is k/tile of the score matrix, so HBM stays
-    item-bound. ``n_items`` None: as in ``_topn_kernel``."""
-    if n_items is None:
-        n_ref, *rest = rest
-        n_items = n_ref[0]
-    vals_ref, idx_ref = rest
-    block = pl.program_id(0)
-    b = q_ref.shape[0]
-    neg_inf = jnp.float32(-jnp.inf)
-    int_max = jnp.int32(2**31 - 1)
-    q = q_ref[:]
-    qn = None
-    if cosine:
-        qn = jnp.sqrt(
-            jnp.sum(q.astype(jnp.float32) * q.astype(jnp.float32), axis=1, keepdims=True)
-        )
-    local_cols = jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
-    best_v = jnp.full((b, k), neg_inf, jnp.float32)
-    best_i = jnp.zeros((b, k), jnp.int32)
-    for s in range(subtiles):
-        base = block * (tile * subtiles) + s * tile
-        scores = _score_tile(
-            q,
-            mat_ref[:, s * tile : (s + 1) * tile],
-            aux_ref[:, s * tile : (s + 1) * tile],
-            qn,
-            cosine=cosine,
-            quantized=quantized,
-        )
-        scores = jnp.where(local_cols < n_items - base, scores, neg_inf)
-        tile_v, tile_i = _tile_topk(scores, local_cols, base, k, int_max, neg_inf)
-        best_v, best_i = _merge_topk(
-            best_v, best_i, tile_v, tile_i, k, int_max, neg_inf
-        )
-    vals_ref[...] = best_v[None]
-    idx_ref[...] = best_i[None]
-
-
 def _scan_k(k: int, n_items: int, resid) -> int:
     """Candidates the scan keeps per query before the residual rescore
-    trims back to k. Capped at MAX_KERNEL_K so the oversampled scan stays
-    on the kernel paths."""
-    if resid is None or RESCORE_OVERSAMPLE <= 1:
+    trims back to k."""
+    if resid is None:
         return k
-    m = min(max(RESCORE_OVERSAMPLE * k, 32), MAX_KERNEL_K, n_items)
+    m = min(max(RESCORE_OVERSAMPLE * k, 32), OVERSAMPLE_CAP, n_items)
     return max(m, k)
 
 
@@ -564,8 +443,6 @@ def _rescore_topk(vals, idxs, q, qn, resid, resid_scales, norms, *, k, cosine):
     sc = jnp.where(jnp.isfinite(vv), vv + corr, -jnp.inf)
     v, pos = jax.lax.top_k(sc, k)
     return v, jnp.take_along_axis(ii, pos, axis=1)
-
-
 
 
 @functools.partial(
@@ -595,23 +472,7 @@ def _streaming_topk_multi(
     return vals, idxs
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k", "n_items", "cosine", "interpret", "download_dtype")
-)
-def _streaming_topk(
-    mat_t, norms, scales, resid, resid_scales, queries, *,
-    k, n_items, cosine, interpret, download_dtype=None,
-):
-    vals, idxs = _streaming_topk_impl(
-        mat_t, norms, scales, resid, resid_scales, queries,
-        k=k, n_items=n_items, cosine=cosine, interpret=interpret,
-    )
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
-
-
-# Scoped VMEM the kernels ask Mosaic for. A v5e core has 128 MiB; the
+# Scoped VMEM the kernel asks Mosaic for. A v5e core has 128 MiB; the
 # default scoped limit of 16 MiB does not hold the [256, 4096] score-tile
 # working set next to a 250-feature item block in any dtype (every 250f
 # variant was refused with "ran out of memory in memory space vmem",
@@ -621,7 +482,7 @@ _VMEM_LIMIT = 64 * 2**20
 _VMEM_BUDGET = 40 * 2**20
 
 
-# grid steps carry the running top-k (scratch kernel), so they run in order
+# grid steps carry the running top-k, so they run in order
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT
 )
@@ -649,17 +510,6 @@ def _subtiles_for(k_feat: int, b: int, dtype_bytes: int) -> int:
     return s
 
 
-def _candidates_tile_for(k_feat: int, b: int, dtype_bytes: int) -> int:
-    """Score-tile width for the block-local candidates kernel: halve from
-    SCORE_TILE until a grid step fits the VMEM budget. Power-of-two
-    halving keeps tile * SUBTILES a divisor of BLOCK_N, so the grid stays
-    exact for any padded item count."""
-    tile = SCORE_TILE
-    while tile > 256 and _step_bytes(k_feat, b, tile, SUBTILES, dtype_bytes) > _VMEM_BUDGET:
-        tile //= 2
-    return tile
-
-
 def _fold_aux(norms, scales, cosine: bool):
     """The kernel's third operand: item norms (unquantized) or the folded
     dequant multiplier (quantized — cosine divides the cached norms into
@@ -684,9 +534,9 @@ def _streaming_topk_impl(
     mat_t, norms, scales, resid, resid_scales, queries, *,
     k, n_items, cosine, interpret, count_rounds=False, n_valid=None,
 ):
-    """(vals [b, k], idxs [b, k]) of one scan batch. ``count_rounds``
+    """(vals [b, k], idxs [b, k]) of one scan group. ``count_rounds``
     (trace-time; tests and tools/scan_rounds.py only, no served program
-    sets it) appends the running-scratch kernel's int32 [1, 2] count of
+    sets it) appends the kernel's int32 [1, 2] count of
     (score tiles that passed the gate, selection rounds run in them).
     ``n_valid`` (int32 [1], a device value) masks the columns from it up
     where the count is not known when the program is traced: every shard
@@ -694,13 +544,15 @@ def _streaming_topk_impl(
     then reads it from SMEM, and ``n_items`` only sizes the rescore."""
     k_feat, n_pad = mat_t.shape
     b = queries.shape[0]
+    if b > MAX_GROUP_ROWS:
+        raise ValueError(f"a scan group holds at most {MAX_GROUP_ROWS} rows, not {b}")
     quantized = scales is not None
     q = _pad_queries(queries.astype(jnp.float32 if quantized else mat_t.dtype), k_feat)
     aux = _fold_aux(norms, scales, cosine)
     m = _scan_k(k, n_items, resid)
 
     def finish(vals, idxs):
-        if resid is None or RESCORE_OVERSAMPLE <= 1:
+        if resid is None:
             return vals, idxs
         qn = (
             jnp.linalg.norm(q.astype(jnp.float32), axis=1, keepdims=True)
@@ -717,42 +569,6 @@ def _streaming_topk_impl(
     masked_from = n_items if n_valid is None else None
     valid_spec = [] if n_valid is None else [pl.BlockSpec(**smem)]
     valid_arg = [] if n_valid is None else [n_valid.astype(jnp.int32).reshape(1)]
-    if b > LOCAL_TOPK_BATCH:
-        if count_rounds:
-            raise ValueError("the candidates kernel has no gate and no rounds to count")
-        # block-local candidates: per-block [b, k] tiles + one final merge
-        tile = _candidates_tile_for(k_feat, b, mat_t.dtype.itemsize)
-        step = tile * SUBTILES
-        grid = n_pad // step
-        kernel = functools.partial(
-            _topn_candidates_kernel, k=m, n_items=masked_from, cosine=cosine,
-            quantized=quantized, subtiles=SUBTILES, tile=tile,
-        )
-        vals_c, idx_c = pl.pallas_call(
-            kernel,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((b, k_feat), lambda i: (0, 0), **common),
-                pl.BlockSpec((k_feat, step), lambda i: (0, i), **common),
-                pl.BlockSpec((1, step), lambda i: (0, i), **common),
-                *valid_spec,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, b, m), lambda i: (i, 0, 0), **common),
-                pl.BlockSpec((1, b, m), lambda i: (i, 0, 0), **common),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((grid, b, m), jnp.float32),
-                jax.ShapeDtypeStruct((grid, b, m), jnp.int32),
-            ],
-            compiler_params=_COMPILER_PARAMS,
-            interpret=interpret,
-            name="oryx_topn_candidates",
-        )(q, mat_t, aux, *valid_arg)
-        allv = jnp.moveaxis(vals_c, 0, 1).reshape(b, grid * m)
-        alli = jnp.moveaxis(idx_c, 0, 1).reshape(b, grid * m)
-        vals, pos = jax.lax.top_k(allv, m)
-        return finish(vals, jnp.take_along_axis(alli, pos, axis=1))
     # adapt sub-tiles to the feature width so wide models (250-feat) still
     # fit scoped VMEM; n_pad is a BLOCK_N multiple, so any power-of-two
     # divisor of SUBTILES keeps the grid exact
@@ -840,7 +656,6 @@ def _xla_streaming_topk_impl(
     chunked = (
         quantized
         and resid is not None
-        and RESCORE_OVERSAMPLE > 1
         and block % _CHUNK == 0
         and block // _CHUNK >= _chunk_k(k, block // _CHUNK)
     )
@@ -973,7 +788,6 @@ def _xla_streaming_topk_multi_impl(
     chunked = (
         scales is not None
         and resid is not None
-        and RESCORE_OVERSAMPLE > 1
         and block % _CHUNK == 0
         and chunks >= _chunk_k(k, chunks)
     )
@@ -1039,22 +853,6 @@ def _xla_streaming_topk_multi_impl(
 @functools.partial(
     jax.jit, static_argnames=("k", "n_items", "cosine", "download_dtype")
 )
-def _xla_streaming_topk(
-    mat_t, norms, scales, resid, resid_scales, queries, *,
-    k, n_items, cosine, download_dtype=None,
-):
-    vals, idxs = _xla_streaming_topk_impl(
-        mat_t, norms, scales, resid, resid_scales, queries,
-        k=k, n_items=n_items, cosine=cosine,
-    )
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
-
-
-@functools.partial(
-    jax.jit, static_argnames=("k", "n_items", "cosine", "download_dtype")
-)
 def _xla_streaming_topk_multi(
     mat_t, norms, scales, resid, resid_scales, queries_kb, *,
     k, n_items, cosine, download_dtype=None,
@@ -1085,121 +883,11 @@ def _xla_streaming_topk_multi_indexed(
     return vals, idxs
 
 
-# the single-dispatch entry's kernel k (the running top-k is then one
-# 128-lane row of VMEM state, and a tile's selection at most k rounds);
-# past it, fall back to one XLA top_k
-MAX_KERNEL_K = 128
-
-
-@functools.partial(jax.jit, static_argnames=("k", "n_items", "cosine"))
-def _materialized_topk(
-    mat_t, norms, scales, resid, resid_scales, queries, *, k, n_items, cosine
-):
-    """Large-k fallback over the same feature-major layout: materialize
-    [b, n] scores once and let XLA's top_k handle the wide selection.
-    Quantized handles sum both planes in full here — at k > MAX_KERNEL_K
-    the oversample-then-rescore shape stops paying for itself."""
-    quantized = scales is not None
-    q = _pad_queries(
-        queries.astype(jnp.float32 if quantized else mat_t.dtype), mat_t.shape[0]
-    )
-    mat = mat_t.astype(jnp.float32) if quantized else mat_t
-    scores = jnp.dot(
-        q, mat, preferred_element_type=jnp.float32,
-        precision=_dot_precision_for(q, quantized),
-    )
-    if quantized:
-        scores = scores * scales
-        if resid is not None:
-            scores = scores + jnp.dot(
-                q, resid.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-                precision=_dot_precision_for(q, quantized),
-            ) * resid_scales
-    if cosine:
-        qn = jnp.linalg.norm(queries.astype(jnp.float32), axis=1, keepdims=True)
-        scores = scores / jnp.maximum(norms * qn, 1e-12)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(cols < n_items, scores, -jnp.inf)
-    return jax.lax.top_k(scores, k)
-
-
 def _use_xla_scan(interpret) -> bool:
     """Non-TPU backends with no explicit interpret request run the XLA
     twin of the blocked scan; ``interpret=True`` always forces the Pallas
     interpreter (the parity test suite), and TPU compiles the kernel."""
     return interpret is None and jax.default_backend() != "tpu"
-
-
-def top_k_streaming_device(
-    up: StreamingItemMatrix,
-    queries: np.ndarray,
-    k: int,
-    cosine: bool = False,
-    interpret: bool | None = None,
-    download_dtype=None,
-) -> tuple[jax.Array, jax.Array]:
-    """(scores [b, k], indices [b, k]) as device arrays — the async
-    building block. ``interpret=None`` picks per backend: the compiled
-    kernel on TPU, the fused XLA blocked scan elsewhere."""
-    q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-    k = max(1, min(int(k), up.n_items))
-    if k > MAX_KERNEL_K:
-        vals, idxs = _materialized_topk(
-            up.mat_t, up.norms, up.scales, up.resid, up.resid_scales,
-            jnp.asarray(q), k=k, n_items=up.n_items, cosine=cosine,
-        )
-        return (vals.astype(download_dtype) if download_dtype is not None else vals), idxs
-    if _use_xla_scan(interpret):
-        return _xla_streaming_topk(
-            up.mat_t, up.norms, up.scales, up.resid, up.resid_scales,
-            jnp.asarray(q),
-            k=k, n_items=up.n_items, cosine=cosine, download_dtype=download_dtype,
-        )
-    return _streaming_topk(
-        up.mat_t,
-        up.norms,
-        up.scales,
-        up.resid,
-        up.resid_scales,
-        jnp.asarray(q),
-        k=k,
-        n_items=up.n_items,
-        cosine=cosine,
-        interpret=bool(interpret),
-        download_dtype=download_dtype,
-    )
-
-
-def top_k_streaming_device_multi(
-    up: StreamingItemMatrix,
-    queries_kb: jax.Array,
-    k: int,
-    cosine: bool = False,
-    interpret: bool | None = None,
-    download_dtype=None,
-) -> tuple[jax.Array, jax.Array]:
-    """(scores [K, b, k], indices [K, b, k]) for [K, b, feat] query
-    groups — K full-matrix scans fused into one dispatch."""
-    k = max(1, min(int(k), up.n_items))
-    if _use_xla_scan(interpret):
-        return _xla_streaming_topk_multi(
-            up.mat_t, up.norms, up.scales, up.resid, up.resid_scales, queries_kb,
-            k=k, n_items=up.n_items, cosine=cosine, download_dtype=download_dtype,
-        )
-    return _streaming_topk_multi(
-        up.mat_t,
-        up.norms,
-        up.scales,
-        up.resid,
-        up.resid_scales,
-        queries_kb,
-        k=k,
-        n_items=up.n_items,
-        cosine=cosine,
-        interpret=bool(interpret),
-        download_dtype=download_dtype,
-    )
 
 
 @functools.partial(
@@ -1227,38 +915,63 @@ def _streaming_topk_multi_indexed(
     return vals, idxs
 
 
-def top_k_streaming_device_multi_indexed(
+def group_rows(rows: np.ndarray, scan_batch: int = MAX_GROUP_ROWS) -> np.ndarray:
+    """[n, ...] rows -> [groups, b, ...] with b = min(scan_batch, n), the
+    last group zero-padded: the shape every scan program takes."""
+    n = rows.shape[0]
+    b = max(1, min(scan_batch, n))
+    groups = -(-n // b)
+    if groups * b != n:
+        pad = np.zeros((groups * b - n,) + rows.shape[1:], rows.dtype)
+        rows = np.concatenate([rows, pad])
+    return rows.reshape((groups, b) + rows.shape[1:])
+
+
+def scan_groups(
     up: StreamingItemMatrix,
-    x_dev: jax.Array,
-    idx_kb: jax.Array,
+    groups: jax.Array,
+    k: int,
+    cosine: bool = False,
+    interpret: bool | None = None,
+    download_dtype=None,
+    x_dev: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """(scores [K, b, k], indices [K, b, k]) as device arrays, K scans of
+    the whole matrix in one dispatch: the one entry of the exact scan.
+    ``groups`` is [K, b, feat] query vectors or, with ``x_dev`` (a query
+    matrix staged on the device), [K, b] int32 rows of it, so that the
+    uplink carries 4 B a query. ``interpret=None`` picks per backend: the
+    compiled kernel on TPU, the fused XLA blocked scan elsewhere."""
+    planes = (up.mat_t, up.norms, up.scales, up.resid, up.resid_scales)
+    queries = (groups,) if x_dev is None else (x_dev, groups)
+    static = dict(
+        k=max(1, min(int(k), up.n_items)), n_items=up.n_items, cosine=cosine,
+        download_dtype=download_dtype,
+    )
+    if _use_xla_scan(interpret):
+        fn = _xla_streaming_topk_multi if x_dev is None else _xla_streaming_topk_multi_indexed
+        return fn(*planes, *queries, **static)
+    fn = _streaming_topk_multi if x_dev is None else _streaming_topk_multi_indexed
+    return fn(*planes, *queries, interpret=bool(interpret), **static)
+
+
+def top_k_streaming_device(
+    up: StreamingItemMatrix,
+    queries: np.ndarray,
     k: int,
     cosine: bool = False,
     interpret: bool | None = None,
     download_dtype=None,
 ) -> tuple[jax.Array, jax.Array]:
-    """(scores [K, b, k], indices [K, b, k]) for [K, b] int32 row indices
-    into the device-resident query matrix ``x_dev`` — the uplink carries
-    4 B/query instead of a full vector."""
-    k = max(1, min(int(k), up.n_items))
-    if _use_xla_scan(interpret):
-        return _xla_streaming_topk_multi_indexed(
-            up.mat_t, up.norms, up.scales, up.resid, up.resid_scales, x_dev, idx_kb,
-            k=k, n_items=up.n_items, cosine=cosine, download_dtype=download_dtype,
-        )
-    return _streaming_topk_multi_indexed(
-        up.mat_t,
-        up.norms,
-        up.scales,
-        up.resid,
-        up.resid_scales,
-        x_dev,
-        idx_kb,
-        k=k,
-        n_items=up.n_items,
-        cosine=cosine,
-        interpret=bool(interpret),
+    """(scores [b, k], indices [b, k]) as device arrays for [b, feat]
+    query vectors (tests and tools; serving submits through ops/topn.py)."""
+    q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+    vals, idxs = scan_groups(
+        up, jnp.asarray(group_rows(q)), k, cosine=cosine, interpret=interpret,
         download_dtype=download_dtype,
     )
+    kk = vals.shape[-1]
+    return vals.reshape(-1, kk)[: len(q)], idxs.reshape(-1, kk)[: len(q)]
 
 
 def top_k_streaming(

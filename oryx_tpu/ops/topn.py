@@ -3,25 +3,29 @@
 Replaces the reference's per-request thread-pool scan over LSH partitions
 (ALSServingModel.topN / TopNConsumer.java, VectorMath.dot in the hot
 loop): dot scores for ALL items are computed on the MXU and top-k
-selected on device. Two device backends share one public API:
+selected on device. ``upload`` picks the handle, and every handle kind
+is scanned through ONE routine (``_submit``), which cuts the query rows
+into groups of at most 256 and walks the kinds once:
 
-- ``xla``: plain ``scores = Q @ Y.T`` + ``lax.top_k`` — simple, fine for
-  small/medium item matrices;
-- ``pallas`` (TPU): the fused streaming kernel in
-  :mod:`oryx_tpu.ops.pallas_topn`, which never materializes the [b, n]
-  score matrix in HBM and can hold items in bfloat16 — 2-6x less HBM
-  traffic at 1M+ items.
+- the plain ``(matrix, norms)`` pair: ``scores = Q @ Y.T`` +
+  ``lax.top_k`` a group — the tests' reference and the CPU default;
+- :class:`StreamingItemMatrix` (TPU, and int8 everywhere): the fused
+  streaming kernel in :mod:`oryx_tpu.ops.pallas_topn`, which never
+  materializes the [b, n] score matrix in HBM and can hold items in
+  bfloat16 or int8 — 2-12x less HBM traffic at 1M+ items;
+- :class:`ShardedItemMatrix`: that kernel on every shard of a mesh, the
+  candidates merged across chips;
+- :class:`IVFIndex`: the approximate tier (``ops/ivf.py``), which groups
+  its queries itself.
 
-``upload`` picks the backend (pallas when running on TPU, xla
-otherwise); ``top_k_scores`` / ``top_k_scores_batch`` dispatch on the
-uploaded handle's type.
-
-``submit_top_k`` is the async form: it enqueues the device computation
-and a non-blocking device→host copy, returning a handle whose
-``result()`` materializes the answer. Callers that keep several requests
-in flight (the serving layer's request pipeline, bench.py) overlap
-device compute and host<->device transfers instead of paying a full
-round-trip per request.
+``submit_top_k`` (query vectors) and ``submit_top_k_multi_indexed`` (rows
+of a query matrix staged on the device) enqueue the device computation
+and a non-blocking device→host copy, returning a :class:`TopNHandle`
+whose ``result()`` materializes the answer. Callers that keep several
+requests in flight (the serving batcher) overlap device compute and
+host<->device transfers instead of paying a full round-trip per request.
+``top_k_scores`` / ``top_k_scores_batch`` are the blocking forms for
+tests, tools and one-off probes.
 """
 
 from __future__ import annotations
@@ -38,13 +42,13 @@ import numpy as np
 from oryx_tpu.ops import ivf as ivf_ops
 from oryx_tpu.ops.ivf import IVFIndex
 from oryx_tpu.ops.pallas_topn import (
+    MAX_GROUP_ROWS,
     StreamingItemMatrix,
     _is_int8,
     _quantize_residual,
     _quantize_rows,
-    top_k_streaming,
-    top_k_streaming_device,
-    top_k_streaming_device_multi,
+    group_rows,
+    scan_groups,
     upload_streaming,
 )
 
@@ -92,66 +96,29 @@ def _dot_precision(dtype):
     return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _dot_topk(mat, query, k):
-    scores = jnp.dot(
-        mat, query, preferred_element_type=jnp.float32, precision=_dot_precision(mat.dtype)
-    )
-    return jax.lax.top_k(scores, k)
-
-
-@functools.partial(jax.jit, static_argnums=3)
-def _cosine_topk(mat, norms, query, k):
-    qn = jnp.linalg.norm(query.astype(jnp.float32))
-    scores = jnp.dot(
-        mat, query, preferred_element_type=jnp.float32, precision=_dot_precision(mat.dtype)
-    ) / jnp.maximum(norms * qn, 1e-12)
-    return jax.lax.top_k(scores, k)
-
-
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _dot_topk_batch(mat, norms, queries, k, cosine, download_dtype=None):
+def _dot_topk_batch(mat, norms, queries, k, cosine):
+    """The plain pair's scan of one group: the [b, n] score block and
+    XLA's ``top_k``."""
     scores = jnp.dot(
         queries, mat.T, preferred_element_type=jnp.float32, precision=_dot_precision(mat.dtype)
     )  # [b, n]
     if cosine:
         qn = jnp.linalg.norm(queries.astype(jnp.float32), axis=1, keepdims=True)
         scores = scores / jnp.maximum(norms[None, :] * qn, 1e-12)
-    vals, idxs = jax.lax.top_k(scores, k)
+    return jax.lax.top_k(scores, k)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _plain_topk_groups(mat, norms, x_dev, groups, k, cosine, download_dtype):
+    """The plain pair's twin of the fused multi-scan: lax.map over query
+    groups keeps peak memory at one [b, n] score block instead of
+    [K*b, n]. ``groups`` is [K, b, feat] vectors or, with ``x_dev``,
+    [K, b] rows of it, gathered on the device."""
+    q_kb = (groups if x_dev is None else x_dev[groups]).astype(mat.dtype)
+    vals, idxs = jax.lax.map(lambda q: _dot_topk_batch(mat, norms, q, k, cosine), q_kb)
     if download_dtype is not None:
         vals = vals.astype(download_dtype)
     return vals, idxs
-
-
-def top_k_scores(uploaded, query: np.ndarray, k: int, cosine: bool = False):
-    """(indices, scores) of the k best items for one query vector."""
-    if isinstance(uploaded, IVFIndex):
-        idx, vals = ivf_ops.top_k(uploaded, query, k, cosine=cosine)
-        return idx[0], vals[0]
-    if isinstance(uploaded, StreamingItemMatrix):
-        idx, vals = top_k_streaming(uploaded, query, k, cosine=cosine)
-        return idx[0], vals[0]
-    mat, norms = uploaded
-    k = max(1, min(int(k), mat.shape[0]))
-    q = jnp.asarray(query, dtype=mat.dtype)
-    if cosine:
-        s, i = _cosine_topk(mat, norms, q, k)
-    else:
-        s, i = _dot_topk(mat, q, k)
-    return np.asarray(i), np.asarray(s)
-
-
-def top_k_scores_batch(uploaded, queries: np.ndarray, k: int, cosine: bool = False):
-    """Batched top-k for [b, k] query vectors (concurrent requests)."""
-    if isinstance(uploaded, IVFIndex):
-        return ivf_ops.top_k(uploaded, queries, k, cosine=cosine)
-    if isinstance(uploaded, StreamingItemMatrix):
-        return top_k_streaming(uploaded, queries, k, cosine=cosine)
-    mat, norms = uploaded
-    k = max(1, min(int(k), mat.shape[0]))
-    q = jnp.asarray(queries, dtype=mat.dtype)
-    s, i = _dot_topk_batch(mat, norms, q, k, cosine)
-    return np.asarray(i), np.asarray(s)
 
 
 # -- mesh-sharded scan --------------------------------------------------------
@@ -381,15 +348,6 @@ def _submit_sharded(up: ShardedItemMatrix, groups: np.ndarray, k: int, cosine: b
     )
 
 
-def top_k_sharded(
-    up: ShardedItemMatrix, queries: np.ndarray, k: int, cosine: bool = False
-) -> tuple[np.ndarray, np.ndarray]:
-    """(indices [b, k], scores [b, k]) over the mesh-sharded matrix: one
-    blocking pass (tools and tests; the serving path submits through the
-    batcher like any handle)."""
-    return submit_top_k(up, queries, k, cosine=cosine).result()
-
-
 # -- incremental updates ------------------------------------------------------
 
 
@@ -572,48 +530,19 @@ def update_rows(uploaded, rows: np.ndarray, values: np.ndarray, n_items: int | N
 @dataclass
 class TopNHandle:
     """In-flight async top-k request; ``result()`` blocks and returns
-    (indices [b, k], scores [b, k]) as numpy arrays."""
+    (indices [n, k], scores [n, k]) as numpy arrays for the n query rows
+    submitted, whatever groups and padding the device was given."""
 
-    _vals: jax.Array
-    _idxs: jax.Array
-
-    def result(self) -> tuple[np.ndarray, np.ndarray]:
-        # scores may travel as bf16 (download_dtype); callers always see f32
-        return np.asarray(self._idxs), np.asarray(self._vals).astype(np.float32, copy=False)
-
-
-@dataclass
-class MultiTopNHandle:
-    """In-flight fused multi-scan request; ``result()`` returns
-    (indices [n, k], scores [n, k]) for the original n queries."""
-
-    _vals: jax.Array  # [K, b, k]
+    _vals: jax.Array  # [..., k]
     _idxs: jax.Array
     _n: int
 
     def result(self) -> tuple[np.ndarray, np.ndarray]:
         k = self._vals.shape[-1]
         idxs = np.asarray(self._idxs).reshape(-1, k)[: self._n]
-        vals = (
-            np.asarray(self._vals)
-            .astype(np.float32, copy=False)  # bf16-on-the-wire -> f32 for callers
-            .reshape(-1, k)[: self._n]
-        )
+        # scores may travel as bf16 (download_dtype); callers always see f32
+        vals = np.asarray(self._vals).astype(np.float32, copy=False).reshape(-1, k)[: self._n]
         return idxs, vals
-
-
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _dot_topk_batch_multi(mat, norms, queries_kb, k, cosine, download_dtype=None):
-    """XLA twin of the fused multi-scan: lax.map over query groups keeps
-    peak memory at one [b, n] score block instead of [K*b, n]."""
-
-    def one(q):
-        return _dot_topk_batch(mat, norms, q, k, cosine)
-
-    vals, idxs = jax.lax.map(one, queries_kb)
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
 
 
 def _auto_download_dtype(uploaded) -> object | None:
@@ -628,63 +557,95 @@ def _auto_download_dtype(uploaded) -> object | None:
     return jnp.bfloat16 if mat.dtype in (jnp.bfloat16, jnp.int8) else None
 
 
-def _group_pad(arr: np.ndarray, scan_batch: int) -> tuple[np.ndarray, int]:
-    """Zero-pad rows to a multiple of the per-scan batch and reshape to
-    [groups, b, ...]; returns (grouped, real row count)."""
-    n = arr.shape[0]
-    b = max(1, min(scan_batch, n))
-    groups = (n + b - 1) // b
-    if groups * b != n:
-        pad = np.zeros((groups * b - n,) + arr.shape[1:], arr.dtype)
-        arr = np.concatenate([arr, pad])
-    return arr.reshape((groups, b) + arr.shape[1:]), n
-
-
-def _async_multi_handle(vals, idxs, n: int) -> MultiTopNHandle:
-    """Enqueue the device→host copies without blocking and wrap."""
+def _submit(
+    uploaded, rows: np.ndarray, k: int, cosine: bool, x_dev=None,
+    scan_batch: int = MAX_GROUP_ROWS, nprobe: int | None = None, wire_dtype: bool = True,
+) -> TopNHandle:
+    """Enqueue the top-k of ``rows`` ([n, feat] float32 query vectors or,
+    with ``x_dev``, [n] int32 rows of that staged query matrix) against
+    any handle kind, and the device→host copies behind it, without
+    blocking. Every kind but IVF (whose program groups its queries
+    itself, and alone knows ``nprobe``) runs ceil(n / scan_batch) scans
+    of the whole matrix inside ONE dispatch (lax.map over zero-padded
+    groups), so per-dispatch host work and the device round-trip are paid
+    once; ``scan_batch`` bounds a scan's VMEM ([scan_batch, SCORE_TILE]
+    f32 scores). ``wire_dtype=False`` keeps float32 scores where a served
+    pass would download bfloat16 (``_auto_download_dtype``; the sharded
+    layout's one program downloads what it serves either way)."""
+    if isinstance(uploaded, IVFIndex):
+        if x_dev is None:
+            vals, idxs = ivf_ops.top_k_device(uploaded, rows, k, cosine=cosine, nprobe=nprobe)
+        else:
+            vals, idxs = ivf_ops.top_k_device_indexed(
+                uploaded, x_dev, rows, k, cosine=cosine, nprobe=nprobe
+            )
+    else:
+        groups = group_rows(rows, scan_batch)
+        download = _auto_download_dtype(uploaded) if wire_dtype else None
+        if isinstance(uploaded, ShardedItemMatrix):
+            vals, idxs = _submit_sharded(uploaded, groups, k, cosine, x_dev=x_dev)
+        elif isinstance(uploaded, StreamingItemMatrix):
+            vals, idxs = scan_groups(
+                uploaded, jnp.asarray(groups), k, cosine=cosine,
+                download_dtype=download, x_dev=x_dev,
+            )
+        else:
+            mat, norms = uploaded
+            vals, idxs = _plain_topk_groups(
+                mat, norms, x_dev, jnp.asarray(groups), max(1, min(int(k), mat.shape[0])),
+                cosine, download,
+            )
     vals.copy_to_host_async()
     idxs.copy_to_host_async()
-    return MultiTopNHandle(vals, idxs, n)
+    return TopNHandle(vals, idxs, rows.shape[0])
 
 
-def submit_top_k_multi(
+def submit_top_k(
+    uploaded, queries: np.ndarray, k: int, cosine: bool = False, nprobe: int | None = None,
+) -> TopNHandle:
+    """Enqueue a batched top-k of [n, feat] query vectors without
+    waiting. Keeping a window of handles in flight pipelines transfers
+    behind compute. ``nprobe`` overrides the IVF index's default probe
+    count per call (the overload controller's reduced-probe rung);
+    ignored for non-IVF handles, which have no probe concept."""
+    q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
+    return _submit(uploaded, q, k, cosine, nprobe=nprobe)
+
+
+def submit_top_k_multi_indexed(
     uploaded,
-    queries: np.ndarray,
+    x_dev: jax.Array,
+    indices: np.ndarray,
     k: int,
     cosine: bool = False,
-    scan_batch: int = 256,
+    scan_batch: int = MAX_GROUP_ROWS,
     nprobe: int | None = None,
-) -> MultiTopNHandle:
-    """Fused form of submit_top_k: ceil(n / scan_batch) full-matrix scans
-    run inside ONE device dispatch (lax.map), so per-dispatch host work
-    and device round-trip latency amortize across the whole query group.
-    This is what converts a dispatch-bound serving pipeline (~hundreds of
-    scans/s regardless of batch size) into a bandwidth/MXU-bound one.
-    scan_batch bounds per-scan VMEM ([scan_batch, BLOCK_N] f32 scores)."""
+) -> TopNHandle:
+    """submit_top_k with the query VECTORS already device-resident: the
+    host ships only int32 row indices into ``x_dev`` (4 B/query vs
+    4*feat B — 50-200x less uplink on a wire-bound link) and the gather
+    happens on device inside the same dispatch as the fused scans.
+
+    This is the serving shape where the user-factor matrix X lives on
+    device next to Y (e.g. refreshed by the same scatter-update path);
+    /recommend then resolves the user id to a row index and never uploads
+    a vector at all."""
+    idx = np.atleast_1d(np.asarray(indices, dtype=np.int32))
+    return _submit(uploaded, idx, k, cosine, x_dev=x_dev, scan_batch=scan_batch, nprobe=nprobe)
+
+
+def top_k_scores_batch(uploaded, queries: np.ndarray, k: int, cosine: bool = False):
+    """(indices [b, k], scores [b, k]) for [b, feat] query vectors: one
+    blocking pass with float32 scores (tests and tools; the serving path
+    submits through the batcher)."""
     q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-    if isinstance(uploaded, IVFIndex):
-        # the IVF program does its own QUERY_BLOCK grouping (lax.map over
-        # groups inside one dispatch), so the whole batch submits at once.
-        # `nprobe` overrides the index default per call (overload control's
-        # reduced-probe rung); ignored for non-IVF handles below, which
-        # have no probe concept.
-        vals, ids = ivf_ops.top_k_device(uploaded, q, k, cosine=cosine, nprobe=nprobe)
-        return _async_multi_handle(vals[None], ids[None], q.shape[0])
-    q_kb, n = _group_pad(q, scan_batch)
-    if isinstance(uploaded, ShardedItemMatrix):
-        return _async_multi_handle(*_submit_sharded(uploaded, q_kb, k, cosine), n)
-    dl = _auto_download_dtype(uploaded)
-    if isinstance(uploaded, StreamingItemMatrix):
-        vals, idxs = top_k_streaming_device_multi(
-            uploaded, jnp.asarray(q_kb), k, cosine=cosine, download_dtype=dl
-        )
-    else:
-        mat, norms = uploaded
-        kk = max(1, min(int(k), mat.shape[0]))
-        vals, idxs = _dot_topk_batch_multi(
-            mat, norms, jnp.asarray(q_kb, dtype=mat.dtype), kk, cosine, dl
-        )
-    return _async_multi_handle(vals, idxs, n)
+    return _submit(uploaded, q, k, cosine, wire_dtype=False).result()
+
+
+def top_k_scores(uploaded, query: np.ndarray, k: int, cosine: bool = False):
+    """(indices, scores) of the k best items for one query vector."""
+    idx, vals = top_k_scores_batch(uploaded, query, k, cosine=cosine)
+    return idx[0], vals[0]
 
 
 def upload_queries(queries: np.ndarray, mesh=None) -> jax.Array:
@@ -822,89 +783,3 @@ def update_query_rows(x_dev: jax.Array, rows: np.ndarray, values: np.ndarray) ->
         rows = np.concatenate([rows, np.repeat(rows[-1:], pad)])
         values = np.concatenate([values, np.repeat(values[-1:], pad, axis=0)])
     return _scatter_query_rows(x_dev, jnp.asarray(rows), jnp.asarray(values))
-
-
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def _indexed_multi_xla(mat, norms, x_dev, idx_kb, k, cosine, download_dtype):
-    q_kb = x_dev[idx_kb].astype(mat.dtype)  # [K, b, feat] gathered on device
-    return _dot_topk_batch_multi(mat, norms, q_kb, k, cosine, download_dtype)
-
-
-def submit_top_k_multi_indexed(
-    uploaded,
-    x_dev: jax.Array,
-    indices: np.ndarray,
-    k: int,
-    cosine: bool = False,
-    scan_batch: int = 256,
-    nprobe: int | None = None,
-) -> MultiTopNHandle:
-    """submit_top_k_multi with the query VECTORS already device-resident:
-    the host ships only int32 row indices into ``x_dev`` (4 B/query vs
-    4*feat B — 50-200x less uplink on a wire-bound link) and the gather
-    happens on device inside the same dispatch as the fused scans.
-
-    This is the serving shape where the user-factor matrix X lives on
-    device next to Y (e.g. refreshed by the same scatter-update path);
-    /recommend then resolves the user id to a row index and never uploads
-    a vector at all."""
-    idx = np.atleast_1d(np.asarray(indices, dtype=np.int32))
-    if isinstance(uploaded, IVFIndex):
-        vals, ids = ivf_ops.top_k_device_indexed(
-            uploaded, x_dev, idx, k, cosine=cosine, nprobe=nprobe
-        )
-        return _async_multi_handle(vals[None], ids[None], len(idx))
-    idx_kb_np, n = _group_pad(idx, scan_batch)
-    if isinstance(uploaded, ShardedItemMatrix):
-        return _async_multi_handle(
-            *_submit_sharded(uploaded, idx_kb_np, k, cosine, x_dev=x_dev), n
-        )
-    idx_kb = jnp.asarray(idx_kb_np)
-    dl = _auto_download_dtype(uploaded)
-    if isinstance(uploaded, StreamingItemMatrix):
-        from oryx_tpu.ops.pallas_topn import top_k_streaming_device_multi_indexed
-
-        vals, idxs = top_k_streaming_device_multi_indexed(
-            uploaded, x_dev, idx_kb, k, cosine=cosine, download_dtype=dl
-        )
-    else:
-        mat, norms = uploaded
-        kk = max(1, min(int(k), mat.shape[0]))
-        vals, idxs = _indexed_multi_xla(mat, norms, x_dev, idx_kb, kk, cosine, dl)
-    return _async_multi_handle(vals, idxs, n)
-
-
-def submit_top_k(
-    uploaded, queries: np.ndarray, k: int, cosine: bool = False,
-    nprobe: int | None = None,
-) -> TopNHandle:
-    """Enqueue a batched top-k without waiting: device compute and the
-    device→host copy both run asynchronously. Keeping a window of
-    handles in flight pipelines transfers behind compute. ``nprobe``
-    overrides the IVF index's default probe count per call (the overload
-    controller's reduced-probe rung); ignored for non-IVF handles."""
-    if isinstance(uploaded, IVFIndex):
-        vals, ids = ivf_ops.top_k_device(
-            uploaded, np.atleast_2d(queries), k, cosine=cosine, nprobe=nprobe
-        )
-        vals.copy_to_host_async()
-        ids.copy_to_host_async()
-        return TopNHandle(vals, ids)
-    if isinstance(uploaded, ShardedItemMatrix):
-        q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-        return _async_multi_handle(
-            *_submit_sharded(uploaded, q[None], k, cosine), q.shape[0]
-        )
-    dl = _auto_download_dtype(uploaded)
-    if isinstance(uploaded, StreamingItemMatrix):
-        vals, idxs = top_k_streaming_device(
-            uploaded, queries, k, cosine=cosine, download_dtype=dl
-        )
-    else:
-        mat, norms = uploaded
-        kk = max(1, min(int(k), mat.shape[0]))
-        q = jnp.asarray(np.atleast_2d(queries), dtype=mat.dtype)
-        vals, idxs = _dot_topk_batch(mat, norms, q, kk, cosine, dl)
-    vals.copy_to_host_async()
-    idxs.copy_to_host_async()
-    return TopNHandle(vals, idxs)

@@ -92,7 +92,7 @@ def claim_devices() -> dict:
 
 def compile_cache_dir(config=None) -> str | None:
     """Where the persistent compilation cache goes, by one rule for the
-    layers, bench.py and chip_smoke.py: ``$JAX_COMPILATION_CACHE_DIR`` if
+    layers, the tools and chip_smoke.py: ``$JAX_COMPILATION_CACHE_DIR`` if
     set (JAX reads it itself, so None = set nothing in code), else
     ``oryx.compute.compile-cache-dir`` if given, else the fixed
     ``<checkout>/.jax_cache``. Never a temporary or per-process name."""
@@ -110,7 +110,7 @@ def enable_compile_cache(config=None) -> None:
     The default directory is used on the TPU only: XLA:CPU entries are
     AOT code for the build machine's CPU features, and the checkout
     (ignored files included) gets copied between machines, where loading
-    them logs errors or dies on an illegal instruction (BENCH_r04.json).
+    them logs errors or dies on an illegal instruction (seen once, on a CPU host).
     A directory named by config or environment is honoured anywhere."""
     global _cache_dir
     d = compile_cache_dir(config)

@@ -18,10 +18,13 @@ serving pattern:
   mid-flight can never mix row indices from different snapshots, pads
   both k and the coalesced batch's row count to power-of-two buckets
   (jitted programs specialize on shape — buckets keep the compiled-
-  program count logarithmic), and calls ``submit_top_k``;
+  program count logarithmic), and submits it, in scan groups of at most
+  ``MULTI_THRESHOLD`` rows that are all one device dispatch
+  (``ops/topn.py``: ``submit_top_k`` for query vectors,
+  ``submit_top_k_multi_indexed`` for rows of a staged query matrix);
 - a completer thread resolves the async handles in submission order and
   wakes the request threads. While the device works on batch r+1, batch
-  r's results stream back — the same overlap bench.py exploits.
+  r's results stream back.
 
 Under load the queue naturally fills while the device is busy, so batch
 size adapts to concurrency automatically (1 request → batch of 1,
@@ -101,8 +104,8 @@ class BatcherClosedError(RuntimeError):
 class BatcherOverloadedError(RuntimeError):
     """Raised by ``score`` when the bounded queue
     (``oryx.serving.overload.max-queue``) is full at enqueue: the caller
-    gets an immediate shed decision instead of the unbounded
-    queued-behind-pipeline wait BENCH_r05 measured at 8.9-18 s p99.
+    gets an immediate shed decision instead of an unbounded wait queued
+    behind the pipeline (8.9-18 s p99 before the bound, on a CPU host).
     Deliberately NOT retried by ``score_default`` — the serving layer maps
     it to a fast 429 with Retry-After."""
 
@@ -364,10 +367,10 @@ class TopNBatcher:
     device calls. Thread-safe; one instance serves any number of models
     (entries carry their own uploaded-matrix handle)."""
 
-    # coalesced groups past this many rows go through submit_top_k_multi:
-    # one device dispatch running ceil(n/256) fused full-matrix scans,
-    # paying per-dispatch cost once instead of per 256-row scan
-    MULTI_THRESHOLD = 256
+    # rows of one scan group (256): a coalesced group past it is still
+    # one device dispatch, running ceil(n/256) fused full-matrix scans, so
+    # the per-dispatch cost is paid once instead of per 256-row scan
+    MULTI_THRESHOLD = topn_ops.MAX_GROUP_ROWS
 
     def __init__(
         self,
@@ -622,7 +625,7 @@ class TopNBatcher:
         indexed = entries[0].row is not None
         if indexed or n <= self.MULTI_THRESHOLD:
             padded = _b_bucket(n)
-        else:  # the fused vector path: _group_pad's multiple of the scan batch
+        else:  # vectors past one scan group: whole groups
             padded = -(-n // self.MULTI_THRESHOLD) * self.MULTI_THRESHOLD
         self._pass_seq += 1  # numbers dispatch attempts: a failed one leaves a gap
         seq = self._pass_seq
@@ -674,19 +677,12 @@ class TopNBatcher:
                 prefetch(queries, nprobe=nprobe, cosine=cosine)
             except Exception:  # never let a hint fail a dispatch
                 pass
-        if len(entries) > self.MULTI_THRESHOLD:
-            # fused multi-scan: pads to a multiple of scan_batch
-            # internally, so compiled shapes stay one-per-K
-            return topn_ops.submit_top_k_multi(
-                entries[0].uploaded,
-                queries,
-                kk,
-                cosine=cosine,
-                scan_batch=self.MULTI_THRESHOLD,
-                nprobe=nprobe,
-            )
         pad_rows = padded - len(entries)
-        if pad_rows:
+        if len(entries) > self.MULTI_THRESHOLD and isinstance(
+            entries[0].uploaded, topn_ops.IVFIndex
+        ):
+            pad_rows = 0  # an IVF index groups its queries itself: no whole scan groups
+        if pad_rows:  # bucketed shapes: zero queries, results discarded
             queries = np.concatenate(
                 [queries, np.zeros((pad_rows, queries.shape[1]), queries.dtype)]
             )
@@ -765,17 +761,13 @@ _atexit_registered = False
 def configure_scheduler(
     max_batch: int | None = None,
     max_inflight: int | None = None,
-    latency_budget_ms: float | None = None,
     max_queue: int | None = None,
 ) -> None:
     """Pin the process-wide batcher's scheduler knobs (the serving layer
-    maps ``oryx.serving.scan.*`` / ``oryx.serving.overload.max-queue``
-    here at startup, before the default batcher spins up). ``None`` leaves
-    a knob at its default (for ``max_queue``: unbounded).
-    ``latency_budget_ms`` is taken so that a configuration that sets it
-    keeps loading; nothing reads it since the depth stopped following a
-    latency budget."""
-    del latency_budget_ms
+    maps ``oryx.serving.scan.max-batch`` / ``max-inflight`` and
+    ``oryx.serving.overload.max-queue`` here at startup, before the
+    default batcher spins up). ``None`` leaves a knob at its default (for
+    ``max_queue``: unbounded)."""
     with _default_lock:
         _default_init["max_batch"] = max_batch
         _default_init["max_inflight"] = max_inflight

@@ -642,11 +642,8 @@ class ServingLayer:
                     merged.append(mod)
             self.app_resources = merged
 
-        # quantized pipelined scan engine: push oryx.serving.scan.* into
-        # the micro-batcher scheduler and the scan kernels before either
-        # compiles/spins up (jitted programs bake the knobs in at trace
-        # time; the default batcher is created on first use)
-        from oryx_tpu.ops.pallas_topn import configure_scan
+        # push oryx.serving.scan.* into the micro-batcher scheduler before
+        # it spins up (the default batcher is created on first use)
         from oryx_tpu.serving.batcher import configure_fairness, configure_scheduler
 
         if self.tenants is not None and self.tenants.fair_share:
@@ -656,17 +653,9 @@ class ServingLayer:
         configure_scheduler(
             max_batch=config.get_optional_int("oryx.serving.scan.max-batch"),
             max_inflight=config.get_optional_int("oryx.serving.scan.max-inflight"),
-            latency_budget_ms=config.get_optional_float(
-                "oryx.serving.scan.latency-budget-ms"
-            ),
             # bounded queue: full queue => immediate shed decision instead
-            # of the unbounded queued-behind-pipeline wait (BENCH_r05)
+            # of an unbounded wait queued behind the pipeline
             max_queue=config.get_optional_int("oryx.serving.overload.max-queue"),
-        )
-        configure_scan(
-            oversample=config.get_optional_int("oryx.serving.scan.oversample"),
-            chunk=config.get_optional_int("oryx.serving.scan.chunk"),
-            block=config.get_optional_int("oryx.serving.scan.block"),
         )
         from oryx_tpu.ops.ivf import configure_ann
 

@@ -1,8 +1,8 @@
 """Adaptive overload control: admission controller + staged quality shedding.
 
 Production serving tiers that only queue under overload convert a traffic
-spike into an unbounded latency tail (BENCH_r05: 8.9-18 s p99 queued behind
-the pipeline).  Following DAGOR-style admission control (Zhou et al.,
+spike into an unbounded latency tail (8.9-18 s p99 queued behind the
+pipeline, before this module, on a CPU host).  Following DAGOR-style admission control (Zhou et al.,
 SoCC'18, "Overload Control for Scaling WeChat Microservices") this module
 degrades answer *quality* in stages instead of degrading *latency*
 unboundedly.  A per-replica :class:`AdmissionController` watches the

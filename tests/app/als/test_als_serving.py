@@ -424,11 +424,13 @@ def test_consume_blocks_slow_fast_ordering_same_id():
     assert blk.get_model().get_known_items("U7") == {'a"b'}
 
 
-def test_top_n_for_user_index_submit_and_freshness():
+def test_top_n_for_user_index_submit_and_freshness(monkeypatch):
     """Device-staged users serve /recommend via index submit with results
     identical to the vector path; a user updated since the last X refresh
     (or unknown) falls back so answers are never staler than the vector
     path's."""
+    import types
+
     import numpy as np
 
     import oryx_tpu.app.als.serving_model as sm
@@ -458,6 +460,7 @@ def test_top_n_for_user_index_submit_and_freshness():
         m.top_n_for_user("u3", 5)
         assert calls == {"indexed": 0, "vector": 1}
         m._x_restage_thread.join(30)
+        assert m._x_matrix is not None and not m._x_building and not m._x_dirty
         r_idx = m.top_n_for_user("u3", 5)
         assert calls == {"indexed": 1, "vector": 1}
         r_vec = m.top_n(m.get_user_vector("u3"), 5)
@@ -476,8 +479,12 @@ def test_top_n_for_user_index_submit_and_freshness():
         m2.set_item_vectors(
             [f"i{i}" for i in range(9)], gen.standard_normal((9, 4)).astype(np.float32)
         )
-        m2.top_n_for_user("u1", 3)  # triggers the background X restage
+        # triggers the background X restage, however young the machine's
+        # monotonic clock is beside refresh_sec (a machine up for 5 s)
+        monkeypatch.setattr(sm, "time", types.SimpleNamespace(monotonic=lambda: 5.0))
+        assert len(m2.top_n_for_user("u1", 3)) == 3
         m2._x_restage_thread.join(30)
+        assert m2._x_matrix is not None and not m2._x_building and not m2._x_dirty
         base = dict(calls)
         fresh_vec = gen.standard_normal(4).astype(np.float32)
         m2.set_user_vector("u1", fresh_vec)  # dirty; refresh not due

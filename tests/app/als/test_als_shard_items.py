@@ -190,7 +190,7 @@ def test_the_shards_own_lists_merged_are_the_uncut_matrixs(devices, items):
     up = topn_ops.upload_sharded(y, _mesh(devices))
     k = min(8, items)
     assert k > min(up.counts) or items > 100
-    idx, vals = topn_ops.top_k_sharded(up, q, k)
+    idx, vals = topn_ops.top_k_scores_batch(up, q, k)
     scores = q.astype(np.float64) @ y.astype(np.float64).T
     for b in range(len(q)):
         parts = []
@@ -211,6 +211,6 @@ def test_equal_scores_on_two_shards_resolve_to_the_lower_row():
     y[[5, 25], 0] = 1.0
     q = np.zeros((1, FEATURES), np.float32)
     q[0, 0] = 1.0
-    sharded, _ = topn_ops.top_k_sharded(topn_ops.upload_sharded(y, _mesh(4)), q, 6)
+    sharded, _ = topn_ops.top_k_scores_batch(topn_ops.upload_sharded(y, _mesh(4)), q, 6)
     alone, _ = topn_ops.top_k_scores_batch(topn_ops.upload(y), q, 6)
     assert sharded[0].tolist() == alone[0].tolist() == [3, 17, 29, 38, 5, 25]

@@ -138,7 +138,7 @@ def test_sharded_scan_matches_streaming():
 
     mat, queries = _random_case(n=30_000, f=48, b=8, seed=8)
     up_s = topn_ops.upload_sharded(mat, get_mesh(), dtype=jnp.int8)
-    idx_sh, _vals_sh = topn_ops.top_k_sharded(up_s, queries, k=K)
+    idx_sh, _vals_sh = topn_ops.top_k_scores_batch(up_s, queries, k=K)
     assert _recall(mat, queries, idx_sh) >= 0.99
 
     up = pt.upload_streaming(mat, dtype=jnp.int8)
@@ -162,11 +162,13 @@ def test_f32_scan_stays_exact():
     np.testing.assert_array_equal(np.asarray(idx), expect)
 
 
-def test_materialized_large_k_int8():
-    """k past MAX_KERNEL_K takes the materialized path, which sums both
-    planes in full — overlap with exact f32 stays >= 0.99."""
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla-twin", "kernel"])
+def test_large_k_int8_keeps_its_recall(interpret):
+    """k past the oversample's cap of 128 runs the same scan as any k
+    (two vregs of state a row in the kernel) — overlap with exact f32
+    stays >= 0.99."""
     mat, queries = _random_case(n=5_000, f=24, b=4, seed=10)
-    k = pt.MAX_KERNEL_K + 16
+    k = pt.OVERSAMPLE_CAP + 16
     up = pt.upload_streaming(mat, dtype=jnp.int8)
-    _vals, idx = pt.top_k_streaming_device(up, queries, k=k)
+    _vals, idx = pt.top_k_streaming_device(up, queries, k=k, interpret=interpret)
     assert _recall(mat, queries, idx, k=k) >= 0.99

@@ -1,7 +1,8 @@
 """Pallas streaming top-N kernel, run under the interpreter on CPU.
 
-The kernel's compiled path is exercised on real TPU by bench.py; here the
-same kernel body runs in Pallas interpret mode and is checked against a
+The kernel's compiled path is exercised on the TPU by tools/chip_kernels.py
+and the benchmark's cells; here the same kernel body runs in Pallas
+interpret mode and is checked against a
 plain numpy scan (the reference semantics: TopNConsumer.java's exact
 heap-based top-N over dot scores, and CosineAverageFunction ordering).
 """
@@ -87,14 +88,55 @@ def test_submit_top_k_multi_matches_single():
 
     gen = np.random.default_rng(11)
     y = gen.standard_normal((3000, 16)).astype(np.float32)
-    q = gen.standard_normal((70, 16)).astype(np.float32)  # ragged vs scan_batch
+    q = gen.standard_normal((300, 16)).astype(np.float32)  # ragged vs a scan group's 256
     for streaming in (False, True):
         up = topn_ops.upload(y, streaming=streaming)
-        mi, mv = topn_ops.submit_top_k_multi(up, q, 5, scan_batch=32).result()
-        si, sv = topn_ops.submit_top_k(up, q, 5).result()
-        assert mi.shape == (70, 5)
-        np.testing.assert_array_equal(mi, si)
-        np.testing.assert_allclose(mv, sv, rtol=1e-5, atol=1e-5)
+        mi, mv = topn_ops.submit_top_k(up, q, 5).result()  # 2 groups of 256, the second zero-padded
+        assert mi.shape == (300, 5)
+        for part in (slice(0, 256), slice(256, 300)):  # one group each
+            si, sv = topn_ops.submit_top_k(up, q[part], 5).result()
+            np.testing.assert_array_equal(mi[part], si)
+            np.testing.assert_allclose(mv[part], sv, rtol=1e-5, atol=1e-5)
+
+
+def test_blocking_top_k_keeps_float32_scores_where_a_pass_downloads_bfloat16():
+    """`top_k_scores_batch` answers with the scan's float32 scores on a
+    bfloat16 or int8 handle, plain or streaming; a submitted pass ships
+    the same ranking with bfloat16 scores (3 B a hit less on the wire)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from oryx_tpu.ops import topn as topn_ops
+
+    gen = np.random.default_rng(17)
+    y = gen.standard_normal((2000, 16)).astype(np.float32)
+    q = gen.standard_normal((6, 16)).astype(np.float32)
+
+    def on_the_bf16_grid(v):
+        return np.array_equal(np.asarray(jnp.asarray(v, jnp.bfloat16).astype(jnp.float32)), v)
+
+    for dtype, streaming in ((jnp.bfloat16, False), (jnp.bfloat16, True), (jnp.int8, True)):
+        up = topn_ops.upload(y, dtype=dtype, streaming=streaming)
+        bi, bv = topn_ops.top_k_scores_batch(up, q, 9)
+        si, sv = topn_ops.submit_top_k(up, q, 9).result()
+        np.testing.assert_array_equal(bi, si)
+        assert bv.dtype == sv.dtype == np.float32
+        assert on_the_bf16_grid(sv) and not on_the_bf16_grid(bv)
+        np.testing.assert_allclose(sv, bv, rtol=1e-2)
+
+
+def test_vector_submit_at_k200_equals_the_plain_reference():
+    """No entry caps k: 200 best of a streaming handle through the public
+    submit (the XLA twin here, the kernel on the TPU) and through the
+    kernel under the interpreter, against the plain pair."""
+    y, q = _make(n=6000, kf=16, b=5, seed=13)
+    ri, rv = topn_ops.top_k_scores_batch(topn_ops.upload(y, streaming=False), q, 200)
+    assert ri.shape == (5, 200)
+    si, sv = topn_ops.submit_top_k(topn_ops.upload(y, streaming=True), q, 200).result()
+    ki, kv = ptn.top_k_streaming(ptn.upload_streaming(y), q, 200, interpret=True)
+    for idx, vals in ((si, sv), (ki, kv)):
+        np.testing.assert_array_equal(idx, ri)
+        np.testing.assert_allclose(vals, rv, rtol=1e-5, atol=1e-5)
 
 
 def test_sharded_topk_matches_single_device():
@@ -107,13 +149,13 @@ def test_sharded_topk_matches_single_device():
     q = gen.standard_normal((9, 12)).astype(np.float32)
     mesh = get_mesh()  # 8 virtual CPU devices
     up = topn_ops.upload_sharded(y, mesh)
-    si, sv = topn_ops.top_k_sharded(up, q, 7)
+    si, sv = topn_ops.top_k_scores_batch(up, q, 7)
     ref = topn_ops.upload(y, streaming=False)
     ri, rv = topn_ops.top_k_scores_batch(ref, q, 7)
     np.testing.assert_allclose(sv, rv, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.sort(si, axis=1), np.sort(ri, axis=1))
     # cosine variant
-    si2, sv2 = topn_ops.top_k_sharded(up, q, 5, cosine=True)
+    si2, sv2 = topn_ops.top_k_scores_batch(up, q, 5, cosine=True)
     ri2, rv2 = topn_ops.top_k_scores_batch(ref, q, 5, cosine=True)
     np.testing.assert_allclose(np.sort(sv2, axis=1), np.sort(rv2, axis=1), rtol=1e-5, atol=1e-5)
 
@@ -130,7 +172,7 @@ def test_sharded_topk_keeps_zero_vector_items():
     y[3] = 0.0  # zero vector: dot score 0 beats all-negative scores
     q = np.ones((1, 4), dtype=np.float32)
     up = topn_ops.upload_sharded(y, get_mesh())
-    si, sv = topn_ops.top_k_sharded(up, q, 3)
+    si, sv = topn_ops.top_k_scores_batch(up, q, 3)
     ref = topn_ops.upload(y, streaming=False)
     ri, rv = topn_ops.top_k_scores_batch(ref, q, 3)
     np.testing.assert_array_equal(si, ri)
@@ -155,12 +197,12 @@ def test_indexed_submit_matches_vector_submit():
     for dtype in (jnp.float32, jnp.bfloat16):
         up = topn_ops.upload(mat, dtype=dtype, streaming=False)
         i1, v1 = topn_ops.submit_top_k_multi_indexed(up, x_dev, idx, 7, scan_batch=32).result()
-        i2, v2 = topn_ops.submit_top_k_multi(up, x[idx], 7, scan_batch=32).result()
+        i2, v2 = topn_ops.submit_top_k(up, x[idx], 7).result()  # one group
         np.testing.assert_array_equal(i1, i2)
         np.testing.assert_allclose(v1, v2, rtol=1e-5)
     ups = topn_ops.upload_streaming(mat, dtype=jnp.bfloat16)
     i3, v3 = topn_ops.submit_top_k_multi_indexed(ups, x_dev, idx, 7, scan_batch=32).result()
-    i4, v4 = topn_ops.submit_top_k_multi(ups, x[idx], 7, scan_batch=32).result()
+    i4, v4 = topn_ops.submit_top_k(ups, x[idx], 7).result()
     np.testing.assert_array_equal(i3, i4)
     np.testing.assert_allclose(v3, v4, rtol=1e-2)
     assert v1.dtype == np.float32 and v3.dtype == np.float32
@@ -209,7 +251,7 @@ def test_upload_random_device_generated_matches_host_topk():
     )
 
 
-# -- selection of the running-scratch kernel (ISSUE 25) -----------------------
+# -- selection of the scan kernel (ISSUE 25) ----------------------------------
 #
 # The kernel under the interpreter against a stable NumPy top-k of the SAME
 # score bits: scores come from the kernel's own ``_score_tile`` on the same
@@ -279,7 +321,7 @@ SELECTION_CASES = {
     "random-b16-k16": (_normal, 20_000, 24, 16, 16, "float32", False),
     "random-b3-k128": (_normal, 20_000, 24, 3, 128, "float32", False),
     "random-b64-k32": (_normal, 20_000, 24, 64, 32, "float32", False),
-    # past MAX_KERNEL_K: the batcher's fused dispatches do not cap the k bucket
+    # two vregs of state a row: no entry caps k
     "ties-b8-k256": (_integers, 20_000, 6, 8, 256, "float32", False),
     "ties-b8-k32": (_integers, 20_000, 6, 8, 32, "float32", False),
     "ties-b16-k16": (_integers, 20_000, 6, 16, 16, "float32", False),
@@ -295,6 +337,8 @@ SELECTION_CASES = {
     "bfloat16-b8-k32": (_normal, 20_000, 24, 8, 32, "bfloat16", False),
     "int8-rescore-b8-k32": (_normal, 20_000, 24, 8, 32, "int8", False),
     "int8-rescore-b16-k16": (_normal, 20_000, 24, 16, 16, "int8", False),
+    # more rows than one scan group holds: 256 + 44 (padded to 256) rows
+    "two-groups-b300-k16": (_integers, 9_000, 6, 300, 16, "float32", False),
 }
 
 
@@ -306,11 +350,12 @@ def test_scratch_kernel_selection_is_a_stable_topk(case):
     k = min(k, n)  # as every public entry clamps it
 
     def scan(k, resid, resid_scales):
-        vals, idxs = ptn._streaming_topk(
-            up.mat_t, up.norms, up.scales, resid, resid_scales, jnp.asarray(queries),
+        vals, idxs = ptn._streaming_topk_multi(
+            up.mat_t, up.norms, up.scales, resid, resid_scales,
+            jnp.asarray(ptn.group_rows(queries)),
             k=k, n_items=n, cosine=cosine, interpret=True,
         )
-        return np.asarray(vals), np.asarray(idxs)
+        return np.asarray(vals).reshape(-1, k)[:b], np.asarray(idxs).reshape(-1, k)[:b]
 
     scores, q, qn = _tile_scores(up, queries, cosine)
     # an int8 scan keeps 4k candidates (at most 128) for the residual rescore:
@@ -411,10 +456,10 @@ def test_counting_flag_off_leaves_the_kernel_two_outputs():
     assert kernel_outputs(count_rounds=True) == (
         [((8, 16), "float32"), ((8, 16), "int32"), ((1, 2), "int32")], 3,
     )
-    with pytest.raises(ValueError, match="no gate"):
+    with pytest.raises(ValueError, match="at most 256 rows"):  # more rows are more groups
         ptn._streaming_topk_impl(
-            up.mat_t, up.norms, None, None, None, jnp.zeros((ptn.LOCAL_TOPK_BATCH + 8, 8)),
-            k=16, n_items=5000, cosine=False, interpret=True, count_rounds=True,
+            up.mat_t, up.norms, None, None, None, jnp.zeros((ptn.MAX_GROUP_ROWS + 8, 8)),
+            k=16, n_items=5000, cosine=False, interpret=True,
         )
 
 

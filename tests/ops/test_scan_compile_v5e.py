@@ -2,10 +2,10 @@
 that no benchmark cell compiles: tests/benchmark/test_bench_compile_v5e.py
 holds the float32 programs of the cells (batch buckets 8-128, k bucket 32);
 here are the other item dtypes (bfloat16; int8 two-plane, whose kernel keeps
-128 candidates for the rescore), the k buckets 16 and 128, and the widest
-batch the running-scratch kernel takes (256 rows), at 50 and at 250
-features. What the chip's compiler would refuse (VMEM, tiling, the lane
-roll of the running top-k) is refused here. A compile that passes is not a
+128 candidates for the rescore), the k buckets 16, 128 and 256, and the
+widest scan group (256 rows), at 50 and at 250 features. What the chip's
+compiler would refuse (VMEM, tiling, the lane roll of the running top-k)
+is refused here. A compile that passes is not a
 chip run and says nothing about time.
 
 The topology is described inside a module fixture, never at import
@@ -94,7 +94,7 @@ def test_scan_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
     )
     compiled = lowered.compile()  # raises what the chip's compiler would raise
     assert "tpu_custom_call" in compiled.as_text()
-    assert "oryx_topn_scan" in compiled.as_text()  # the running-scratch kernel, not the candidates one
+    assert "oryx_topn_scan" in compiled.as_text()  # the one scan kernel
     (vals, idxs) = lowered.out_info
     assert vals.shape == idxs.shape == (1, batch, k)
     mem = compiled.memory_analysis()

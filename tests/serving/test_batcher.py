@@ -97,20 +97,17 @@ def test_closed_batcher_raises_and_default_revives():
 
 
 def test_large_group_routes_through_fused_multi(monkeypatch):
-    """Coalesced groups past MULTI_THRESHOLD take the fused multi-scan
-    dispatch; answers stay identical to the direct path."""
+    """A coalesced group past MULTI_THRESHOLD is still one dispatch,
+    padded to whole scan groups; answers stay identical to the direct path."""
     y, up = _make(n=600, kf=10, seed=5)
-    calls = {"multi": 0, "single": 0}
-    real_multi = topn_ops.submit_top_k_multi
-    real_single = topn_ops.submit_top_k
-    monkeypatch.setattr(
-        batcher_mod.topn_ops, "submit_top_k_multi",
-        lambda *a, **k: calls.__setitem__("multi", calls["multi"] + 1) or real_multi(*a, **k),
-    )
-    monkeypatch.setattr(
-        batcher_mod.topn_ops, "submit_top_k",
-        lambda *a, **k: calls.__setitem__("single", calls["single"] + 1) or real_single(*a, **k),
-    )
+    calls = []  # rows given to every submit
+    real_submit = topn_ops.submit_top_k
+
+    def counting(uploaded, queries, *a, **k):
+        calls.append(len(queries))
+        return real_submit(uploaded, queries, *a, **k)
+
+    monkeypatch.setattr(batcher_mod.topn_ops, "submit_top_k", counting)
     b = TopNBatcher()
     b.MULTI_THRESHOLD = 8  # force the multi path with a small fleet
     gen = np.random.default_rng(6)
@@ -140,7 +137,7 @@ def test_large_group_routes_through_fused_multi(monkeypatch):
             ridx, rvals = topn_ops.top_k_scores(up, queries[j], 4)
             np.testing.assert_array_equal(results[j][0], ridx)
             np.testing.assert_allclose(results[j][1], rvals, atol=1e-5)
-        assert calls["multi"] >= 1
+        assert 40 in calls  # one dispatch of all 40 rows: whole groups of the forced 8
     finally:
         b.close()
 
@@ -199,6 +196,34 @@ def test_every_pass_is_on_the_record_and_the_counts_agree(indexed):
     assert got["passes"] <= got["depth_sum"] <= 2 * got["passes"]
 
 
+def test_an_ivf_handle_past_one_scan_group_is_given_its_rows_unpadded(monkeypatch):
+    """An IVF index groups its queries itself: the exact layouts get whole
+    scan groups (above), IVF the rows that asked."""
+    from oryx_tpu.ops import ivf as ivf_ops
+
+    gen = np.random.default_rng(8)
+    index = ivf_ops.build_ivf(gen.standard_normal((400, 8)).astype(np.float32), n_cells=4, seed=1)
+    given = []
+    monkeypatch.setattr(
+        batcher_mod.topn_ops, "submit_top_k",
+        lambda uploaded, queries, *a, **k: given.append(len(queries)) or _StubHandle(
+            0.0, None, np.zeros((len(queries), 4), np.int64), np.zeros((len(queries), 4), np.float32)
+        ),
+    )
+    b = TopNBatcher()
+    b.MULTI_THRESHOLD = 8
+    try:
+        entries = [
+            batcher_mod._Entry(uploaded=index, query=np.zeros(8, np.float32), k=4, cosine=False)
+            for _ in range(11)
+        ]
+        b._submit_vectors(entries, False, 4, None, padded=16)
+        b._submit_vectors(entries[:5], False, 4, None, padded=8)
+        assert given == [11, 8]  # past the group size as it came; inside it, its bucket
+    finally:
+        b.close()
+
+
 def test_fused_vector_path_counts_the_multiple_of_the_scan_batch():
     """Above MULTI_THRESHOLD the vector path pads to a multiple of the
     scan batch (ops.topn._group_pad), not to a power of two."""
@@ -247,7 +272,7 @@ def test_a_dispatch_that_raises_releases_its_slot_and_counts_no_pass(monkeypatch
 #
 # A stub device in place of `submit_top_k`: one pass at a time, `pass_s`
 # each, its results on the host when it ends; a submit costs the host
-# `submit_s`. No device, and every test sleeps well under 50 ms in all.
+# `submit_s`. No device, and every test sleeps about 100 ms in all at most.
 
 
 class _StubDevice:
@@ -283,21 +308,29 @@ class _StubHandle:
         return self._out
 
 
-def _ask(b, numbers, tenant=None, k=3) -> dict:
+def _ask(b, numbers, tenant=None, k=3, together=False) -> dict:
     """One thread a number: each asks the batcher with a query that
-    carries its number. The threads and {number: served idx}, for `_join`."""
+    carries its number. The threads and {number: served idx}, for `_join`.
+    `together`: the threads are all started first and ask at once when
+    this returns, so that starting them is in nobody's measured wait."""
     from oryx_tpu.tenancy.context import tenant_scope
 
     got: dict = {}
     uploaded = object()
+    numbers = list(numbers)
+    started = threading.Barrier(len(numbers) + 1) if together else None
 
     def one(n):
+        if started is not None:
+            started.wait(30)
         with tenant_scope(tenant(n) if tenant else None):
             got[n] = b.score(uploaded, np.full(4, n, np.float32), k)[0]
 
     threads = [threading.Thread(target=one, args=(n,)) for n in numbers]
     for t in threads:
         t.start()
+    if started is not None:
+        started.wait(30)
     return {"threads": threads, "got": got}
 
 
@@ -412,14 +445,13 @@ def test_the_cap_changes_counter_is_there_and_reads_zero():
         b.close()
 
 
-def test_the_latency_budget_still_loads_and_governs_nothing():
-    batcher_mod.configure_scheduler(max_inflight=None, latency_budget_ms=5.0)
+def test_a_scheduler_configured_with_nothing_holds_depth_two_and_4096_rows():
+    batcher_mod.configure_scheduler()
     try:
         b = batcher_mod.get_default_batcher()
-        assert b._inflight_cap == 2 and b.max_batch == batcher_mod.DEFAULT_MAX_BATCH
+        assert b._inflight_cap == 2 and b.max_batch == batcher_mod.DEFAULT_MAX_BATCH == 4096
     finally:
         batcher_mod.close_default_batcher()
-        batcher_mod.configure_scheduler()
 
 
 @pytest.mark.parametrize("max_inflight", [None, 4], ids=["the-rule", "four-as-the-old-rule-held"])
@@ -428,25 +460,33 @@ def test_a_backlog_behind_a_stalled_completer_leaves_in_two_passes(stub, max_inf
     stalled (a pause of the process): they leave in at most two passes,
     and the wait the ladder is told of is the stall, as it was at the
     depth the old rule held in the cells."""
-    waits = {}
-    for depth in (max_inflight, 4):
+    def stalled_wait(depth) -> float:
         stub.groups.clear()
         b = TopNBatcher(max_inflight=depth)
         try:
             slots = b._inflight_cap
             first = _stall(b, stub)
+            queued, put = [], b._queue.put
+            b._queue.put = lambda e: (queued.append(e), put(e))[1]
+            backlog = _ask(b, range(128), together=True)
             t0 = time.monotonic()
-            backlog = _ask(b, range(128))
-            _wait_until(lambda: b._queue.qsize() == 0)
+            # all 128 have asked and the dispatcher holds them, waiting for a slot
+            _wait_until(lambda: len(queued) == 128 and b._queue.qsize() == 0)
             time.sleep(0.01 - min(0.01, time.monotonic() - t0))  # the stall: 10 ms
             stub.gate.set()
             _join(backlog)
             for asked in first:
                 _join(asked)
             assert len(stub.groups) - slots <= 2
-            waits[depth] = b.queue_wait_ewma_ms()
+            return b.queue_wait_ewma_ms()
         finally:
             b.close()
+
+    # a sample is the stall or, on a machine that runs five other test
+    # files, the time 128 threads took to ask where that is longer: the
+    # threads are started before the clock (`together`), and a depth is
+    # read as the median of five samples
+    waits = {depth: float(np.median([stalled_wait(depth) for _ in range(5)])) for depth in (max_inflight, 4)}
     assert waits[max_inflight] <= 1.25 * waits[4] + 2.0
 
 

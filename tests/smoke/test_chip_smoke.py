@@ -104,7 +104,7 @@ def test_phases_rehearsed_tiny_on_cpu(tmp_path):
         out=tmp_path / "out", platform="cpu", users=300, items=800, ratings=8000,
         sweeps=2, features=16, events=500, sample_users=8,
         kernel_args=("--tiny", "--interpret", "--only",
-                     "scan/50f/int8/dot/scratch/single,fold-in"),
+                     "scan/50f/int8/dot/one-group/single,fold-in"),
         deadline=time.monotonic() + 280,
     )
     result = cs.run(plan, {"serving-int8", "speed", "kernels", "mesh"})

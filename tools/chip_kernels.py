@@ -12,12 +12,14 @@ rehearsal: same code, small shapes, Pallas under the interpreter.
 
 What is checked (ISSUE 21 item 5):
 
-- the streaming top-k scan (ops/pallas_topn.py) at 250 and 50 features x
-  >= 1M items: f32 / bf16 / int8 items, dot / cosine, the running-scratch
-  kernel (b <= 256) and the block-candidates kernel (b > 256), single,
-  multi (lax.map over pallas_call) and multi-indexed dispatch: not the
-  full cross, see ``scan_checks`` for which and why;
-- ``_materialized_topk`` (k > 128);
+- the streaming top-k scan (ops/pallas_topn.py, the one kernel
+  ``oryx_topn_scan``) at 250 and 50 features x >= 1M items: f32 / bf16 /
+  int8 items, dot / cosine, one scan group (256 rows) and two (512 rows
+  cut into groups of 256), query vectors given flat (``single``), as
+  groups (``multi``: lax.map over pallas_call) and as rows of a staged
+  query matrix (``multi-indexed``): not the full cross, see
+  ``scan_checks`` for which and why;
+- the same kernel at a k bucket of 256 (two vregs of state a row);
 - the mesh-sharded scan on whatever devices exist (f32; the int8 planes
   shard the same way and are summed in full there);
 - the fused Pallas k-means sweep, full and mini-batch, at d = 250 with k
@@ -222,35 +224,35 @@ class Checks:
         # Which variants. A cold Mosaic compile of one scan program takes
         # 5-17 s on a v5e (PR 21: 31 variants, 310 s), so the full cross of
         # dtype x metric x form x dispatch x width (72 programs) does not fit
-        # the smoke's time limit. Kept: at 250 features every dtype in both
-        # kernel forms and both metrics, every dtype in a fused (multi or
-        # multi-indexed) dispatch, and the block-candidates form under lax.map
-        # once; at 50 features, where only tile sizing and the int8 sublane
-        # padding differ, every dtype and both forms. PERF.md section 7 lists
+        # the smoke's time limit. Kept: at 250 features every dtype at both
+        # row counts and both metrics, every dtype with explicit groups (multi
+        # or multi-indexed), and two groups a half under lax.map once; at 50
+        # features, where only tile sizing and the int8 sublane padding
+        # differ, every dtype and both row counts. PERF.md section 7 lists
         # what is left out.
         wide = features == 250
         plan = {
             250: [
-                ("float32", False, "scratch", "single"),
-                ("float32", True, "candidates", "single"),
-                ("float32", False, "scratch", "multi"),
-                ("bfloat16", False, "candidates", "single"),
-                ("bfloat16", True, "scratch", "single"),
-                ("bfloat16", False, "scratch", "multi-indexed"),
-                ("int8", False, "scratch", "single"),
-                ("int8", False, "candidates", "single"),
-                ("int8", True, "scratch", "single"),
-                ("int8", False, "scratch", "multi"),
-                ("int8", False, "scratch", "multi-indexed"),
-                ("int8", True, "candidates", "multi"),
-                ("int8", False, "materialized-k200", "single"),
+                ("float32", False, "one-group", "single"),
+                ("float32", True, "two-groups", "single"),
+                ("float32", False, "one-group", "multi"),
+                ("bfloat16", False, "two-groups", "single"),
+                ("bfloat16", True, "one-group", "single"),
+                ("bfloat16", False, "one-group", "multi-indexed"),
+                ("int8", False, "one-group", "single"),
+                ("int8", False, "two-groups", "single"),
+                ("int8", True, "one-group", "single"),
+                ("int8", False, "one-group", "multi"),
+                ("int8", False, "one-group", "multi-indexed"),
+                ("int8", True, "two-groups", "multi"),
+                ("int8", False, "k256", "single"),
             ],
             50: [
-                ("float32", False, "candidates", "single"),
-                ("bfloat16", False, "scratch", "single"),
-                ("int8", False, "scratch", "single"),
-                ("int8", False, "candidates", "single"),
-                ("int8", True, "scratch", "multi-indexed"),
+                ("float32", False, "two-groups", "single"),
+                ("bfloat16", False, "one-group", "single"),
+                ("int8", False, "one-group", "single"),
+                ("int8", False, "two-groups", "single"),
+                ("int8", True, "one-group", "multi-indexed"),
             ],
         }[features]
 
@@ -272,9 +274,9 @@ class Checks:
 
         def run(dtype, cosine, form, dispatch):
             x, x_dev = queries[dtype]
-            b = big_b if form == "candidates" else small_b
-            if form == "materialized-k200":
-                kk = 200  # > MAX_KERNEL_K: one [b, n] score block + lax.top_k
+            b = big_b if form == "two-groups" else small_b
+            if form == "k256":
+                kk = 256  # what the batcher asks for a howMany of 129 to 256
                 q = x[:8]
                 vals, idx = pt.top_k_streaming_device(
                     handle(dtype), q, kk, cosine=cosine, interpret=self.interpret
@@ -287,16 +289,16 @@ class Checks:
                 )
             elif dispatch == "multi":
                 q = x[: 2 * b]
-                vals, idx = pt.top_k_streaming_device_multi(
-                    handle(dtype), jnp.asarray(q.reshape(2, b, features)), k,
+                vals, idx = pt.scan_groups(
+                    handle(dtype), jnp.asarray(q.reshape(-1, small_b, features)), k,
                     cosine=cosine, interpret=self.interpret,
                 )
             else:
-                rows = self.gen.integers(0, n_users, (2, b)).astype(np.int32)
+                rows = self.gen.integers(0, n_users, (2 * b // small_b, small_b)).astype(np.int32)
                 q = x[rows.reshape(-1)]
-                vals, idx = pt.top_k_streaming_device_multi_indexed(
-                    handle(dtype), x_dev, jnp.asarray(rows), k, cosine=cosine,
-                    interpret=self.interpret,
+                vals, idx = pt.scan_groups(
+                    handle(dtype), jnp.asarray(rows), k, cosine=cosine,
+                    interpret=self.interpret, x_dev=x_dev,
                 )
             idx, vals = np.asarray(idx).reshape(-1, k), np.asarray(vals).reshape(-1, k)
             if len(q) > big_b:  # the reference holds a [rows, n_items] f32 block
@@ -321,14 +323,14 @@ class Checks:
                 up = topn_ops.upload_sharded(mat, mesh, dtype=jnp.float32)
                 shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat_t.addressable_shards]
                 q = x[:64]
-                idx, vals = topn_ops.top_k_sharded(up, q, k)
+                idx, vals = topn_ops.top_k_scores_batch(up, q, k)
                 by_row, _ = topn_ops.submit_top_k_multi_indexed(
                     up, topn_ops.upload_queries(x, mesh=mesh), np.arange(64, dtype=np.int32), k
                 ).result()
                 expect(np.array_equal(idx, by_row), "indexed submit differs from vector submit")
                 last = np.asarray(up.starts) + np.asarray(up.counts) - 1
                 best = 50.0 * x[: len(last)]
-                top, _ = topn_ops.top_k_sharded(topn_ops.update_rows(up, last, best), best, 1)
+                top, _ = topn_ops.top_k_scores_batch(topn_ops.update_rows(up, last, best), best, 1)
                 expect(top[:, 0].tolist() == last.tolist(), "a row update missed its shard")
                 return {**verify("float32", False, q, idx, vals), "shards": shards,
                         "layout": topn_ops.sharded_layout(up)}

@@ -1,9 +1,9 @@
 """Fetch the real parity datasets (VERDICT r3 #6).
 
 Downloads MovieLens-100K and UCI covtype into data/real/ with checksum
-verification. This build environment has **no network egress**, so the
-committed quality numbers in docs/performance.md come from
-dataset-shaped synthetics and say so; run this script on a connected
+verification. This build environment has **no network egress**, so
+tools/train_benchmark.py's quality numbers come from dataset-shaped
+synthetics and say so; run this script on a connected
 host, then `python tools/real_data_eval.py` to produce the real-data
 parity table.
 

@@ -882,7 +882,7 @@ def run_crash_campaign(
     recovery_budget_s: float = 30.0,
 ) -> dict:
     """3-replica open-loop run, one SIGKILL, recovery measured. Returns
-    the campaign report (also the bench.py crash-recovery row's input)."""
+    the campaign report."""
     with ProcessFleet(replicas, work_dir) as fleet:
         scenario = crash_scenario(rate, seconds, seed=seed)
         result, verdict, runner = run_scenario(fleet, scenario)

@@ -3,8 +3,8 @@ real MovieLens-100K and held-out accuracy on the real UCI covtype,
 through the SAME training paths the framework's apps use.
 
 Requires `python tools/fetch_datasets.py` first (needs network; this
-build sandbox has none — which is why docs/performance.md labels its
-committed quality numbers as synthetic stand-ins).
+build sandbox has none, so tools/train_benchmark.py's quality numbers
+come from dataset-shaped synthetics).
 
 Parity bars (the MLlib-trained reference's ballpark at comparable
 settings): ML-100K held-out RMSE ~0.90-0.95 (rank 25, lam 0.1,

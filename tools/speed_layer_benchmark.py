@@ -215,7 +215,7 @@ def main() -> None:
     from oryx_tpu.lambda_.speed import SpeedLayer
 
     if os.environ.get("ORYX_LOCK_WATCHDOG") == "1":
-        # bench.py lock-watchdog overhead row: patch the lock factories
+        # the lock watchdog's overhead: patch the lock factories
         # before the broker/layer allocate theirs, the same way the
         # chaos/fleet test suites run
         from oryx_tpu.common import locks

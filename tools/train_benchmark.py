@@ -47,8 +47,7 @@ def _emit(d: dict) -> None:
 def _phase_sec(ops_mod, eval_sec: float) -> dict:
     """{"init": s, "iterate": s, "eval": s} for the trainer that just ran:
     init/iterate come from the ops module's last_phase_seconds, eval is
-    the harness's own held-out metric wall. Feeds bench.py's per-phase
-    rows."""
+    the harness's own held-out metric wall."""
     ph = dict(getattr(ops_mod, "last_phase_seconds", {}) or {})
     ph["eval"] = eval_sec
     return {p: round(float(s), 3) for p, s in ph.items()}
