@@ -126,17 +126,87 @@ def _is_int8(dtype) -> bool:
         return False
 
 
+# A device array's second-minor dimension is tiled: 8 sublanes of 4-byte
+# elements, 16 of 2-byte, 32 of 1-byte.
+_SUBLANES = 8
+
+
+def tail_rows(k_feat: int, dtype) -> int:
+    """Rows of the tail plane a [n, k_feat] item matrix of `dtype` is
+    stored with; 0 = one plane, as ever. THE rule of the split layout,
+    decided by what the matrix is and nothing else:
+
+    a float32 plane ``[k_feat, n]`` is stored in tiles of 8 sublanes, so
+    50 features stream 56 rows an item (12 % of every pass zeros) and 250
+    stream 256. A plane of 1, 2 or 4 rows is stored in tiles of its own
+    height. So where ``t = k_feat % 8`` is 1-4 and there is a whole tile
+    before it, the last t rows live in a plane of 1, 2 or 4 rows (3 rides
+    as 4) and the main plane keeps whole tiles: stored rows = logical
+    rows (one more where t = 3). With t = 5-7 a 4-row tail cannot hold
+    them and the padding is 1-3 rows: the main plane keeps them, padded
+    as ever. bfloat16 and int8 matrices keep one plane, exactly as they
+    were: their tiles are 16 and 32 rows (int8 is padded explicitly,
+    ``_INT8_FEAT_MULTIPLE``), how the chip stores a small plane of theirs
+    was not measured, and no benchmark cell serves them (they are its
+    control)."""
+    t = k_feat % _SUBLANES
+    if np.dtype(dtype) != np.float32 or k_feat < _SUBLANES or not 1 <= t <= 4:
+        return 0
+    return _plane_rows_stored(t, 4)
+
+
+def _plane_rows_stored(rows: int, itemsize: int) -> int:
+    """Rows the device stores for a [rows, n] plane: whole sublane tiles,
+    or for a 4-byte plane of at most 4 rows a tile of its own height (1,
+    2 or 4; read off the compiled programs' operand layouts, T(2,128))."""
+    if itemsize == 4 and rows <= 4:
+        return 1 << (rows - 1).bit_length()
+    return _ceil_to(rows, _SUBLANES * 4 // itemsize)
+
+
+def split_features(rows: np.ndarray, cols: int) -> tuple[np.ndarray, np.ndarray]:
+    """[n, k] float32 rows whose ``tail_rows`` is not 0 -> (main plane,
+    tail plane), both float32 and feature-major with `cols` columns."""
+    k = rows.shape[1]
+    k_main = k - k % _SUBLANES
+    return (
+        feature_major(rows[:, :k_main], cols, np.float32),
+        feature_major(rows[:, k_main:], cols, np.float32, tail_rows(k, np.float32)),
+    )
+
+
+def stored_feature_rows(up) -> int:
+    """Feature rows an item that the device stores for the scanned plane
+    of a streaming or sharded handle: the main plane padded to its dtype's
+    sublane tile, plus the tail's."""
+    return _plane_rows_stored(up.mat_t.shape[0], up.mat_t.dtype.itemsize) + (
+        0 if up.tail is None else up.tail.shape[0]
+    )
+
+
+def note_feature_rows(up) -> None:
+    """The two gauges that say whether the split engaged, set at upload."""
+    from oryx_tpu.common.metrics import registry as metrics
+
+    logical = up.features if up.features is not None else up.mat_t.shape[0]
+    metrics.gauge("serving.scan.feature-rows.logical").set(logical)
+    metrics.gauge("serving.scan.feature-rows.stored").set(stored_feature_rows(up))
+
+
 @dataclass(frozen=True)
 class StreamingItemMatrix:
     """Device-resident item factors in the kernel's feature-major layout."""
 
-    mat_t: jax.Array  # [k_feat(_pad), n_padded]; f32, bf16, or row-quantized int8
+    # [k_feat(_pad), n_padded]; f32, bf16, or row-quantized int8. With a
+    # ``tail`` it is the MAIN plane: the first ``k_feat - k_feat % 8`` rows
+    mat_t: jax.Array
     norms: jax.Array  # [1, n_padded] f32 (L2 norms of the ORIGINAL f32 rows)
     n_items: int
     # int8 handles only: per-item dequantization scale (absmax/127, f32,
     # 1.0 for all-zero rows so dequantizing is always a plain multiply)
     scales: jax.Array | None = None
-    # true feature count before int8 sublane padding (None = no padding)
+    # true feature count where the stored rows are not it: int8 sublane
+    # padding, or a tail plane (None = mat_t's own rows)
     features: int | None = None
     # int8 handles only: residual plane — int8 codes of (row - codes * s),
     # with its own per-row scale. Never scanned: only the top-(~4k)
@@ -144,6 +214,10 @@ class StreamingItemMatrix:
     # scan traffic stays 1 B/feature while recall matches f32.
     resid: jax.Array | None = None
     resid_scales: jax.Array | None = None
+    # float32 handles whose feature count is 1-4 over a multiple of 8
+    # (``tail_rows``): the last ``k_feat % 8`` feature rows, [1 | 2 | 4,
+    # n_padded] float32, scored on the VPU beside the main plane's dot
+    tail: jax.Array | None = None
 
     @property
     def num_features(self) -> int:
@@ -245,7 +319,7 @@ def upload_streaming(matrix: np.ndarray, dtype=jnp.float32) -> StreamingItemMatr
         scales[0, :n] = s
         rscales = np.ones((1, n_pad), dtype=np.float32)
         rscales[0, :n] = s2
-        return StreamingItemMatrix(
+        up = StreamingItemMatrix(
             mat_t=jnp.asarray(mat_t),
             norms=jnp.asarray(norms),
             n_items=n,
@@ -254,11 +328,20 @@ def upload_streaming(matrix: np.ndarray, dtype=jnp.float32) -> StreamingItemMatr
             resid=jnp.asarray(resid),
             resid_scales=jnp.asarray(rscales),
         )
-    return StreamingItemMatrix(
-        mat_t=jnp.asarray(feature_major(mat, n_pad, np.float32), dtype=dtype),
-        norms=jnp.asarray(norms),
-        n_items=n,
-    )
+    elif tail_rows(k_feat, dtype):
+        main, tail = split_features(mat, n_pad)
+        up = StreamingItemMatrix(
+            mat_t=jnp.asarray(main), norms=jnp.asarray(norms), n_items=n,
+            features=k_feat, tail=jnp.asarray(tail),
+        )
+    else:
+        up = StreamingItemMatrix(
+            mat_t=jnp.asarray(feature_major(mat, n_pad, np.float32), dtype=dtype),
+            norms=jnp.asarray(norms),
+            n_items=n,
+        )
+    note_feature_rows(up)
+    return up
 
 
 def _dot_precision_for(q, quantized: bool):
@@ -272,18 +355,25 @@ def _dot_precision_for(q, quantized: bool):
     return jax.lax.Precision.HIGHEST
 
 
-def _score_tile(q, mat_s, aux_s, qn, *, cosine, quantized):
+def _score_tile(q, mat_s, aux_s, qn, *, cosine, quantized, tail_s=None):
     """[b, tile] scores for one item sub-tile. ``aux_s`` is the item-norm
     tile (unquantized) or the folded dequant multiplier (quantized; cosine
-    norms already divided in outside the kernel)."""
+    norms already divided in outside the kernel). ``tail_s`` is the tail
+    plane's [t, tile] sub-tile where the matrix has one (float32 only):
+    the query's columns past the main plane's rows are its, a broadcast
+    multiply-add a row on the VPU, exact float32."""
     if quantized:
         mat_s = mat_s.astype(jnp.float32)
+    k_main = mat_s.shape[0]
     scores = jnp.dot(
-        q,
+        q if tail_s is None else q[:, :k_main],
         mat_s,
         preferred_element_type=jnp.float32,
         precision=_dot_precision_for(q, quantized),
     )
+    if tail_s is not None:
+        for i in range(tail_s.shape[0]):  # unrolled: 1, 2 or 4 rows
+            scores = scores + q[:, k_main + i : k_main + i + 1] * tail_s[i : i + 1, :]
     if quantized:
         scores = scores * aux_s
         if cosine:
@@ -340,7 +430,7 @@ def _insert_beaten(sc, m, local_cols, base, vstate, istate, *, k, int_max, neg_i
 
 def _topn_kernel(
     q_ref, mat_ref, aux_ref, *rest,
-    k, n_items, cosine, quantized, grid, subtiles
+    k, n_items, cosine, quantized, grid, subtiles, tailed=False
 ):
     """One grid step: score a [k_feat, BLOCK_N] item block and fold it
     into the running top-k carried in VMEM scratch across grid steps.
@@ -350,9 +440,13 @@ def _topn_kernel(
     round for each entry made (``_insert_beaten``), not k rounds.
     ``rest`` is the two outputs and the two scratch refs, with a third,
     SMEM output between them where the call asked for one ([gated tiles,
-    rounds] of the pass), and in front of them an SMEM input where
-    ``n_items`` is None: the valid item count, known only on the device
-    (a mesh shard's own row count, ops/topn.py)."""
+    rounds] of the pass), and in front of them the optional inputs: the
+    tail plane's [t, BLOCK_N] block where ``tailed``, then an SMEM input
+    where ``n_items`` is None: the valid item count, known only on the
+    device (a mesh shard's own row count, ops/topn.py)."""
+    tail_ref = None
+    if tailed:
+        tail_ref, *rest = rest
     if n_items is None:
         n_ref, *rest = rest
         n_items = n_ref[0]
@@ -390,6 +484,7 @@ def _topn_kernel(
             qn,
             cosine=cosine,
             quantized=quantized,
+            tail_s=tail_ref[:, s * SCORE_TILE : (s + 1) * SCORE_TILE] if tailed else None,
         )
         scores = jnp.where(local_cols < n_items - base, scores, neg_inf)
         kth = vstate[:, k - 1 : k]  # worst of the running top-k, [b, 1]
@@ -450,7 +545,7 @@ def _rescore_topk(vals, idxs, q, qn, resid, resid_scales, norms, *, k, cosine):
 )
 def _streaming_topk_multi(
     mat_t, norms, scales, resid, resid_scales, queries_kb, *,
-    k, n_items, cosine, interpret, download_dtype=None,
+    k, n_items, cosine, interpret, download_dtype=None, tail=None,
 ):
     """K full-matrix scans in ONE dispatch: lax.map runs the pallas scan
     sequentially over [K, b, feat] query groups inside a single jitted
@@ -463,7 +558,7 @@ def _streaming_topk_multi(
     def one(q):
         return _streaming_topk_impl(
             mat_t, norms, scales, resid, resid_scales, q,
-            k=k, n_items=n_items, cosine=cosine, interpret=interpret,
+            k=k, n_items=n_items, cosine=cosine, interpret=interpret, tail=tail,
         )
 
     vals, idxs = jax.lax.map(one, queries_kb)
@@ -532,7 +627,7 @@ def _pad_queries(q, k_feat: int):
 
 def _streaming_topk_impl(
     mat_t, norms, scales, resid, resid_scales, queries, *,
-    k, n_items, cosine, interpret, count_rounds=False, n_valid=None,
+    k, n_items, cosine, interpret, count_rounds=False, n_valid=None, tail=None,
 ):
     """(vals [b, k], idxs [b, k]) of one scan group. ``count_rounds``
     (trace-time; tests and tools/scan_rounds.py only, no served program
@@ -541,8 +636,13 @@ def _streaming_topk_impl(
     ``n_valid`` (int32 [1], a device value) masks the columns from it up
     where the count is not known when the program is traced: every shard
     of a mesh runs one program on its own rows (ops/topn.py). The kernel
-    then reads it from SMEM, and ``n_items`` only sizes the rescore."""
-    k_feat, n_pad = mat_t.shape
+    then reads it from SMEM, and ``n_items`` only sizes the rescore.
+    ``tail`` ([t, n_pad] float32; ``tail_rows``) is the split layout's
+    second plane, one more streamed operand of the same kernel; without
+    it ``mat_t`` holds every feature row, as a handle's does whose rule
+    does not engage."""
+    k_main, n_pad = mat_t.shape
+    k_feat = k_main + (0 if tail is None else tail.shape[0])
     b = queries.shape[0]
     if b > MAX_GROUP_ROWS:
         raise ValueError(f"a scan group holds at most {MAX_GROUP_ROWS} rows, not {b}")
@@ -571,13 +671,22 @@ def _streaming_topk_impl(
     valid_arg = [] if n_valid is None else [n_valid.astype(jnp.int32).reshape(1)]
     # adapt sub-tiles to the feature width so wide models (250-feat) still
     # fit scoped VMEM; n_pad is a BLOCK_N multiple, so any power-of-two
-    # divisor of SUBTILES keeps the grid exact
-    subtiles = _subtiles_for(k_feat, b, mat_t.dtype.itemsize)
+    # divisor of SUBTILES keeps the grid exact. A tail block is counted
+    # as a whole sublane tile, which is what the padding rows it replaces
+    # took: the split changes no grid.
+    subtiles = _subtiles_for(
+        k_main + (0 if tail is None else _SUBLANES), b, mat_t.dtype.itemsize
+    )
     step = SCORE_TILE * subtiles
     grid = n_pad // step
+    tail_spec = (
+        [] if tail is None
+        else [pl.BlockSpec((tail.shape[0], step), lambda i: (0, i), **common)]
+    )
+    tail_arg = [] if tail is None else [tail]
     kernel = functools.partial(
         _topn_kernel, k=m, n_items=masked_from, cosine=cosine, quantized=quantized,
-        grid=grid, subtiles=subtiles,
+        grid=grid, subtiles=subtiles, tailed=tail is not None,
     )
     out_specs = [
         pl.BlockSpec((b, m), lambda i: (0, 0), **common),
@@ -595,8 +704,9 @@ def _streaming_topk_impl(
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((b, k_feat), lambda i: (0, 0), **common),
-            pl.BlockSpec((k_feat, step), lambda i: (0, i), **common),
+            pl.BlockSpec((k_main, step), lambda i: (0, i), **common),
             pl.BlockSpec((1, step), lambda i: (0, i), **common),
+            *tail_spec,
             *valid_spec,
         ],
         out_specs=out_specs,
@@ -608,7 +718,7 @@ def _streaming_topk_impl(
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="oryx_topn_scan",
-    )(q, mat_t, aux, *valid_arg)
+    )(q, mat_t, aux, *tail_arg, *valid_arg)
     return (*finish(vals, idxs), *counts)
 
 
@@ -627,7 +737,7 @@ def _xla_scan_step(n_pad: int) -> int:
 
 def _xla_streaming_topk_impl(
     mat_t, norms, scales, resid, resid_scales, queries, *, k, n_items, cosine,
-    n_valid=None,
+    n_valid=None, tail=None,
 ):
     """Fused XLA blocked scan over the feature-major layout: lax.scan
     streams [k_feat, block] item slices and reduces each block on the
@@ -643,8 +753,10 @@ def _xla_streaming_topk_impl(
     chunks' columns gather both int8 planes for an exact ~14-bit rescore
     after the scan. HIGHEST precision keeps the f32 GEMM on the fast CPU
     path (the DEFAULT-precision CPU kernel is ~2x slower, measured).
-    ``n_valid``: as in ``_streaming_topk_impl``."""
-    k_feat, n_pad = mat_t.shape
+    ``n_valid``, ``tail``: as in ``_streaming_topk_impl`` (the tail's
+    rows are a second small GEMM a block, added to the main plane's)."""
+    k_main, n_pad = mat_t.shape
+    k_feat = k_main + (0 if tail is None else tail.shape[0])
     b = queries.shape[0]
     quantized = scales is not None
     q = _pad_queries(queries.astype(jnp.float32), k_feat)
@@ -672,13 +784,20 @@ def _xla_streaming_topk_impl(
 
     def scores_for(i):
         base = i * block
-        blk = jax.lax.dynamic_slice(mat_t, (0, base), (k_feat, block))
+        blk = jax.lax.dynamic_slice(mat_t, (0, base), (k_main, block))
         scores = jnp.dot(
-            q,
+            q[:, :k_main],
             blk.astype(jnp.float32),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         )
+        if tail is not None:
+            scores = scores + jnp.dot(
+                q[:, k_main:],
+                jax.lax.dynamic_slice(tail, (0, base), (k_feat - k_main, block)),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
         if quantized:
             scores = scores * jax.lax.dynamic_slice(mult, (0, base), (1, block))
             if cosine:
@@ -767,7 +886,7 @@ def _chunk_tail(
 
 
 def _xla_streaming_topk_multi_impl(
-    mat_t, norms, scales, resid, resid_scales, q_kbf, *, k, n_items, cosine
+    mat_t, norms, scales, resid, resid_scales, q_kbf, *, k, n_items, cosine, tail=None
 ):
     """K fused scans sharing ONE pass of int8->f32 block conversion. The
     naive multi path (lax.map of the single impl) re-converts every item
@@ -779,7 +898,7 @@ def _xla_streaming_topk_multi_impl(
     score tiles stay [b, block] (the merged [K*b, block] tile blows the
     LLC — measured 3x slowdown at 512 rows), and the candidate tails
     stay per-group after the scan. Non-chunked handles (f32/bf16, tiny
-    matrices) keep the exact lax.map path."""
+    matrices) keep the exact lax.map path; only they can have a ``tail``."""
     kg, b, _ = q_kbf.shape
     k_feat, n_pad = mat_t.shape
     block = _xla_scan_step(n_pad)
@@ -795,7 +914,7 @@ def _xla_streaming_topk_multi_impl(
         def one(q):
             return _xla_streaming_topk_impl(
                 mat_t, norms, scales, resid, resid_scales, q,
-                k=k, n_items=n_items, cosine=cosine,
+                k=k, n_items=n_items, cosine=cosine, tail=tail,
             )
 
         return jax.lax.map(one, q_kbf)
@@ -855,11 +974,11 @@ def _xla_streaming_topk_multi_impl(
 )
 def _xla_streaming_topk_multi(
     mat_t, norms, scales, resid, resid_scales, queries_kb, *,
-    k, n_items, cosine, download_dtype=None,
+    k, n_items, cosine, download_dtype=None, tail=None,
 ):
     vals, idxs = _xla_streaming_topk_multi_impl(
         mat_t, norms, scales, resid, resid_scales, queries_kb,
-        k=k, n_items=n_items, cosine=cosine,
+        k=k, n_items=n_items, cosine=cosine, tail=tail,
     )
     if download_dtype is not None:
         vals = vals.astype(download_dtype)
@@ -871,12 +990,12 @@ def _xla_streaming_topk_multi(
 )
 def _xla_streaming_topk_multi_indexed(
     mat_t, norms, scales, resid, resid_scales, x_dev, idx_kb, *,
-    k, n_items, cosine, download_dtype=None,
+    k, n_items, cosine, download_dtype=None, tail=None,
 ):
     vals, idxs = _xla_streaming_topk_multi_impl(
         mat_t, norms, scales, resid, resid_scales,
         x_dev[idx_kb].astype(jnp.float32),
-        k=k, n_items=n_items, cosine=cosine,
+        k=k, n_items=n_items, cosine=cosine, tail=tail,
     )
     if download_dtype is not None:
         vals = vals.astype(download_dtype)
@@ -896,7 +1015,7 @@ def _use_xla_scan(interpret) -> bool:
 )
 def _streaming_topk_multi_indexed(
     mat_t, norms, scales, resid, resid_scales, x_dev, idx_kb, *,
-    k, n_items, cosine, interpret, download_dtype=None,
+    k, n_items, cosine, interpret, download_dtype=None, tail=None,
 ):
     """Index-submitted fused multi-scan: gather the [K, b, feat] query
     group from the device-resident ``x_dev`` inside the dispatch, then
@@ -906,7 +1025,7 @@ def _streaming_topk_multi_indexed(
         q = x_dev[idx_b].astype(jnp.float32)
         return _streaming_topk_impl(
             mat_t, norms, scales, resid, resid_scales, q,
-            k=k, n_items=n_items, cosine=cosine, interpret=interpret,
+            k=k, n_items=n_items, cosine=cosine, interpret=interpret, tail=tail,
         )
 
     vals, idxs = jax.lax.map(one, idx_kb)
@@ -944,15 +1063,15 @@ def scan_groups(
     compiled kernel on TPU, the fused XLA blocked scan elsewhere."""
     planes = (up.mat_t, up.norms, up.scales, up.resid, up.resid_scales)
     queries = (groups,) if x_dev is None else (x_dev, groups)
-    static = dict(
+    shared = dict(
         k=max(1, min(int(k), up.n_items)), n_items=up.n_items, cosine=cosine,
-        download_dtype=download_dtype,
+        download_dtype=download_dtype, tail=up.tail,
     )
     if _use_xla_scan(interpret):
         fn = _xla_streaming_topk_multi if x_dev is None else _xla_streaming_topk_multi_indexed
-        return fn(*planes, *queries, **static)
+        return fn(*planes, *queries, **shared)
     fn = _streaming_topk_multi if x_dev is None else _streaming_topk_multi_indexed
-    return fn(*planes, *queries, interpret=bool(interpret), **static)
+    return fn(*planes, *queries, interpret=bool(interpret), **shared)
 
 
 def top_k_streaming_device(

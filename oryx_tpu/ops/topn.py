@@ -48,7 +48,10 @@ from oryx_tpu.ops.pallas_topn import (
     _quantize_residual,
     _quantize_rows,
     group_rows,
+    note_feature_rows,
     scan_groups,
+    split_features,
+    tail_rows,
     upload_streaming,
 )
 
@@ -130,9 +133,10 @@ class ShardedItemMatrix:
     streaming kernel's layout: device s holds rows ``starts[s]`` to
     ``starts[s] + counts[s]`` feature-major, ``[k_feat, cols]`` with
     ``cols`` a BLOCK_N multiple, plus their norms (and, for int8, scales
-    and the residual plane). The serving layout of a catalog past one
-    chip's memory: 20M x 250 float32 is 20 GB, 5 GB a chip on a v5e host's
-    four. One pass scans every shard with the single-device kernel and
+    and the residual plane; for float32 with ``tail_rows``, the tail plane
+    of the same split the streaming handle makes). The serving layout of
+    a catalog past one chip's memory: 20M x 250 float32 is 20 GB, 5 GB a
+    chip on a v5e host's four. One pass scans every shard with the single-device kernel and
     merges the ``[b, k]`` candidates across chips (``_sharded_scan_fn``).
     Rows split evenly (the first ``n % d`` shards hold one more), so no
     shard is empty once there is a row a device; the columns past a
@@ -149,7 +153,8 @@ class ShardedItemMatrix:
     scales: jax.Array | None = None  # [1, d * cols] per-row int8 dequant scale
     resid: jax.Array | None = None  # [k_feat_pad, d * cols] int8 residual plane
     resid_scales: jax.Array | None = None  # [1, d * cols]
-    features: int | None = None  # true feature count before int8 sublane padding
+    features: int | None = None  # true feature count where the stored rows are not it
+    tail: jax.Array | None = None  # [1 | 2 | 4, d * cols] float32: pallas_topn.tail_rows
 
     @property
     def cols(self) -> int:
@@ -205,6 +210,7 @@ def upload_sharded(matrix: np.ndarray, mesh, dtype=None) -> ShardedItemMatrix:
     cols = max(BLOCK_N, _ceil_to(counts[0], BLOCK_N))
     quantized = _is_int8(dtype)
     kf = _ceil_to(k_feat, _INT8_FEAT_MULTIPLE) if quantized else k_feat
+    tailed = not quantized and tail_rows(k_feat, dtype or jnp.float32) > 0
 
     def a_row(values: np.ndarray, fill: float) -> np.ndarray:
         out = np.full((1, cols), fill, dtype=np.float32)
@@ -221,11 +227,14 @@ def upload_sharded(matrix: np.ndarray, mesh, dtype=None) -> ShardedItemMatrix:
             parts["resid"] = feature_major(q2, cols, np.int8, kf)
             parts["scales"] = a_row(s, 1.0)  # pad: scale 1.0
             parts["resid_scales"] = a_row(s2, 1.0)
+        elif tailed:
+            parts["mat_t"], parts["tail"] = split_features(rows, cols)
         else:
             parts["mat_t"] = feature_major(rows, cols, np.dtype(dtype or jnp.float32))
         return parts
 
     names = ("mat_t", "norms") + (("scales", "resid", "resid_scales") if quantized else ())
+    names += ("tail",) if tailed else ()
     on_device: dict[str, list] = {name: [] for name in names}
     on_its_way: list = []
     for dev, lo, cnt in zip(devices, starts, counts):
@@ -244,9 +253,10 @@ def upload_sharded(matrix: np.ndarray, mesh, dtype=None) -> ShardedItemMatrix:
     up = ShardedItemMatrix(
         n_items=n, mesh=mesh, counts=counts, starts=starts,
         base=_per_shard(mesh, starts), valid=_per_shard(mesh, counts),
-        features=k_feat if kf != k_feat else None,
+        features=k_feat if kf != k_feat or tailed else None,
         **{name: assemble(name) for name in names},
     )
+    note_feature_rows(up)
     metrics.gauge("serving.scan.shards").set(sum(1 for c in counts if c))
     metrics.gauge("serving.scan.shard.rows-max").set(max(counts))
     metrics.gauge("serving.scan.shard.rows-min").set(min(counts))
@@ -256,7 +266,8 @@ def upload_sharded(matrix: np.ndarray, mesh, dtype=None) -> ShardedItemMatrix:
 
 def sharded_layout(up: ShardedItemMatrix) -> str:
     """'dev0:(rows, features) dev1:(rows, features) ...': the rows each
-    device's slice holds, by the array's own addressable shards."""
+    device's slice holds, by the array's own addressable shards; the
+    features are the logical count, however many planes store them."""
     feats = up.features if up.features is not None else up.mat_t.shape[0]
     return " ".join(
         f"dev{s.device.id}:({up.counts[(s.index[1].start or 0) // up.cols]}, {feats})"
@@ -267,7 +278,7 @@ def sharded_layout(up: ShardedItemMatrix) -> str:
 @functools.lru_cache(maxsize=None)
 def _sharded_scan_fn(
     mesh, k: int, cosine: bool, quantized: bool, indexed: bool, download_dtype,
-    interpret: bool | None = None,
+    interpret: bool | None = None, tailed: bool = False,
 ):
     """The mesh's one scan program: under ``shard_map`` every device runs
     the single-device dispatch of its backend on its own ``[k_feat, cols]``
@@ -281,7 +292,11 @@ def _sharded_scan_fn(
     stable, so equal scores resolve to the lower global row, as they do on
     one chip. Keyed by the mesh itself (``Mesh`` hashes by value).
     ``interpret`` as in ``top_k_streaming_device``: None picks per backend,
-    False compiles the kernel (the compile rehearsal for a described mesh)."""
+    False compiles the kernel (the compile rehearsal for a described mesh).
+    The third operand is the planes beside ``mat_t`` and ``norms``:
+    ``(scales, resid, resid_scales)`` where ``quantized``, ``(tail,)``
+    where ``tailed`` (the float32 split, ``pallas_topn.tail_rows``), else
+    ``()``."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -290,13 +305,14 @@ def _sharded_scan_fn(
 
     use_xla = pt._use_xla_scan(interpret)
 
-    def local(mat_t, norms, quant, base, valid, groups, x_dev):
-        scales, resid, resid_scales = quant if quantized else (None, None, None)
+    def local(mat_t, norms, beside, base, valid, groups, x_dev):
+        scales, resid, resid_scales = beside if quantized else (None, None, None)
+        tail = beside[0] if tailed else None
         capacity = mat_t.shape[1]
 
         def one(g):
             q = (x_dev[g] if indexed else g).astype(jnp.float32)
-            shared = dict(k=k, n_items=capacity, cosine=cosine, n_valid=valid)
+            shared = dict(k=k, n_items=capacity, cosine=cosine, n_valid=valid, tail=tail)
             if use_xla:
                 return pt._xla_streaming_topk_impl(
                     mat_t, norms, scales, resid, resid_scales, q, **shared
@@ -318,7 +334,7 @@ def _sharded_scan_fn(
     cols_spec, shard_spec = P(None, DATA_AXIS), P(DATA_AXIS)
     in_specs = (
         cols_spec, cols_spec,
-        (cols_spec, cols_spec, cols_spec) if quantized else (),
+        (cols_spec,) * (3 if quantized else 1 if tailed else 0),
         shard_spec, shard_spec, P(), P() if indexed else (),
     )
     # after the all_gather every device computes the same merge; the
@@ -337,11 +353,13 @@ def _submit_sharded(up: ShardedItemMatrix, groups: np.ndarray, k: int, cosine: b
     k = max(1, min(int(k), up.n_items))
     quantized = up.scales is not None
     fn = _sharded_scan_fn(
-        up.mesh, k, bool(cosine), quantized, x_dev is not None, _auto_download_dtype(up)
+        up.mesh, k, bool(cosine), quantized, x_dev is not None, _auto_download_dtype(up),
+        tailed=up.tail is not None,
     )
     return fn(
         up.mat_t, up.norms,
-        (up.scales, up.resid, up.resid_scales) if quantized else (),
+        (up.scales, up.resid, up.resid_scales) if quantized
+        else () if up.tail is None else (up.tail,),
         up.base, up.valid,
         jax.device_put(groups, replicated(up.mesh)),
         x_dev if x_dev is not None else (),
@@ -356,11 +374,23 @@ def _submit_sharded(up: ShardedItemMatrix, groups: np.ndarray, k: int, cosine: b
 # device-side copy this costs is HBM-internal (no host transfer — the
 # thing incremental refresh exists to avoid) and transient.
 @jax.jit
-def _scatter_rows_t(mat_t, norms, rows, vals, new_norms):
-    """Feature-major scatter: mat_t[:, rows] <- vals.T, norms[0, rows] <- n."""
+def _scatter_rows_t(mat_t, norms, tail, rows, vals, tail_vals, new_norms):
+    """Feature-major scatter: mat_t[:, rows] <- vals.T, norms[0, rows] <- n,
+    and tail[:, rows] <- tail_vals.T where the handle has a tail plane."""
     mat_t = mat_t.at[:, rows].set(vals.T.astype(mat_t.dtype))
     norms = norms.at[0, rows].set(new_norms)
-    return mat_t, norms
+    if tail is not None:
+        tail = tail.at[:, rows].set(tail_vals.T)
+    return mat_t, norms, tail
+
+
+def _tail_values(values: np.ndarray, k_main: int, tail) -> np.ndarray | None:
+    """[m, t] float32 for a row update of the ``tail`` plane: the features
+    past the main plane's rows, zero-padded to the plane's height (3
+    features ride in 4 rows); None where the handle has no tail."""
+    if tail is None:
+        return None
+    return np.pad(values[:, k_main:], [(0, 0), (0, tail.shape[0] - (values.shape[1] - k_main))])
 
 
 @jax.jit
@@ -398,11 +428,12 @@ def _scatter_rows_t_q(
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_scatter_fn(mesh, quantized: bool):
+def _sharded_scatter_fn(mesh, n: int):
     """Row update of a sharded matrix: the touched rows' values travel to
     every device, each writes the ones whose column lies in its own slice
-    (the rest fall outside and are dropped). Like the single-device
-    scatters it donates nothing: a pass in flight may hold the old slices."""
+    (the rest fall outside and are dropped), for the handle's ``n`` planes.
+    Like the single-device scatters it donates nothing: a pass in flight
+    may hold the old slices."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -416,7 +447,6 @@ def _sharded_scatter_fn(mesh, quantized: bool):
             p.at[:, at].set(v.T.astype(p.dtype), mode="drop") for p, v in zip(planes, vals)
         )
 
-    n = 5 if quantized else 2
     spec = (P(None, DATA_AXIS),) * n
     return jax.jit(
         shard_map(
@@ -436,22 +466,26 @@ def _update_rows_sharded(up: ShardedItemMatrix, rows, values, new_norms, n_items
     starts = np.asarray(up.starts)
     shard = np.searchsorted(starts, rows, side="right") - 1
     cols = (shard * up.cols + (rows - starts[shard])).astype(np.int32)
-    quantized = up.scales is not None
-    if quantized:
+    k_main = up.mat_t.shape[0]
+    if up.scales is not None:
         q, s = _quantize_rows(values)
         q2, s2 = _quantize_residual(values, q, s)
-        kf = up.mat_t.shape[0]
-        pad = [(0, 0), (0, kf - q.shape[1])]  # int8 sublane padding
-        planes = (up.mat_t, up.norms, up.scales, up.resid, up.resid_scales)
-        vals = (np.pad(q, pad), new_norms[:, None], s[:, None], np.pad(q2, pad), s2[:, None])
+        pad = [(0, 0), (0, k_main - q.shape[1])]  # int8 sublane padding
+        parts = {
+            "mat_t": (up.mat_t, np.pad(q, pad)), "norms": (up.norms, new_norms[:, None]),
+            "scales": (up.scales, s[:, None]), "resid": (up.resid, np.pad(q2, pad)),
+            "resid_scales": (up.resid_scales, s2[:, None]),
+        }
     else:
-        planes = (up.mat_t, up.norms)
-        vals = (values, new_norms[:, None])
-    out = _sharded_scatter_fn(up.mesh, quantized)(
+        parts = {"mat_t": (up.mat_t, values[:, :k_main]), "norms": (up.norms, new_norms[:, None])}
+        if up.tail is not None:
+            parts["tail"] = (up.tail, _tail_values(values, k_main, up.tail))
+    planes, vals = zip(*parts.values())
+    out = _sharded_scatter_fn(up.mesh, len(planes))(
         planes, *jax.device_put((cols, vals), replicated(up.mesh))
     )
     counts = up.counts[:-1] + (count - up.starts[-1],)
-    grown = dict(zip(("mat_t", "norms", "scales", "resid", "resid_scales"), out))
+    grown = dict(zip(parts, out))
     return dataclasses.replace(
         up, n_items=count, counts=counts, valid=_per_shard(up.mesh, counts), **grown
     )
@@ -519,10 +553,12 @@ def update_rows(uploaded, rows: np.ndarray, values: np.ndarray, n_items: int | N
                 scales=scales, features=uploaded.features,
                 resid=resid, resid_scales=resid_scales,
             )
-        mat_t, norms = _scatter_rows_t(
-            uploaded.mat_t, uploaded.norms, rows, values, new_norms
+        k_main = uploaded.mat_t.shape[0]  # every feature, or the main plane's
+        mat_t, norms, tail = _scatter_rows_t(
+            uploaded.mat_t, uploaded.norms, uploaded.tail, rows,
+            values[:, :k_main], _tail_values(values, k_main, uploaded.tail), new_norms,
         )
-        return StreamingItemMatrix(mat_t=mat_t, norms=norms, n_items=count)
+        return dataclasses.replace(uploaded, mat_t=mat_t, norms=norms, tail=tail, n_items=count)
     mat, norms = uploaded
     return _scatter_rows(mat, norms, rows, values, new_norms)
 
@@ -699,7 +735,14 @@ def upload_random(
 
         n_pad = max(BLOCK_N, ((n_items + BLOCK_N - 1) // BLOCK_N) * BLOCK_N)
         mat_t, norms = _gen_streaming_random(key, num_features, n_pad, n_items, dtype)
-        return StreamingItemMatrix(mat_t=mat_t, norms=norms, n_items=n_items)
+        t = tail_rows(num_features, dtype)
+        if not t:
+            return StreamingItemMatrix(mat_t=mat_t, norms=norms, n_items=n_items)
+        # the same values as one plane would hold, cut where upload cuts them
+        mat_t, tail = _split_planes(mat_t, t)
+        return StreamingItemMatrix(
+            mat_t=mat_t, norms=norms, n_items=n_items, features=num_features, tail=tail
+        )
     mat, norms = _gen_plain_random(key, n_items, num_features, dtype)
     return mat, norms
 
@@ -718,6 +761,15 @@ def _mask_and_norms(mat_t, n_items_arr, n_pad):
         jnp.sum(jnp.square(mat_t.astype(jnp.float32)), axis=0, keepdims=True)
     )
     return mat_t, norms
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _split_planes(mat_t, t):
+    """(main plane, tail plane of ``t`` rows) of a whole [k, n] float32
+    plane, as ``pallas_topn.split_features`` cuts a host matrix."""
+    k_main = mat_t.shape[0] - mat_t.shape[0] % 8
+    tail = mat_t[k_main:]
+    return mat_t[:k_main], jnp.pad(tail, ((0, t - tail.shape[0]), (0, 0)))
 
 
 def _gen_streaming_random(key, num_features, n_pad, n_items, dtype):

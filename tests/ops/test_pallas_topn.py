@@ -497,3 +497,162 @@ def test_row_norms_equal_numpy_bit_for_bit(n, k):
     got = row_norms(rows)
     assert got.dtype == np.float32
     np.testing.assert_array_equal(got, np.linalg.norm(rows, axis=1))
+
+
+# -- the split layout: a float32 matrix streamed at its logical feature width -----------------
+
+# features -> (main plane rows, tail plane rows or None, rows the device stores an item)
+SPLIT_LAYOUT = {
+    1: (1, None, 1), 2: (2, None, 2), 4: (4, None, 4),  # no whole tile before the tail
+    30: (30, None, 32),  # 6 rows over: a 4-row tail cannot hold them, padded as ever
+    48: (48, None, 48),
+    50: (48, 2, 50), 100: (96, 4, 100), 250: (248, 2, 250),
+    51: (48, 4, 52),  # 3 rows ride in a 4-row tail
+    9: (8, 1, 9),
+}
+
+
+def _small_ints(shape, seed, low=1, high=3):
+    """Small whole numbers of either sign, none zero, as float32: every
+    dot product is exact in any order of summation, so equal scores are
+    equal bit for bit (and there are many), no sum is a negative zero
+    (which ``lax.top_k`` ranks below zero and the kernel does not), and
+    the ids of two exact scans must agree one for one."""
+    gen = np.random.default_rng(seed)
+    return (gen.integers(low, high, shape) * gen.choice([-1, 1], shape)).astype(np.float32)
+
+
+@pytest.mark.parametrize("features", sorted(SPLIT_LAYOUT))
+def test_float32_upload_stores_the_logical_rows_where_the_rule_engages(features):
+    from oryx_tpu.common.metrics import registry as metrics
+
+    main, tail, stored = SPLIT_LAYOUT[features]
+    y = _small_ints((300, features), features)
+    up = ptn.upload_streaming(y)
+    assert up.mat_t.shape == (main, ptn.BLOCK_N) and up.mat_t.dtype == jnp.float32
+    assert (None if up.tail is None else up.tail.shape) == (tail and (tail, ptn.BLOCK_N))
+    assert ptn.tail_rows(features, np.float32) == (tail or 0)
+    assert up.num_features == features and ptn.stored_feature_rows(up) == stored
+    snap = metrics.snapshot()
+    assert snap["serving.scan.feature-rows.logical"]["value"] == features
+    assert snap["serving.scan.feature-rows.stored"]["value"] == stored
+    # the planes hold the matrix: main rows first, the tail's after, zeros beyond
+    planes = [np.asarray(up.mat_t)] + ([] if tail is None else [np.asarray(up.tail)])
+    whole = np.concatenate(planes)
+    np.testing.assert_array_equal(whole[:features, :300], y.T)
+    assert not whole[features:].any() and not whole[:, 300:].any()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("features", [50, 250])
+def test_bfloat16_and_int8_keep_one_plane(features, dtype):
+    """Tiles of 16 and 32 rows, no tail: the layout these handles had."""
+    y = _small_ints((300, features), 3)
+    up = ptn.upload_streaming(y, dtype=jnp.dtype(dtype))
+    assert up.tail is None and ptn.tail_rows(features, jnp.dtype(dtype)) == 0
+    rows = ptn._ceil_to(features, 32) if dtype == "int8" else features
+    assert up.mat_t.shape == (rows, ptn.BLOCK_N) and up.num_features == features
+    assert ptn.stored_feature_rows(up) == ptn._ceil_to(features, 16 if dtype == "bfloat16" else 32)
+
+
+@pytest.mark.parametrize("backend", ["kernel", "xla-twin"])
+@pytest.mark.parametrize("metric", ["dot", "cosine"])
+@pytest.mark.parametrize("features", [1, 2, 4, 30, 48, 50, 100, 250])
+def test_split_layout_scan_equals_the_plain_scan(features, metric, backend):
+    """Ids equal one for one, ties in item-id order, against
+    ``_plain_topk_groups`` on the same factors and norms: through the
+    interpreter's kernel and through the XLA twin, indexed and by vector,
+    over two grid steps with the second partly padding."""
+    n, b, k = 20_000, 8, 24
+    y, x = _small_ints((n, features), features), _small_ints((64, features), 1000 + features)
+    cosine = metric == "cosine"
+    up = ptn.upload_streaming(y)
+    assert up.mat_t.shape[1] == 2 * ptn.BLOCK_N
+    rows = np.arange(b, dtype=np.int32) * 3
+    x_dev = topn_ops.upload_queries(x)
+    norms = up.norms[0, :n]
+    rv, ri = topn_ops._plain_topk_groups(
+        jnp.asarray(y), norms, x_dev, jnp.asarray(rows[None, :]), k, cosine, None
+    )
+    interpret = True if backend == "kernel" else None
+    vals, idxs = ptn.scan_groups(
+        up, jnp.asarray(rows[None, :]), k, cosine=cosine, interpret=interpret, x_dev=x_dev
+    )
+    np.testing.assert_array_equal(np.asarray(idxs), np.asarray(ri))
+    if cosine:
+        np.testing.assert_allclose(np.asarray(vals), np.asarray(rv), rtol=0, atol=3e-7)
+    else:
+        np.testing.assert_array_equal(np.asarray(vals), np.asarray(rv))
+        ties = np.asarray(vals)[0, :, 1:] == np.asarray(vals)[0, :, :-1]
+        assert ties.any()  # the data has equal scores, and they came out lowest id first
+        assert (np.diff(np.asarray(idxs)[0], axis=1)[ties] > 0).all()
+    by_vector = ptn.top_k_streaming(up, x[rows], k, cosine=cosine, interpret=interpret)
+    np.testing.assert_array_equal(by_vector[0], np.asarray(idxs)[0])
+
+
+@pytest.mark.parametrize("layout", ["one-device", "sharded"])
+@pytest.mark.parametrize("features", [50, 51, 250, 48])
+def test_a_row_update_writes_both_planes(features, layout):
+    """Updated and appended rows leave the handle plane for plane what a
+    fresh upload of the updated matrix holds, and are served."""
+    from oryx_tpu.parallel.mesh import get_mesh
+
+    n = 4001
+    y = _small_ints((n, features), 7)
+    fresh = np.abs(_small_ints((5, features), 8, low=3, high=6))  # beat every old row
+    rows = np.array([0, 1999, 2000, 4000, 4001], dtype=np.int32)  # the last one appends
+    after = np.concatenate([y, np.zeros((1, features), np.float32)])
+    after[rows] = fresh
+
+    def upload(mat):
+        if layout == "sharded":
+            return topn_ops.upload_sharded(mat, get_mesh())
+        return ptn.upload_streaming(mat)
+
+    # in place: plane for plane a fresh upload (an append would cut new shards)
+    got, want = topn_ops.update_rows(upload(y), rows[:4], fresh[:4]), upload(after[:n])
+    assert got.n_items == n and got.features == want.features
+    assert (got.tail is None) == (want.tail is None) == (features % 8 == 0)
+    for name in ("mat_t", "tail", "norms"):
+        a, b = getattr(got, name), getattr(want, name)
+        if a is not None or b is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    got = topn_ops.update_rows(got, rows[4:], fresh[4:], n_items=n + 1)
+    assert got.n_items == n + 1
+    q = np.ones((1, features), np.float32)
+    idx, vals = topn_ops.top_k_scores_batch(got, q, 5)
+    ridx, rvals = _ref_topk(q @ after.T, 5)
+    np.testing.assert_array_equal(idx, ridx)
+    np.testing.assert_array_equal(vals, rvals)
+    assert set(idx[0]) == set(rows.tolist())
+
+
+@pytest.mark.parametrize("features", [50, 250])
+def test_sharded_split_layout_equals_one_device(features):
+    """Every shard holds the same two planes the one-device handle would
+    hold of its rows, and the merged answer is the one-device answer."""
+    from oryx_tpu.parallel.mesh import get_mesh
+
+    y, q = _small_ints((9001, features), 11), _small_ints((6, features), 12)
+    mesh = get_mesh()
+    up = topn_ops.upload_sharded(y, mesh)
+    d = mesh.devices.size
+    main, tail, stored = SPLIT_LAYOUT[features]
+    assert up.mat_t.shape == (main, d * up.cols) and up.tail.shape == (tail, d * up.cols)
+    assert up.features == features and f", {features})" in topn_ops.sharded_layout(up)
+    assert {s.device for s in up.tail.addressable_shards} == set(mesh.devices.flat)
+    for metric in (False, True):
+        si, sv = topn_ops.top_k_scores_batch(up, q, 12, cosine=metric)
+        oi, ov = topn_ops.top_k_scores_batch(ptn.upload_streaming(y), q, 12, cosine=metric)
+        np.testing.assert_array_equal(si, oi)
+        np.testing.assert_array_equal(sv, ov)
+
+
+def test_upload_random_splits_the_same_values():
+    up = topn_ops.upload_random(3000, 50, jnp.float32, seed=5, streaming=True)
+    whole, _ = topn_ops._gen_streaming_random(
+        __import__("jax").random.PRNGKey(5), 50, ptn.BLOCK_N, 3000, jnp.float32
+    )
+    assert up.mat_t.shape == (48, ptn.BLOCK_N) and up.tail.shape == (2, ptn.BLOCK_N)
+    assert up.num_features == 50
+    np.testing.assert_array_equal(np.concatenate([up.mat_t, up.tail]), np.asarray(whole))

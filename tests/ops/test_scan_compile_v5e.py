@@ -33,6 +33,22 @@ CASES = {
     "b256-250f": (250, "float32", 256, 32),
 }
 
+# The float32 programs as they are SERVED since PR 30: the item matrix
+# split into a main plane of whole sublane tiles and a tail plane of
+# `features % 8` rows (pallas_topn.tail_rows). tests/benchmark's compile
+# tests lower the un-split operand form, which the same kernel still takes.
+# name: (features, batch rows, cosine)
+SPLIT_CASES = {
+    "split-50f-b8": (50, 8, False),
+    "split-50f-b32": (50, 32, False),
+    "split-50f-b128": (50, 128, False),
+    "split-250f-b8": (250, 8, False),
+    "split-250f-b32": (250, 32, False),
+    "split-250f-b128": (250, 128, False),
+    "split-50f-cosine": (50, 16, True),
+    "split-250f-b256-cosine": (250, 256, True),
+}
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -99,6 +115,85 @@ def test_scan_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
     assert vals.shape == idxs.shape == (1, batch, k)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("case", SPLIT_CASES)
+def test_served_split_scan_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
+    """Main plane + tail plane as two streamed operands of the ONE kernel,
+    and the device holds the matrix at its logical width: the arguments
+    are smaller than the un-split program's by the padding rows."""
+    import jax.numpy as jnp
+
+    from oryx_tpu.ops import pallas_topn
+
+    features, batch, cosine = SPLIT_CASES[case]
+    items = SHAPES[features]
+    n_pad = pallas_topn._ceil_to(items, pallas_topn.BLOCK_N)
+    tail = pallas_topn.tail_rows(features, jnp.float32)
+    assert tail == 2
+
+    shape = functools.partial(_shape, one_chip)
+    row, users, rows = shape((1, n_pad), jnp.float32), shape((4096, features), jnp.float32), shape((1, batch), jnp.int32)
+    static = dict(k=32, n_items=items, cosine=cosine, interpret=False, download_dtype=None)
+    lowered = pallas_topn._streaming_topk_multi_indexed.lower(
+        shape((features - tail, n_pad), jnp.float32), row, None, None, None, users, rows,
+        tail=shape((tail, n_pad), jnp.float32), **static,
+    )
+    compiled = lowered.compile()  # raises what the chip's compiler would raise
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1  # one kernel, the named one
+    assert "%oryx_topn_scan" in text
+    assert f"f32[{tail},{n_pad}]{{1,0:T({tail},128)}}" in text  # the tail is stored 2 rows high
+    (vals, idxs) = lowered.out_info
+    assert vals.shape == idxs.shape == (1, batch, 32)
+    stored = compiled.memory_analysis().argument_size_in_bytes
+    whole = pallas_topn._streaming_topk_multi_indexed.lower(
+        shape((features, n_pad), jnp.float32), row, None, None, None, users, rows, **static
+    ).compile().memory_analysis().argument_size_in_bytes
+    padding = (pallas_topn._ceil_to(features, 8) - features) * n_pad * 4
+    assert stored == whole - padding
+
+
+def test_served_split_sharded_program_compiles_for_a_v5e_host(no_persistent_cache):
+    """The four-chip cell's program in its served operand form: each chip
+    its `[248, cols]` main and `[2, cols]` tail plane."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from oryx_tpu.ops import pallas_topn, topn
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe it is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    d, f, batch = 4, 250, 32
+    cols = pallas_topn._ceil_to(SHAPES[f], pallas_topn.BLOCK_N)
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    fn = topn._sharded_scan_fn(mesh, 32, False, False, True, None, False, tailed=True)
+    lowered = fn.lower(
+        shape((f - 2, d * cols), jnp.float32, P(None, "data")),
+        shape((1, d * cols), jnp.float32, P(None, "data")),
+        (shape((2, d * cols), jnp.float32, P(None, "data")),),
+        shape((d,), jnp.int32, P("data")), shape((d,), jnp.int32, P("data")),
+        shape((1, batch), jnp.int32, P()),
+        shape((1_250_000, f), jnp.float32, P()),
+    )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "oryx_topn_scan" in text and "all-gather" in text
+    assert f"f32[{f - 2},{cols}]" in text and f"f32[2,{cols}]" in text
+    mem = compiled.memory_analysis()  # a device's own: its two planes, norms, the staged users
+    assert mem.argument_size_in_bytes < (SHAPES[f] * 1.01 + 1_250_000) * f * 4 + 2 * cols * 4
 
 
 def test_counting_scan_compiles_for_the_v5e(one_chip, no_persistent_cache):

@@ -20,8 +20,9 @@ What is checked (ISSUE 21 item 5):
   query matrix (``multi-indexed``): not the full cross, see
   ``scan_checks`` for which and why;
 - the same kernel at a k bucket of 256 (two vregs of state a row);
-- the mesh-sharded scan on whatever devices exist (f32; the int8 planes
-  shard the same way and are summed in full there);
+- the mesh-sharded scan on whatever devices exist, at both widths (f32,
+  so the split layout: a main and a tail plane on every shard; the int8
+  planes shard the same way and are summed in full there);
 - the fused Pallas k-means sweep, full and mini-batch, at d = 250 with k
   at the ``fits_vmem`` edge;
 - the IVF device probe at >= 1M items;
@@ -228,9 +229,10 @@ class Checks:
         # row counts and both metrics, every dtype with explicit groups (multi
         # or multi-indexed), and two groups a half under lax.map once; at 50
         # features, where only tile sizing and the int8 sublane padding
-        # differ, every dtype and both row counts. PERF.md section 7 lists
-        # what is left out.
-        wide = features == 250
+        # differ, every dtype and both row counts. float32 is stored split
+        # at both widths (main plane + 2-row tail plane): dot, cosine and
+        # the sharded form run it at each. PERF.md section 7 lists what is
+        # left out.
         plan = {
             250: [
                 ("float32", False, "one-group", "single"),
@@ -249,6 +251,7 @@ class Checks:
             ],
             50: [
                 ("float32", False, "two-groups", "single"),
+                ("float32", True, "one-group", "single"),
                 ("bfloat16", False, "one-group", "single"),
                 ("int8", False, "one-group", "single"),
                 ("int8", False, "two-groups", "single"),
@@ -271,6 +274,15 @@ class Checks:
                 handles.clear()  # one item matrix on the device at a time
                 handles[dtype] = pt.upload_streaming(mat, dtype=jdt[dtype])
             return handles[dtype]
+
+        def split_layout(up) -> dict:
+            """float32 at 50 and 250 features is stored split (main plane
+            of whole sublane tiles + a 2-row tail): every float32 check
+            below runs that layout, and says so."""
+            stored = pt.stored_feature_rows(up)
+            expect(up.tail is not None and up.tail.shape[0] == features % 8, "no tail plane")
+            expect(stored == features, f"{stored} feature rows stored for {features}")
+            return {"feature_rows": [features, stored]}
 
         def run(dtype, cosine, form, dispatch):
             x, x_dev = queries[dtype]
@@ -303,7 +315,8 @@ class Checks:
             idx, vals = np.asarray(idx).reshape(-1, k), np.asarray(vals).reshape(-1, k)
             if len(q) > big_b:  # the reference holds a [rows, n_items] f32 block
                 q, idx, vals = q[::2], idx[::2], vals[::2]
-            return verify(dtype, cosine, q, idx, vals)
+            layout = split_layout(handle(dtype)) if dtype == "float32" else {}
+            return {**verify(dtype, cosine, q, idx, vals), **layout}
 
         for dtype, cosine, form, dispatch in plan:
             self.check(
@@ -312,30 +325,29 @@ class Checks:
             )
         handles.clear()
 
-        if wide:
-            from oryx_tpu.parallel.mesh import get_mesh
+        from oryx_tpu.parallel.mesh import get_mesh
 
-            def sharded():
-                # the served shapes: vector submit, then the same rows by
-                # index into the user matrix staged on every device, then
-                # a row updated on each shard
-                mesh = get_mesh()
-                up = topn_ops.upload_sharded(mat, mesh, dtype=jnp.float32)
-                shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat_t.addressable_shards]
-                q = x[:64]
-                idx, vals = topn_ops.top_k_scores_batch(up, q, k)
-                by_row, _ = topn_ops.submit_top_k_multi_indexed(
-                    up, topn_ops.upload_queries(x, mesh=mesh), np.arange(64, dtype=np.int32), k
-                ).result()
-                expect(np.array_equal(idx, by_row), "indexed submit differs from vector submit")
-                last = np.asarray(up.starts) + np.asarray(up.counts) - 1
-                best = 50.0 * x[: len(last)]
-                top, _ = topn_ops.top_k_scores_batch(topn_ops.update_rows(up, last, best), best, 1)
-                expect(top[:, 0].tolist() == last.tolist(), "a row update missed its shard")
-                return {**verify("float32", False, q, idx, vals), "shards": shards,
-                        "layout": topn_ops.sharded_layout(up)}
+        def sharded():
+            # the served shapes: vector submit, then the same rows by
+            # index into the user matrix staged on every device, then
+            # a row updated on each shard (it lands in both planes)
+            mesh = get_mesh()
+            up = topn_ops.upload_sharded(mat, mesh, dtype=jnp.float32)
+            shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat_t.addressable_shards]
+            q = x[:64]
+            idx, vals = topn_ops.top_k_scores_batch(up, q, k)
+            by_row, _ = topn_ops.submit_top_k_multi_indexed(
+                up, topn_ops.upload_queries(x, mesh=mesh), np.arange(64, dtype=np.int32), k
+            ).result()
+            expect(np.array_equal(idx, by_row), "indexed submit differs from vector submit")
+            last = np.asarray(up.starts) + np.asarray(up.counts) - 1
+            best = 50.0 * x[: len(last)]
+            top, _ = topn_ops.top_k_scores_batch(topn_ops.update_rows(up, last, best), best, 1)
+            expect(top[:, 0].tolist() == last.tolist(), "a row update missed its shard")
+            return {**verify("float32", False, q, idx, vals), "shards": shards,
+                    "layout": topn_ops.sharded_layout(up), **split_layout(up)}
 
-            self.check(f"scan/{features}f/float32/dot/sharded", sharded)
+        self.check(f"scan/{features}f/float32/dot/sharded", sharded)
 
     # -- k-means -------------------------------------------------------------
 

@@ -19,7 +19,8 @@ enters k items (adversarial: no cell looks like it).
 ``--root`` names the checkout whose ``oryx_tpu`` is imported (default:
 this file's own), so one chip call can run parent and change from the
 same script; every case's (scores, ids) go to ``DIR/<case>.npz`` and
-``--compare`` demands they are identical, bit for bit, between two runs.
+``--compare`` demands of two runs equal ids, equal counts of gated tiles
+and rounds, and scores within 1e-6 of the case's score scale.
 ``--tiny --interpret --platform cpu`` is the CPU rehearsal: its times are
 the interpreter's and mean nothing.
 """
@@ -27,6 +28,7 @@ the interpreter's and mean nothing.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import inspect
 import json
@@ -34,6 +36,12 @@ import statistics
 import sys
 import time
 from pathlib import Path
+
+
+# Two checkouts may sum a score's products in another order (the tail
+# plane's on the VPU, the rest on the MXU): ids, gated tiles and rounds
+# must be equal, scores within this share of the case's largest score.
+SCORE_TOLERANCE = 1e-6
 
 
 def compare(dir_a: Path, dir_b: Path) -> int:
@@ -46,12 +54,20 @@ def compare(dir_a: Path, dir_b: Path) -> int:
     bad = 0
     for name in names:
         a, b = np.load(dir_a / name), np.load(dir_b / name)
-        same = all(
-            a[key].dtype == b[key].dtype and np.array_equal(a[key], b[key])
-            for key in ("vals", "idxs")
+        scale = float(np.max(np.abs(a["vals"]))) or 1.0
+        err = float(np.max(np.abs(a["vals"].astype(np.float64) - b["vals"]))) / scale
+        counts = [key for key in ("gated_tiles", "rounds") if key in a and key in b]
+        same = (
+            a["vals"].dtype == b["vals"].dtype
+            and np.array_equal(a["idxs"], b["idxs"])
+            and err <= SCORE_TOLERANCE
+            and all(int(a[key]) == int(b[key]) for key in counts)
         )
         bad += not same
-        print(f"scan_rounds: compare {name}: {'identical' if same else 'DIFFERENT'}")
+        print(
+            f"scan_rounds: compare {name}: {'same' if same else 'DIFFERENT'}"
+            f" (score_err_of_scale {err:.3g}; compared {', '.join(['ids', 'scores'] + counts)})"
+        )
     print(f"scan_rounds: compared {len(names)} cases, {bad} differ")
     return 1 if bad else 0
 
@@ -120,7 +136,10 @@ def main(argv=None) -> int:
         def report(name, idx, is_sorted):
             line = _run_case(ptn, up, x_dev, idx, args, counting)
             case = f"{items}x{features}-{name}"
-            np.savez(args.out / f"{case}.npz", vals=line.pop("vals"), idxs=line.pop("idxs"))
+            counted = {key: line[key] for key in ("gated_tiles", "rounds") if key in line}
+            np.savez(
+                args.out / f"{case}.npz", vals=line.pop("vals"), idxs=line.pop("idxs"), **counted
+            )
             line = {"case": case, "items": items, "features": features, "sorted": is_sorted, **line}
             print("scan_rounds: " + json.dumps(line), flush=True)
 
@@ -131,9 +150,7 @@ def main(argv=None) -> int:
                 report(f"b{b}-distinct{d}", [1 + j % d for j in range(b)], False)
                 d *= 2
         ramp = jnp.arange(up.mat_t.shape[1], dtype=jnp.float32)
-        up = ptn.StreamingItemMatrix(
-            mat_t=_row0_setter()(up.mat_t, ramp), norms=up.norms, n_items=items
-        )
+        up = dataclasses.replace(up, mat_t=_row0_setter()(up.mat_t, ramp))
         for b in batches:
             report(f"b{b}-sorted", [0] * b, True)
         del up, x_dev
@@ -148,11 +165,14 @@ def _run_case(ptn, up, x_dev, idx, args, counting) -> dict:
     import numpy as np
 
     idx_b = jnp.asarray(idx, jnp.int32)
+    # a float32 handle may hold its last `features % 8` rows in a tail
+    # plane (pallas_topn.tail_rows); a checkout from before has none
+    tail = {} if getattr(up, "tail", None) is None else {"tail": up.tail}
 
     def dispatch():
         return ptn._streaming_topk_multi_indexed(
             up.mat_t, up.norms, None, None, None, x_dev, idx_b[None, :],
-            k=args.k, n_items=up.n_items, cosine=False, interpret=args.interpret,
+            k=args.k, n_items=up.n_items, cosine=False, interpret=args.interpret, **tail,
         )
 
     def ms_per_pass():
@@ -173,7 +193,7 @@ def _run_case(ptn, up, x_dev, idx, args, counting) -> dict:
     }
     if counting is not None:
         *_, counts = counting(
-            up.mat_t, up.norms, None, None, None, x_dev[idx_b], n_items=up.n_items
+            up.mat_t, up.norms, None, None, None, x_dev[idx_b], n_items=up.n_items, **tail
         )
         line["gated_tiles"], line["rounds"] = (int(c) for c in np.asarray(counts)[0])
     return line
