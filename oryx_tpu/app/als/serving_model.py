@@ -43,6 +43,40 @@ from oryx_tpu.serving.batcher import score_default, score_indexed_default
 
 log = logging.getLogger(__name__)
 
+# What the staged user matrix leaves free on a device: the scan's own
+# buffers (two passes in flight, each its gathered queries, its [b, k]
+# results and the program's temporaries: tens of MB at 128 rows) and the
+# allocator's fragmentation. Not in it, because a read-only replica never
+# pays them: the copy that a row update of the item matrix or of the staged
+# user matrix makes (both scatters donate nothing, a pass in flight may
+# hold the old array; docs/serving-scan.md).
+USER_STAGE_RESERVE_BYTES = 1 << 30
+# A backend that reports no memory statistics (the CPU of tests and
+# development, where the "device" is the host's own memory and a staged
+# copy doubles what the store holds) keeps the bound this rule replaced.
+USER_STAGE_DEFAULT_BUDGET_BYTES = 2 << 30
+
+
+def user_stage_budget(devices) -> int:
+    """Bytes the staged user matrix may take on each of ``devices`` (it is
+    held whole on every one): the smallest, over them, of the allocator's
+    limit less what is in use, less ``USER_STAGE_RESERVE_BYTES``; never
+    negative. Read when a (re)stage is about to allocate;
+    ``_rebuild_x_staging`` sees to it that the item matrix is counted."""
+    free = []
+    for dev in devices:
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+            return USER_STAGE_DEFAULT_BUDGET_BYTES
+        free.append(int(stats["bytes_limit"]) - int(stats["bytes_in_use"]))
+    if not free:
+        return USER_STAGE_DEFAULT_BUDGET_BYTES
+    return max(0, min(free) - USER_STAGE_RESERVE_BYTES)
+
+
+class _UserVanished(Exception):
+    """A user listed by the store had no vector a moment later."""
+
 
 class ALSServingModel(ServingModel):
     def __init__(
@@ -62,6 +96,7 @@ class ALSServingModel(ServingModel):
         # only meaningful for the exact-device-scan path
         self.device_user_matrix = device_user_matrix
         self._x_staging = bool(device_user_matrix) and sample_rate >= 1.0
+        self._x_stage_refused = False  # the budget refused it: _refuse_x_staging
         # row-shard Y over all local devices (the kernel on every shard,
         # candidates merged across chips; X staged on each): the serving
         # mode of a catalog past one chip's memory, docs/serving-scan.md
@@ -133,6 +168,9 @@ class ALSServingModel(ServingModel):
         self._x_building = False
         self._x_restage_thread: threading.Thread | None = None
         self._x_epoch = 0  # bumped by rotation: invalidates in-flight restages
+        # taken here so that the counter is in every snapshot from the
+        # start: absent means the program does not count this, never 0
+        self._unstaged_requests = metrics.registry.counter("serving.users.unstaged-requests")
 
     # -- vectors -------------------------------------------------------------
 
@@ -422,60 +460,128 @@ class ALSServingModel(ServingModel):
         self._x_matrix = topn_ops.update_query_rows(self._x_matrix, rows, vals)
         return True
 
-    # staged X bigger than this is not worth the HBM next to Y: fall back
-    # to vector submit rather than risk OOMing a previously-fine deploy
-    _X_STAGE_MAX_BYTES = 2 << 30
+    def _refuse_x_staging(self, rows: int, need: int, budget: int) -> None:
+        """All or nothing: a user matrix that does not fit the device's
+        budget is not staged, and every known user's request goes by the
+        vector path (a store lookup and a [b, features] float32 upload a
+        pass) for this model's lifetime."""
+        log.warning(
+            "user matrix not staged: %d users x %d features ask %d bytes on the device "
+            "(25 %% headroom included) and the budget is %d (the device's limit less what "
+            "is in use less a reserve of %d); every known user's request goes by the "
+            "vector path (gauge serving.users.stage.refused = 1)",
+            rows, self.features, need, budget, USER_STAGE_RESERVE_BYTES,
+        )
+        metrics.registry.gauge("serving.users.stage.refused").set(1)
+        metrics.registry.gauge("serving.users.staged-rows").set(0)
+        metrics.registry.gauge("serving.users.staged-bytes").set(0)
+        with self._cache_lock:
+            # flip + drain under the same lock that set_user_vector
+            # appends dirty ids under, so no stale dirty set is
+            # retained for the model's lifetime after the disable
+            self._x_matrix = None
+            self._x_capacity = 0
+            self._x_staging = False
+            self._x_stage_refused = True
+            self._x_dirty_ids.clear()
+            self._x_dirty = False
+
+    def _stage_devices(self):
+        """The devices that each hold the staged user matrix whole."""
+        mesh = self._shard_mesh()
+        if mesh is not None:
+            return list(mesh.devices.flat)
+        import jax
+
+        return jax.local_devices()[:1]
 
     def _rebuild_x_staging(self, pre_dirty: set[str], epoch: int) -> None:
         """Full X restage, run by the triggering request thread OUTSIDE
-        the cache lock (to_matrix + a potentially multi-GB upload must
-        not stall Y scoring); the swap happens under the lock and is
-        DISCARDED if a rotation bumped the epoch mid-build (the snapshot
-        predates it; the next tick rebuilds from the rotated store). Ids
-        written during the build stay dirty and catch up on the next
-        refresh tick; incremental scatters are held off while a build is
-        in flight so the swap can never clobber one."""
+        the cache lock (reading the store and a potentially multi-GB
+        upload must not stall Y scoring); the swap happens under the lock
+        and is DISCARDED if a rotation bumped the epoch mid-build (the
+        snapshot predates it; the next tick rebuilds from the rotated
+        store). Ids written during the build stay dirty and catch up on
+        the next refresh tick; incremental scatters are held off while a
+        build is in flight so the swap can never clobber one.
+
+        The rows go up in chunks read from the store (``stage_queries``):
+        the host never holds the matrix a second time, and the id -> row
+        index is built here, not under the lock that item scoring takes."""
         try:
-            ids, mat = self.x.to_matrix()
-            if len(ids) * self.features * 4 * 1.25 > self._X_STAGE_MAX_BYTES:
-                log.info(
-                    "device X (%d users x %d) exceeds the staging budget; "
-                    "index submit disabled for this model",
-                    len(ids), self.features,
-                )
-                with self._cache_lock:
-                    # flip + drain under the same lock that set_user_vector
-                    # appends dirty ids under, so no stale dirty set is
-                    # retained for the model's lifetime after the disable
-                    self._x_matrix = None
-                    self._x_capacity = 0
-                    self._x_staging = False
-                    self._x_dirty_ids.clear()
-                    self._x_dirty = False
+            t0 = time.monotonic()
+            ids = self.x.ids()
+            n = len(ids)
+            index = dict(zip(ids, range(n)))
+            # pad capacity so a trickle of new users appends via
+            # scatter instead of re-uploading everything
+            cap = max(64, int(n * 1.25)) if n else 0
+            need = cap * self.features * 4
+            # The request that started this thread goes on to upload the
+            # item matrix, and the two must not race for the same room.
+            # Where the users fit even with a device's whole share of the
+            # item matrix still to come (float32 and its padding: the most
+            # it can ask; counted twice if it is up already) the staging
+            # runs beside that upload; where they do not clearly fit, the
+            # item matrix goes first and the budget is read again, exact.
+            devices = self._stage_devices()
+            budget = user_stage_budget(devices)
+            waited = 0.0
+            items_to_come = int(self.y.size() * self.features * 4 * 1.1) // len(devices)
+            if need > budget - items_to_come:
+                waited = time.monotonic()
+                self._ensure_y_matrix()
+                waited = time.monotonic() - waited  # the item upload's time, not staging's
+                budget = user_stage_budget(devices)
+            metrics.registry.gauge("serving.users.stage-budget-bytes").set(budget)
+            if need > budget:
+                self._refuse_x_staging(n, need, budget)
                 return
-            if len(ids):
-                # pad capacity so a trickle of new users appends via
-                # scatter instead of re-uploading everything
-                cap = max(64, int(len(ids) * 1.25))
-                pad = np.zeros((cap - len(ids), self.features), np.float32)
-                staged = topn_ops.upload_queries(
-                    np.concatenate([mat, pad]) if cap > len(ids) else mat,
-                    mesh=self._shard_mesh(),
-                )
-            else:
-                staged, cap = None, 0
+            step = topn_ops.query_chunk_rows(self.features)
+            chunks = -(-n // step)
+
+            def rows_of_the_store():
+                for lo in range(0, n, step):
+                    vals, valid = self.x.get_batch(ids[lo : lo + step], dim=self.features)
+                    if not np.all(valid):
+                        raise _UserVanished
+                    yield vals
+
+            staged = None
+            if n:
+                with profiling.annotate(
+                    "serving.users.stage", rows=n, bytes=need, chunks=chunks
+                ):
+                    try:
+                        staged = topn_ops.stage_queries(
+                            rows_of_the_store(), cap, self.features, mesh=self._shard_mesh()
+                        )
+                    except _UserVanished:
+                        # membership shrank under the build: the next tick
+                        # restages from the store as it then is
+                        return
+                    staged.block_until_ready()
             profiling.record_device_memory_peak()
             with self._cache_lock:
                 if self._x_epoch != epoch:
                     return  # rotation landed mid-build: discard the snapshot
-                self._x_ids = list(ids)
-                self._x_index = {id_: i for i, id_ in enumerate(ids)}
+                self._x_ids = ids
+                self._x_index = index
                 self._x_matrix = staged
                 self._x_capacity = cap
                 self._x_full_rebuild = False
                 self._x_dirty_ids -= pre_dirty
                 self._x_dirty = bool(self._x_dirty_ids)
                 self._x_built_at = time.monotonic()
+            metrics.registry.gauge("serving.users.stage.refused").set(0)
+            metrics.registry.gauge("serving.users.staged-rows").set(n)
+            metrics.registry.gauge("serving.users.staged-bytes").set(need)
+            seconds = time.monotonic() - t0 - waited
+            metrics.registry.histogram("serving.users.stage.seconds").observe(seconds)
+            log.info(
+                "user matrix staged: %d rows (capacity %d), %d bytes of a budget of %d, "
+                "%d chunks, %.2f s", n, cap, need, budget, chunks, seconds,
+            )
         finally:
             # under the cache lock: _user_scan_row reads this flag under
             # the lock to decide whether a scatter is safe, and a
@@ -511,6 +617,13 @@ class ALSServingModel(ServingModel):
                     self._x_building = True
                     rebuild_dirty = set(self._x_dirty_ids)
                     rebuild_epoch = self._x_epoch
+                    if self._x_full_rebuild:
+                        # after a rotation no row of the old matrix serves
+                        # (stale, below): let it go before the restage reads
+                        # its budget, or a matrix of several GB is refused
+                        # for want of room beside its own predecessor
+                        self._x_matrix = None
+                        self._x_capacity = 0
             stale = (
                 self._x_matrix is None
                 or self._x_full_rebuild  # rotation pending: rows may be gone
@@ -578,6 +691,10 @@ class ALSServingModel(ServingModel):
         vec = self.get_user_vector(user)
         if vec is None:
             return None
+        if self._x_staging or self._x_stage_refused:
+            # a known user whose row was not there to ship: not staged yet,
+            # written since, a restage pending, or the budget refused
+            self._unstaged_requests.inc()
         return self.top_n(vec, how_many, exclude=exclude, rescorer=rescorer, cosine=cosine)
 
     def top_n(

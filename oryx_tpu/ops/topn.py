@@ -684,16 +684,67 @@ def top_k_scores(uploaded, query: np.ndarray, k: int, cosine: bool = False):
     return idx[0], vals[0]
 
 
-def upload_queries(queries: np.ndarray, mesh=None) -> jax.Array:
-    """Stage a [m, feat] query-vector matrix on device (float32), for
-    index-submitted scans; with a ``mesh`` (the sharded item layout) a
-    copy on each of its devices, so every shard gathers its rows itself."""
-    queries = np.atleast_2d(np.asarray(queries, np.float32))
-    if mesh is None:
-        return jnp.asarray(queries)
-    from oryx_tpu.parallel.mesh import replicated
+# rows of a staged query matrix travel in chunks of this many bytes: what a
+# caller that reads them from a store holds on the host at a time
+QUERY_CHUNK_BYTES = 256 << 20
 
-    return jax.device_put(queries, replicated(mesh))
+
+def query_chunk_rows(features: int) -> int:
+    """Rows of ``features`` float32 values in one staging chunk."""
+    return max(1, QUERY_CHUNK_BYTES // (4 * max(1, features)))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_query_rows(buf, chunk, start):
+    return jax.lax.dynamic_update_slice(buf, chunk, (start, 0))
+
+
+def _put_query_rows(buf, chunk: np.ndarray, start: int, where):
+    """One chunk on the device and written, DONE before the caller reads
+    its next: a device that is busy (an item matrix still on its way up)
+    would otherwise let every chunk queue beside the buffer, a second
+    copy of the matrix in all but name."""
+    buf = _write_query_rows(buf, jax.device_put(chunk, where), np.int32(start))
+    buf.block_until_ready()
+    return buf
+
+
+def stage_queries(chunks, capacity: int, features: int, mesh=None) -> jax.Array:
+    """A [capacity, features] float32 query matrix on the device, filled
+    from ``chunks`` (an iterable of [rows, features] arrays) in order from
+    row 0; the rows past the last chunk stay zero. The buffer is made at
+    its final size and each chunk is written into it in place (donated:
+    nothing else holds it yet) before the next is read, so neither the
+    host nor the device ever holds more than one chunk beside the matrix,
+    and headroom rows are never sent.
+    With a ``mesh`` (the sharded item layout) a copy on each of its
+    devices, so every shard gathers its rows itself."""
+    where = None
+    if mesh is not None:
+        from oryx_tpu.parallel.mesh import replicated
+
+        where = replicated(mesh)
+    buf = jnp.zeros((capacity, features), jnp.float32, device=where)
+    at = 0
+    for chunk in chunks:
+        chunk = np.asarray(chunk, np.float32)
+        if at + len(chunk) > capacity:
+            raise ValueError(f"{at + len(chunk)} query rows exceed the capacity {capacity}")
+        if len(chunk):
+            buf = _put_query_rows(buf, chunk, at, where)
+            at += len(chunk)
+    return buf
+
+
+def upload_queries(queries: np.ndarray, mesh=None) -> jax.Array:
+    """Stage a whole [m, feat] query-vector matrix on device (float32),
+    for index-submitted scans: :func:`stage_queries` of its own rows."""
+    queries = np.atleast_2d(np.asarray(queries, np.float32))
+    step = query_chunk_rows(queries.shape[1])
+    return stage_queries(
+        (queries[lo : lo + step] for lo in range(0, len(queries), step)),
+        len(queries), queries.shape[1], mesh=mesh,
+    )
 
 
 def upload_random(

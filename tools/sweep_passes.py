@@ -1,0 +1,126 @@
+"""python3 tools/sweep_passes.py --workload <open cell> --seed <n> --rates 500,1000,600:30,...
+       [--seconds 10] [--trace 0|1] [--raw]
+
+`benchmark/sweep.py` for an open-loop cell, with the batcher's and the
+device's view beside the generator's: one set-up, one window a rate, and
+for each window p50 / p95, the shed share, how late the generator ran, the
+ladder's pressure at the window's end, rows and passes
+(`serving.batcher.pass.*`), queue wait and pass in flight, and, with
+`--trace 1`, the device's idle share and the scan kernel's ms a pass from
+a profiler recording of the 4 s after the window at the same load; with
+`--raw`, every request's due time and latency of every window. A tool
+for the builder who sites a cell's rate (knee = the highest rate with no
+shed answer and the generator on time); the driver's check never runs it."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import run, stats  # noqa: E402
+from benchmark import spec as spec_mod  # noqa: E402
+
+
+def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
+               raw: dict | None = None) -> dict:
+    got, result, span, reduced, _w = session.window(
+        seed, seconds, trace, {"cell": {"rate_per_s": rate}}
+    )
+    if raw is not None:
+        # every request's due time and latency, for a builder who asks how
+        # a window's tail is made (what a pause of the machine moves)
+        i = len(raw) // 2
+        due, done = np.asarray(result["due"]), np.asarray(result["done"])
+        raw[f"due_{i}_{rate:g}"] = due - result["window"][0]
+        raw[f"lat_ms_{i}_{rate:g}"] = 1000.0 * (done - due)
+    before, after = span["window"]
+
+    def d(metric, field="value"):
+        return stats.counter_delta(before, after, metric, field)
+
+    def mean_ms(hist):
+        return 1000.0 * d(hist, "sum") / max(d(hist, "count"), 1.0)
+
+    passes = max(d("serving.batcher.passes"), 1.0)
+    row = {
+        "rate_per_s": rate,
+        "seconds": seconds,
+        "attempted": got["attempted"],
+        "failed": got["failed"],
+        "shed_pct": 100.0 * got["failed"] / max(got["attempted"], 1),
+        "kinds": result.get("kinds", {}),
+        **{k: got["values"].get(k) for k in (
+            "recommend_p50_ms", "recommend_p95_ms", "recommend_p99_ms", "generator_late_p99_ms"
+        )},
+        "overload_pressure": (after.get("serving.overload.pressure") or {}).get("value"),
+        "handler_mean_ms": mean_ms("serving.request.seconds"),
+        "queue_wait_mean_ms": mean_ms("serving.batcher.queue-wait.seconds"),
+        "pass_inflight_mean_ms": mean_ms("serving.batcher.pass.seconds"),
+        "passes_per_s": passes / seconds,
+        "rows_per_pass": d("serving.batcher.pass.rows") / passes,
+        "inflight_depth_mean": d("serving.batcher.pass.inflight-depth-sum") / passes,
+        "indexed_pct": 100.0 * d("serving.scan.indexed.queries")
+        / max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0),
+        "unstaged_requests": d("serving.users.unstaged-requests"),
+        "compiles": d("jax.compile.seconds", "count"),
+        "server_pause_max_ms": session.pause["max_ms"],
+        "generator_pause_max_ms": result["pause"]["max_ms"],
+    }
+    if reduced is not None:
+        from benchmark import trace as trace_mod
+
+        row["device_idle_pct"] = 100.0 * (1.0 - reduced["busy_s"] / reduced["window_s"])
+        n, s = trace_mod.matching(reduced, "oryx_topn")
+        if n:
+            row["scan_kernel_ms_per_pass"] = 1000.0 * s / n
+    return row
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--rates", required=True,
+                    help="requests/s, comma-separated, in this order; `600:30` = a 30 s window")
+    ap.add_argument("--seconds", type=float, default=10.0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=1)
+    ap.add_argument("--raw", action="store_true",
+                    help="also save every request's due time and latency (an .npz beside the rows)")
+    ap.add_argument("--allow-cpu", action="store_true", help="rehearsal: no device number")
+    ap.add_argument("--root", default=spec_mod.ROOT,
+                    help="rehearsal: a copy of the benchmark with tiny cells")
+    args = ap.parse_args(argv)
+    session = run.Session(
+        spec_mod.Spec(args.root), args.workload, args.seed, require_chip=not args.allow_cpu
+    )
+    rows = []
+    raw = {} if args.raw else None
+    try:
+        print("setup: " + ", ".join(f"{k[:-2]} {v:.2f} s" for k, v in session.timings.items()))
+        for i, item in enumerate(r for r in args.rates.split(",") if r):
+            rate, _, seconds = item.partition(":")
+            row = window_row(session, float(rate), args.seed + 1000 * i + int(float(rate)),
+                             float(seconds or args.seconds), bool(args.trace), raw)
+            rows.append(row)
+            print("sweep_passes:", json.dumps(row), flush=True)
+    finally:
+        session.close()
+    out = os.path.join("chiprun_out", f"sweep_passes_{args.workload}_{args.seed}.json")
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(out, "w", encoding="utf-8") as f:
+        json.dump(rows, f, indent=1)
+    if raw:
+        np.savez_compressed(out[:-5] + "_raw.npz", **raw)
+    return 0
+
+
+if __name__ == "__main__":
+    code = main()
+    sys.stdout.flush()
+    os._exit(code)
