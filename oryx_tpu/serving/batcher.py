@@ -13,7 +13,9 @@ serving pattern:
 - request threads enqueue (item-matrix handle, query, k, cosine) and
   block on an event;
 - a dispatcher thread takes whatever is queued the moment it wakes —
-  no artificial wait, so an idle server adds zero batching latency —
+  no artificial wait, so an idle server adds zero batching latency: a
+  batch is kept open only while it could not run anyway (every slot
+  taken, or the pass ahead still far from its end: "The close" below) —
   groups entries by (matrix snapshot, cosine) so a model rotation
   mid-flight can never mix row indices from different snapshots, pads
   both k and the coalesced batch's row count to power-of-two buckets
@@ -48,6 +50,36 @@ is blocked anyway, so one full batch goes out where several fragments
 would have — ``serving.batcher.coalesced`` counts the requests that
 piggybacked this way.
 
+THE CLOSE. With a slot free and the other slot's pass still in flight,
+a batch submitted now would only sit in the device's queue behind that
+pass, and nothing can join it there: a request that arrives meanwhile
+waits for the batch after it, a whole pass more. So the dispatcher keeps
+such a batch OPEN, absorbing arrivals as it does while every slot is
+taken, until the pass ahead is due to leave the device within a lead,
+and submits then. It predicts from its own stamps and nothing else: the
+times the results of the last passes were on the host (``_PassTiming.t_ready``).
+Two passes that ran back to back are one SERVICE TIME apart there (kept
+per (handle, cosine, probes, padded rows, k bucket), a low quantile of
+the last few); a pass submitted to an idle device reads that and the
+RESULT LAG on top (launch, download, the completer's wake: a median of
+the last few such passes, whatever their key: it is the host's); its own
+submits it times itself (the longest of the last few). The lead is that
+submit time + the lag + ``HOLD_GUARD_S``, the one constant. By what it
+observes it falls back to the immediate close: nothing in flight, no
+estimate yet for the pass ahead (a new handle or bucket) or for the lag
+(no pass has started on an idle device since there were estimates: a
+replica that is never idle runs the schedule it always ran), the pass
+ahead due within the lead (every pass shorter than the refill: a small
+catalog, the CPU backend, where a dispatch is done when it returns), a
+full batch, shutdown. A hold needs a pass ahead that outlasts the lead,
+so an idle server still adds nothing; it never outlasts the predicted
+end of the pass ahead, and ends at once when that pass's results come
+early. The hold is queueing: it lies inside ``queue-wait.seconds`` and
+the wait EWMA, outside ``pass.seconds``. ``serving.batcher.pass.held``,
+``hold.rows``, ``hold.seconds``, ``hold.late`` and
+``hold.error.seconds`` say how often it engages and how well it
+predicts (docs/observability.md).
+
 The unit of work is the PASS: one device dispatch serving one coalesced
 group. Every pass is on the record three ways at the same boundaries
 (docs/observability.md): always-on counters and histograms
@@ -56,7 +88,8 @@ group. Every pass is on the record three ways at the same boundaries
 ``deliver.seconds``), a ``serving.pass`` span in the tracer's ring when a
 request of the pass is sampled, and ``serving.pass.submit`` /
 ``serving.pass.wait`` annotations on the profiler's timeline while a
-device trace records. None of it feeds a scheduling decision.
+device trace records. None of it feeds a scheduling decision: the close
+reads the dispatcher's own stamps, never the registry.
 """
 
 from __future__ import annotations
@@ -65,11 +98,10 @@ import logging
 import queue
 import threading
 import time
+from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 
 import numpy as np
-
-from collections import deque
 
 from oryx_tpu.common import profiling, tracing
 from oryx_tpu.common.metrics import registry as _metrics
@@ -93,6 +125,25 @@ MIN_INFLIGHT = 2
 WAIT_EWMA_ALPHA = 0.3
 WAIT_DECAY_GRACE_S = 0.25
 WAIT_DECAY_HALF_LIFE_S = 0.5
+
+# The close (module docstring): a batch behind a pass in flight is
+# submitted a LEAD before that pass's results are due on the host: the
+# dispatcher's own submit time + the result lag, both measured (below), +
+# this guard, which is what the prediction's error, the timer's and a
+# submit slower than the last few need on top. Found on the chip (PERF.md
+# section 6, PR 32: at 0.5 ms a sixth of the held passes reached the device
+# late, at 1.0 ms 4 %, at 1.5 ms 1 %, and the saturated cell is level at
+# each).
+HOLD_GUARD_S = 0.0015
+# Measured, each from the last HOLD_HISTORY of its kind: a pass's service
+# time per key, once HOLD_MIN_SAMPLES passes of the key were submitted
+# behind another, as their lower quartile (a machine pause reads anything,
+# and an estimate that is too short only closes sooner); the result lag as
+# the median of what the passes that started on an idle device took beyond
+# their service time; the submit as the longest.
+HOLD_HISTORY = 8
+HOLD_MIN_SAMPLES = 3
+HOLD_KEYS = 64  # estimates kept, least recently fed first out
 
 
 class BatcherClosedError(RuntimeError):
@@ -164,6 +215,25 @@ class _Pass:
     padded_rows: int  # rows the device is given
     k_bucket: int
     inflight: int  # passes in flight once this one held its slot
+    timing: _PassTiming  # what the dispatcher keeps of it
+
+
+@dataclass
+class _PassTiming:
+    """What the close keeps of a pass, and all it keeps: the dispatcher's
+    ``_flight`` holds these, never the ``_Pass`` itself, so the last
+    reference to a pass's handle (two device arrays, whose release costs
+    the thread that drops it about a millisecond on a busy TPU host) falls
+    in the completer as it always did, not on the dispatcher's way to the
+    next submit."""
+
+    key: tuple  # what decides the service time (the close's estimate)
+    t_submit: float
+    # perf_counter when the results were on the host or the pass had failed,
+    # stamped by the completer BEFORE it frees the slot; 0.0 = in flight
+    t_ready: float = 0.0
+    failed: bool = False
+    due: float = 0.0  # when a held batch behind it predicted its results (0.0: none was)
 
 
 def _record_pass_spans(p: _Pass, t_done: float) -> None:
@@ -407,6 +477,25 @@ class TopNBatcher:
         self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
         self._m_pass_seconds = _metrics.histogram("serving.batcher.pass.seconds")
         self._m_deliver_seconds = _metrics.histogram("serving.batcher.deliver.seconds")
+        self._m_coalesced = _metrics.counter("serving.batcher.coalesced")
+        self._m_held = _metrics.counter("serving.batcher.pass.held")
+        self._m_hold_rows = _metrics.counter("serving.batcher.hold.rows")
+        self._m_hold_late = _metrics.counter("serving.batcher.hold.late")
+        self._m_hold_seconds = _metrics.histogram("serving.batcher.hold.seconds")
+        self._m_hold_error = _metrics.histogram("serving.batcher.hold.error.seconds")
+        self._m_hold_lag = _metrics.gauge("serving.batcher.hold.lag-ms")
+        # the close's own state, all of it the dispatcher thread's: the
+        # timings of the passes it submitted whose results it has not yet
+        # seen on the host (the completer only stamps `t_ready` on them),
+        # when the last results were, the service times by key, the result
+        # lags, its own submit times, and the hold of the batch in hand
+        self._flight: deque[_PassTiming] = deque()
+        self._last_ready = 0.0
+        self._service_s: OrderedDict[tuple, deque[float]] = OrderedDict()
+        self._lag_s: deque[float] = deque(maxlen=HOLD_HISTORY)
+        self._submit_s: deque[float] = deque(maxlen=HOLD_HISTORY)
+        self._hold_s = 0.0  # how long the close of the batch in hand was held
+        self._hold_due = 0.0  # when the results of the pass ahead of it were due
         self._flight_cv = threading.Condition()
         self._inflight_count = 0
         self._state_lock = threading.Lock()  # serializes score-enqueue vs close
@@ -494,12 +583,74 @@ class TopNBatcher:
         with self._flight_cv:
             return self._inflight_count >= self._inflight_cap
 
+    def _settle(self) -> None:
+        """Dispatcher only: take the passes whose results have reached the
+        host off ``_flight`` and learn from each. One submitted before the
+        results ahead of it were there ran behind that pass: ready less
+        those results is its service time (and up to a lag more where the
+        device had already ended that pass: the low quantile's to discard;
+        the lag estimate is not used here, or an error in it would feed
+        itself). One submitted after them found the device idle: ready
+        less submit less the service time of its key, where there is one,
+        is the result lag."""
+        flight = self._flight
+        while flight and flight[0].t_ready:
+            p = flight.popleft()
+            if not p.failed and p.t_submit < self._last_ready:
+                samples = self._service_s.setdefault(p.key, deque(maxlen=HOLD_HISTORY))
+                samples.append(p.t_ready - self._last_ready)
+                self._service_s.move_to_end(p.key)
+                if len(self._service_s) > HOLD_KEYS:
+                    self._service_s.popitem(last=False)
+            elif not p.failed:
+                service_s = self._service_estimate(p.key)
+                if service_s is not None:
+                    self._lag_s.append(max(0.0, p.t_ready - p.t_submit - service_s))
+                    self._m_hold_lag.set(1000.0 * self._lag())
+            if p.due and not p.failed:
+                self._m_hold_error.observe(p.t_ready - p.due)
+            self._last_ready = p.t_ready
+
+    def _service_estimate(self, key: tuple) -> float | None:
+        """The lower quartile of the last passes of `key`, None before
+        ``HOLD_MIN_SAMPLES`` of them: one slow pass does not stretch it."""
+        samples = self._service_s.get(key)
+        if samples is None or len(samples) < HOLD_MIN_SAMPLES:
+            return None
+        return sorted(samples)[(len(samples) - 1) // 4]
+
+    def _lag(self) -> float:
+        """The median result lag of the last passes that started on an
+        idle device; 0.0 while there is none (and then no close is held)."""
+        return sorted(self._lag_s)[len(self._lag_s) // 2] if self._lag_s else 0.0
+
+    def _close_at(self) -> float | None:
+        """When the batch in hand has to be closed for its submit to reach
+        the device before the pass ahead leaves it, and None for now: no
+        pass ahead (or fewer in flight than the other slots hold: the
+        pipeline is filled first), no estimate for one of them or for the
+        lag. ``_hold_due`` is left at the results' predicted time."""
+        self._settle()
+        flight = self._flight
+        if not flight or len(flight) != self._inflight_cap - 1 or not self._lag_s:
+            return None
+        lag_s = self._lag()
+        due = self._last_ready
+        for p in flight:
+            service_s = self._service_estimate(p.key)
+            if service_s is None:
+                return None
+            due = max(due, p.t_submit + lag_s) + service_s
+        self._hold_due = due
+        return due - (max(self._submit_s) + lag_s + HOLD_GUARD_S)
+
     def _take_batch(self) -> list[_Entry] | None:
         first = self._queue.get()
         if first is None:
             return None
         batch = [first]
         coalesced = 0
+        hold_from, hold_len = 0.0, 0  # since when the close is held, the batch's rows then
         while len(batch) < self.max_batch:
             try:
                 e = self._queue.get_nowait()
@@ -508,19 +659,34 @@ class TopNBatcher:
                 # this thread is about to block anyway, so absorb arrivals
                 # in bounded waits instead of dispatching a dribble now
                 # and more power-of-two-padded fragments right after it
-                if not self._device_busy():
-                    break
+                busy = self._device_busy()
+                wait = 0.001
+                if not busy:
+                    # the close: behind a pass that is far from its end the
+                    # batch would only queue on the device, so it stays open
+                    close_at = self._close_at()
+                    now = time.perf_counter()
+                    if close_at is None or close_at <= now:
+                        break
+                    if not hold_from:
+                        hold_from, hold_len = now, len(batch)
+                    self._flight[-1].due = self._hold_due
+                    wait = min(wait, close_at - now)  # the submit is not up to 1 ms late
                 try:
-                    e = self._queue.get(timeout=0.001)
+                    e = self._queue.get(timeout=wait)
                 except queue.Empty:
                     continue
-                coalesced += 1
+                if busy:
+                    coalesced += 1
             if e is None:
                 self._queue.put(None)  # keep the shutdown signal visible
                 break
             batch.append(e)
+        self._hold_s = time.perf_counter() - hold_from if hold_from else 0.0
         if coalesced:
-            _metrics.counter("serving.batcher.coalesced").inc(coalesced)
+            self._m_coalesced.inc(coalesced)
+        if hold_from and len(batch) > hold_len:
+            self._m_hold_rows.inc(len(batch) - hold_len)
         _metrics.gauge("serving.batcher.queue.depth").set(self._queue.qsize())
         return batch
 
@@ -629,6 +795,7 @@ class TopNBatcher:
             padded = -(-n // self.MULTI_THRESHOLD) * self.MULTI_THRESHOLD
         self._pass_seq += 1  # numbers dispatch attempts: a failed one leaves a gap
         seq = self._pass_seq
+        t_slot = time.perf_counter()
         try:
             with profiling.annotate(
                 "serving.pass.submit", **{"pass": seq, "rows": n, "padded_rows": padded}
@@ -648,19 +815,30 @@ class TopNBatcher:
             for e in entries:
                 if e.trace_ctx is not None:
                     e.t_submit = time.time()
-            self._pending.put(
-                _Pass(handle, entries, time.perf_counter(), seq, padded, kk, inflight)
-            )
+            key = (id(entries[0].uploaded), indexed, cosine, nprobe, padded, kk)
+            t_submit = time.perf_counter()
+            timing = _PassTiming(key, t_submit)
+            self._pending.put(_Pass(handle, entries, t_submit, seq, padded, kk, inflight, timing))
         except BaseException as exc:  # deliver the failure to the waiters
             self._release_slot()
+            self._hold_s = 0.0
             for e in entries:
                 e.error = exc
                 e.done.set()
             return
+        self._settle()  # here too: under a queue that is never empty nothing asks `_close_at`
+        self._flight.append(timing)
+        self._submit_s.append(t_submit - t_slot)
         self._m_passes.inc()
         self._m_pass_rows.inc(n)
         self._m_pass_padded_rows.inc(padded)
         self._m_pass_depth_sum.inc(inflight)
+        if self._hold_s:  # the first pass of a batch whose close was held
+            self._m_held.inc()
+            self._m_hold_seconds.observe(self._hold_s)
+            self._hold_s = 0.0
+            if t_submit > self._hold_due - self._lag():
+                self._m_hold_late.inc()  # the pass ahead had left the device: it idled
 
     def _submit_vectors(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
         """Dispatch one coalesced group of uploaded query vectors (caller
@@ -727,9 +905,13 @@ class TopNBatcher:
                     e.idx = idx[row, : e.k]
                     e.vals = vals[row, : e.k]
             except BaseException as exc:
+                item.timing.failed = True
                 for e in entries:
                     e.error = exc
             finally:
+                # before the slot is free: the dispatcher reads the stamp
+                # once it is (the close's estimate, `_settle`)
+                item.timing.t_ready = t_ready or time.perf_counter()
                 self._release_slot()
                 _record_pass_spans(item, time.time())
                 for e in entries:

@@ -123,6 +123,10 @@ def test_large_group_routes_through_fused_multi(monkeypatch):
 
     b._take_batch = gated_take
     try:
+        # the take that was already waiting when the gate went in serves this
+        # one; every take after it waits for the gate
+        b.score(up, queries[0], 4)
+
         def run(j):
             results[j] = b.score(up, queries[j], 4)
 
@@ -155,7 +159,24 @@ def _pass_record() -> dict:
         "pass_seconds": snap[name + "pass.seconds"].get("count", 0),
         "deliveries": snap[name + "deliver.seconds"].get("count", 0),
         "depth_sum": snap[name + "pass.inflight-depth-sum"]["value"],
+        # the close (a batch held open behind the pass ahead)
+        "held": snap[name + "pass.held"]["value"],
+        "hold_rows": snap[name + "hold.rows"]["value"],
+        "hold_late": snap[name + "hold.late"]["value"],
+        "holds": snap[name + "hold.seconds"].get("count", 0),
+        "hold_s": snap[name + "hold.seconds"].get("sum", 0.0),
+        "hold_errors": snap[name + "hold.error.seconds"].get("count", 0),
+        "wait_s": snap[name + "queue-wait.seconds"].get("sum", 0.0),
+        "pass_s": snap[name + "pass.seconds"].get("sum", 0.0),
     }
+
+
+def _delta(before: dict) -> dict:
+    got = {k: v - before[k] for k, v in _pass_record().items()}
+    # what holds of every run: a held pass is a pass, a row that joined a hold is a row
+    assert 0 <= got["hold_late"] <= got["held"] == got["holds"] <= got["passes"]
+    assert 0 <= got["hold_rows"] <= got["rows"] and got["hold_errors"] <= got["held"]
+    return got
 
 
 @pytest.mark.parametrize("indexed", [False, True], ids=["vectors", "indexed"])
@@ -186,7 +207,7 @@ def test_every_pass_is_on_the_record_and_the_counts_agree(indexed):
     assert len(results) == len(queries)
     for j, (idx, _vals) in results.items():
         np.testing.assert_array_equal(idx, topn_ops.top_k_scores(up, queries[j], 5)[0])
-    got = {k: v - before[k] for k, v in _pass_record().items()}
+    got = _delta(before)
     n = len(queries)
     assert got["rows"] == n and got["waits"] == n
     assert 1 <= got["passes"] <= n
@@ -239,7 +260,7 @@ def test_fused_vector_path_counts_the_multiple_of_the_scan_batch():
             assert e.done.wait(30) and e.error is None
     finally:
         b.close()
-    got = {k: v - before[k] for k, v in _pass_record().items()}
+    got = _delta(before)
     assert (got["passes"], got["rows"], got["padded"]) == (1, 20, 24)
 
 
@@ -262,7 +283,7 @@ def test_a_dispatch_that_raises_releases_its_slot_and_counts_no_pass(monkeypatch
         assert len(idx) == 3
     finally:
         b.close()
-    got = {k: v - before[k] for k, v in _pass_record().items()}
+    got = _delta(before)
     # the failed attempt waited in the queue like any other and was no pass
     assert got["waits"] == 2 and got["passes"] == 1 and got["rows"] == 1
     assert got["pass_seconds"] == 1
@@ -308,15 +329,17 @@ class _StubHandle:
         return self._out
 
 
-def _ask(b, numbers, tenant=None, k=3, together=False) -> dict:
+def _ask(b, numbers, tenant=None, k=3, together=False, uploaded=None, parked=None) -> dict:
     """One thread a number: each asks the batcher with a query that
     carries its number. The threads and {number: served idx}, for `_join`.
     `together`: the threads are all started first and ask at once when
-    this returns, so that starting them is in nobody's measured wait."""
+    this returns, so that starting them is in nobody's measured wait;
+    `parked()` is then called once they all stand ready, before they ask.
+    `uploaded`: the matrix handle they ask of (a new one a call if None)."""
     from oryx_tpu.tenancy.context import tenant_scope
 
     got: dict = {}
-    uploaded = object()
+    uploaded = object() if uploaded is None else uploaded
     numbers = list(numbers)
     started = threading.Barrier(len(numbers) + 1) if together else None
 
@@ -330,6 +353,9 @@ def _ask(b, numbers, tenant=None, k=3, together=False) -> dict:
     for t in threads:
         t.start()
     if started is not None:
+        if parked is not None:
+            _wait_until(lambda: started.n_waiting == len(numbers))
+            parked()
         started.wait(30)
     return {"threads": threads, "got": got}
 
@@ -379,19 +405,29 @@ def _stall(b, stub) -> list:
 def test_two_passes_are_in_flight_whatever_the_pass_and_the_refill_take(stub, pass_ms, submit_ms):
     """The depth rests at two and `inflight-cap.changes` does not move:
     measured on the chip, a deeper pipeline buys nothing where the pass is
-    shorter than the refill either (PERF.md, PR 28)."""
+    shorter than the refill either (PERF.md, PR 28). And no close is held
+    where the pass is shorter than the lead (all but the first stub),
+    however many passes of one handle have been timed: such a replica
+    runs the schedule it ran before there was a hold."""
     stub.pass_s, stub.submit_s = pass_ms / 1000.0, submit_ms / 1000.0
     b = TopNBatcher()
     before = _pass_record()
+    handle = object()
     try:
         start = b._m_cap_changes.value
         assert b._inflight_cap == batcher_mod.MIN_INFLIGHT == 2
-        _join(_ask(b, range(1, 13)))
+        for first in (1, 13, 25):
+            _join(_ask(b, range(first, first + 12), uploaded=handle))
         assert b._inflight_cap == 2 and b._m_cap_changes.value == start
+        assert len(b._flight) <= 2  # settled at every submit, whether or not a close was weighed
     finally:
         b.close()
-    got = {k: v - before[k] for k, v in _pass_record().items()}
-    assert got["rows"] == 12 and got["passes"] <= got["depth_sum"] <= 2 * got["passes"]
+    got = _delta(before)
+    assert got["rows"] == 36 and got["passes"] <= got["depth_sum"] <= 2 * got["passes"]
+    # the lead is the submit + the lag (the stub's: none) + the guard; a pass
+    # about as long as that (the first two stubs) may be held for a moment
+    if pass_ms < submit_ms + 1000.0 * batcher_mod.HOLD_GUARD_S - 0.5:
+        assert got["held"] == got["hold_rows"] == 0
 
 
 def test_the_third_group_waits_for_a_slot_and_takes_the_arrivals(stub):
@@ -429,7 +465,7 @@ def test_an_explicit_max_inflight_pins_the_depth(stub, depth):
         assert b._inflight_cap == depth and b._m_cap_changes.value == start
     finally:
         b.close()
-    got = {k: v - before[k] for k, v in _pass_record().items()}
+    got = _delta(before)
     assert got["passes"] == depth + 1 and got["depth_sum"] <= depth * got["passes"]
 
 
@@ -512,3 +548,211 @@ def test_two_tenants_of_unequal_weight_get_their_shares_at_depth_two(stub):
     # the dispatcher took the first 15 arrivals as they came, beside the
     # entry it held; what queued up behind left in the round-robin's order
     assert len(served) == 128 and (served[15 : 15 + 64] <= 64).sum() == 48
+
+
+# -- the close ---------------------------------------------------------------------
+#
+# A batch behind a pass that is far from its end stays open until that pass
+# is due to leave the device within the lead. The stub's pass is 30 ms here
+# (its results are on the host when it ends: no lag), so that a loaded
+# machine's milliseconds do not decide a case; a case sleeps 0.2 s or so.
+
+_PASS_S = 0.030
+
+
+def _timed(b, stub, handle, behind=3, lag=True) -> None:
+    """Let the batcher time the passes of `handle` (bucket of 8 rows, k
+    bucket 16: the key of every pass below): one on the idle device and
+    `behind` more, each submitted behind the one before it, which read the
+    service time; then one more on the idle device, which reads the lag
+    (the stub's results are on the host when its pass ends: about none)."""
+    asked = []
+    for n in range(behind + 1):
+        seen = len(stub.groups)
+        asked.append(_ask(b, [900 + n], uploaded=handle))
+        _wait_until(lambda: len(stub.groups) == seen + 1)
+    for a in asked:
+        _join(a)
+    if lag:
+        _join(_ask(b, [999], uploaded=handle))
+
+
+def _three_requests(b, stub, handle, before_second=None) -> tuple[dict, list[float]]:
+    """Requests at 0, +2 and +10 ms of a 30 ms pass: 1 starts a pass on the
+    idle device, 2 finds that pass ahead of it, 3 comes while 2's batch
+    either is held open or already sits in the device's queue. What they
+    were answered and when, from the first's asking."""
+    done: dict[int, float] = {}
+    t0 = time.perf_counter()
+    n_groups = len(stub.groups)
+    asked = [_ask(b, [1], uploaded=handle)]
+    _wait_until(lambda: len(stub.groups) == n_groups + 1)
+    if before_second is not None:
+        before_second()
+    for n, at in ((2, 0.002), (3, 0.010)):
+        time.sleep(max(0.0, t0 + at - time.perf_counter()))
+        asked.append(_ask(b, [n], uploaded=handle))
+    for n, a in enumerate(asked, start=1):
+        _join(a)
+        done[n] = time.perf_counter() - t0
+    return done, [g[g > 0].tolist() for g in stub.groups[n_groups:]]
+
+
+@pytest.mark.parametrize(
+    "timed, max_inflight, held, groups",
+    [
+        # an estimate of the pass ahead: the second batch stays open, and the third joins it
+        ("this-handle", None, 1, [[1.0], [2.0, 3.0]]),
+        # no estimate yet, and a rotation (the new handle has none): close at
+        # once, as before there was a hold: the second is on the device's queue when the third comes
+        ("nothing", None, 0, [[1.0], [2.0], [3.0]]),
+        ("another-handle", None, 0, [[1.0], [2.0], [3.0]]),
+        # the service time known and no pass yet that started on an idle device and said what the lag
+        # is: the second is closed at once; the first then says it, and the third is held alone behind the second
+        ("this-handle-no-lag", None, 1, [[1.0], [2.0], [3.0]]),
+        # one slot: nothing is ever ahead of a free slot (the second waits for it and the third joins it there)
+        ("this-handle", 1, 0, [[1.0], [2.0, 3.0]]),
+        # three slots: the second fills the pipeline at once; the third finds both other slots' passes ahead and is held alone
+        ("this-handle", 3, 1, [[1.0], [2.0], [3.0]]),
+    ],
+    ids=["held", "no-estimate-yet", "after-a-rotation", "no-lag-yet", "max-inflight-1", "max-inflight-3"],
+)
+def test_a_request_that_arrives_while_the_pass_ahead_has_far_to_go_joins_the_open_batch(
+    stub, timed, max_inflight, held, groups
+):
+    stub.pass_s = _PASS_S
+    b = TopNBatcher(max_inflight=max_inflight)
+    handle = object()
+    try:
+        if timed != "nothing":
+            _timed(b, stub, object() if timed == "another-handle" else handle, lag=timed != "this-handle-no-lag")
+        before = _pass_record()
+        done, served = _three_requests(b, stub, handle)
+    finally:
+        b.close()
+    b._settle()  # its dispatcher has gone: the last pass ahead's error goes on the record
+    got = _delta(before)
+    assert got["rows"] == got["waits"] == 3 and served == groups
+    # (`hold.late` is not held to 0: a loaded machine's timer oversleeps the guard now and then)
+    assert (got["held"], got["hold_errors"]) == (held, held)
+    assert got["hold_rows"] == (1 if len(groups) == 2 and held else 0)
+    if len(groups) == 2 and held:
+        assert done[3] < 2.6 * _PASS_S  # two passes, not three: the third's answer a pass sooner
+        # the hold is queueing: in the queue wait, and in no pass's time in flight
+        assert got["wait_s"] >= got["hold_s"] >= 0.5 * _PASS_S
+        assert got["pass_s"] < 2.0 * _PASS_S + 0.5 * got["hold_s"]
+    elif max_inflight is None:
+        assert done[3] > 2.6 * _PASS_S
+
+
+def test_a_submit_that_outlasts_the_lead_is_counted_late(stub):
+    """The lead holds the dispatcher's own submit time as it was; a submit
+    that takes 10 ms where the last took none ends after the pass ahead
+    has left the device, which idled for it: `hold.late`."""
+    stub.pass_s = _PASS_S
+    b = TopNBatcher()
+    handle = object()
+    try:
+        _timed(b, stub, handle)
+        before = _pass_record()
+        _three_requests(b, stub, handle, before_second=lambda: setattr(stub, "submit_s", 0.010))
+    finally:
+        b.close()
+    got = _delta(before)
+    assert (got["passes"], got["held"], got["hold_late"]) == (2, 1, 1)
+
+
+def test_close_during_a_hold_submits_the_open_batch_and_joins(stub):
+    stub.pass_s = _PASS_S
+    b = TopNBatcher()
+    handle = object()
+    try:
+        _timed(b, stub, handle)
+        before = _pass_record()
+        n_groups = len(stub.groups)
+        first = _ask(b, [1], uploaded=handle)
+        _wait_until(lambda: len(stub.groups) == n_groups + 1)
+        second = _ask(b, [2], uploaded=handle)
+        _wait_until(lambda: b._queue.qsize() == 0 and b._flight and b._flight[-1].due)  # held
+        t0 = time.perf_counter()
+        b.close()
+        closed_in = time.perf_counter() - t0
+        _join(first)
+        _join(second)
+    finally:
+        b.close()
+    assert not b._dispatcher.is_alive() and not b._completer.is_alive()
+    got = _delta(before)
+    assert (got["passes"], got["held"]) == (2, 1)
+    # the hold ended with the close, far before its time: the second pass
+    # was submitted behind the first and close() waited for both to drain
+    assert got["hold_s"] < 0.5 * _PASS_S and closed_in < 2.5 * _PASS_S
+
+
+def test_one_slow_pass_does_not_stretch_the_estimate_of_the_next(stub):
+    """Four passes of 20 ms behind one another and one whose results were
+    stalled for 80 ms (a pause of the machine): the estimate stays the pass's."""
+    stub.pass_s = 0.020
+    b = TopNBatcher()
+    handle = object()
+    try:
+        _timed(b, stub, handle, behind=4)
+        n_groups = len(stub.groups)
+        ahead = _ask(b, [1], uploaded=handle)
+        _wait_until(lambda: len(stub.groups) == n_groups + 1)
+        gate = stub.gate = threading.Event()
+        stalled = _ask(b, [2], uploaded=handle)  # behind `ahead`, held or not
+        _wait_until(lambda: len(stub.groups) == n_groups + 2)
+        stub.gate = None
+        time.sleep(0.08)
+        gate.set()
+        _join(ahead)
+        _join(stalled)
+        b._settle()  # the dispatcher is idle: nothing else touches its state
+        (key,) = b._service_s
+        samples = sorted(b._service_s[key])
+        assert len(samples) == 5 and samples[-1] > 0.06
+        assert 0.015 < b._service_estimate(key) < 0.030
+        assert b._lag_s and b._lag() < 0.005  # the stub's results are on the host when its pass ends
+    finally:
+        b.close()
+
+
+def test_two_tenants_of_unequal_weight_keep_their_shares_through_a_held_batch(stub):
+    """A batch held open takes what comes in the queue's own order, fills
+    (`max_batch`) and is closed at once; behind it the deficit round-robin
+    serves tenant a (weight 3) and tenant b (weight 1) three to one."""
+    stub.pass_s = 4 * _PASS_S  # a hold long enough for 128 threads to ask inside it on a loaded machine
+    b = TopNBatcher(max_batch=16, tenant_weights={"a": 3.0, "b": 1.0}, fair_quantum=4.0)
+    handle = object()
+    try:
+        _timed(b, stub, handle)
+        before = _pass_record()
+        n_groups = len(stub.groups)
+        asked = []
+
+        def a_pass_ahead_and_a_batch_held_behind_it():
+            asked.append(_ask(b, [2000], uploaded=handle))
+            _wait_until(lambda: len(stub.groups) == n_groups + 1)
+            asked.append(_ask(b, [2001], uploaded=handle))
+            _wait_until(lambda: b._queue.qsize() == 0 and b._flight and b._flight[-1].due)
+
+        asked.append(_ask(
+            b, range(1, 129), tenant=lambda n: "a" if n <= 64 else "b", together=True, uploaded=handle,
+            parked=a_pass_ahead_and_a_batch_held_behind_it,
+        ))
+        for a in asked:
+            _join(a)
+    finally:
+        b.close()
+    got = _delta(before)
+    assert got["rows"] == 130 and got["held"] >= 1
+    groups = stub.groups[n_groups + 1 :]
+    assert len(groups[0]) == 16 and groups[0][0] == 2001  # the held batch: full, closed before its time
+    assert got["hold_rows"] >= 15
+    served = np.concatenate(groups)
+    served = served[(served >= 1) & (served <= 128)]
+    # the held batch and the one that waited for a slot behind it took the
+    # arrivals as they came; from then on all are queued and any 32 served
+    # in a row are two turns of the round-robin: 24 of a, 8 of b
+    assert len(served) == 128 and (served[31:63] <= 64).sum() == 24

@@ -5,7 +5,10 @@
 device's view beside the generator's: one set-up, one window a rate, and
 for each window p50 / p95, the shed share, how late the generator ran, the
 ladder's pressure at the window's end, rows and passes
-(`serving.batcher.pass.*`), queue wait and pass in flight, and, with
+(`serving.batcher.pass.*`), queue wait and pass in flight, the later
+close (share of passes held, share of those submitted late, mean hold,
+rows that joined a hold, mean error of the prediction, the result lag it
+reckons with at the window's end), and, with
 `--trace 1`, the device's idle share and the scan kernel's ms a pass from
 a profiler recording of the 4 s after the window at the same load; with
 `--raw`, every request's due time and latency of every window. A tool
@@ -48,6 +51,7 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         return 1000.0 * d(hist, "sum") / max(d(hist, "count"), 1.0)
 
     passes = max(d("serving.batcher.passes"), 1.0)
+    held = d("serving.batcher.pass.held")  # 0 on a program without the later close
     row = {
         "rate_per_s": rate,
         "seconds": seconds,
@@ -65,6 +69,12 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         "passes_per_s": passes / seconds,
         "rows_per_pass": d("serving.batcher.pass.rows") / passes,
         "inflight_depth_mean": d("serving.batcher.pass.inflight-depth-sum") / passes,
+        "held_pass_pct": 100.0 * held / passes,
+        "hold_late_pct": 100.0 * d("serving.batcher.hold.late") / max(held, 1.0),
+        "hold_mean_ms": mean_ms("serving.batcher.hold.seconds"),
+        "hold_rows": d("serving.batcher.hold.rows"),
+        "hold_error_mean_ms": mean_ms("serving.batcher.hold.error.seconds"),
+        "hold_lag_ms": (after.get("serving.batcher.hold.lag-ms") or {}).get("value"),
         "indexed_pct": 100.0 * d("serving.scan.indexed.queries")
         / max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0),
         "unstaged_requests": d("serving.users.unstaged-requests"),
