@@ -84,9 +84,10 @@ The unit of work is the PASS: one device dispatch serving one coalesced
 group. Every pass is on the record three ways at the same boundaries
 (docs/observability.md): always-on counters and histograms
 (``serving.batcher.passes``, ``pass.rows`` / ``pass.padded-rows``,
-``pass.inflight-depth-sum``, ``queue-wait.seconds``, ``pass.seconds``,
-``deliver.seconds``), a ``serving.pass`` span in the tracer's ring when a
-request of the pass is sampled, and ``serving.pass.submit`` /
+``pass.inflight-depth-sum``, ``queue-wait.seconds``, ``submit.seconds``,
+``pass.seconds``, ``deliver.seconds``), a ``serving.pass`` span in the
+tracer's ring when a request of the pass is sampled, and
+``serving.pass.submit`` /
 ``serving.pass.wait`` annotations on the profiler's timeline while a
 device trace records. None of it feeds a scheduling decision: the close
 reads the dispatcher's own stamps, never the registry.
@@ -467,6 +468,10 @@ class TopNBatcher:
         self._pass_seq = 0  # dispatcher thread only
         self._m_queue_wait = _metrics.histogram("serving.batcher.queue-wait.seconds")
         self._m_sharded_queries = _metrics.counter("serving.scan.sharded.queries")
+        self._m_cosine_queries = _metrics.counter("serving.scan.cosine.queries")
+        self._m_vector_queries = _metrics.counter("serving.scan.vector.queries")
+        self._m_indexed_queries = _metrics.counter("serving.scan.indexed.queries")
+        self._m_upload_bytes = _metrics.counter("serving.scan.vector.upload-bytes")
         self._m_passes = _metrics.counter("serving.batcher.passes")
         self._m_pass_rows = _metrics.counter("serving.batcher.pass.rows")
         self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
@@ -475,6 +480,7 @@ class TopNBatcher:
         # fixed, so it stays 0; registered so that a reader of it reads a
         # number and not nothing, as it would where the counter is missing
         self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
+        self._m_submit_seconds = _metrics.histogram("serving.batcher.submit.seconds")
         self._m_pass_seconds = _metrics.histogram("serving.batcher.pass.seconds")
         self._m_deliver_seconds = _metrics.histogram("serving.batcher.deliver.seconds")
         self._m_coalesced = _metrics.counter("serving.batcher.coalesced")
@@ -798,7 +804,11 @@ class TopNBatcher:
         t_slot = time.perf_counter()
         try:
             with profiling.annotate(
-                "serving.pass.submit", **{"pass": seq, "rows": n, "padded_rows": padded}
+                "serving.pass.submit",
+                **{
+                    "pass": seq, "rows": n, "padded_rows": padded,
+                    "kind": "indexed" if indexed else "vector", "cosine": int(cosine),
+                },
             ):
                 kk = _k_bucket(max(e.k for e in entries))
                 nprobe = self._group_nprobe(entries)
@@ -808,6 +818,8 @@ class TopNBatcher:
                     # once in a great while, not once in six passes): these
                     # rows are scanned on every shard and merged across chips
                     self._m_sharded_queries.inc(n)
+                if cosine:  # beside the count by submit kind too
+                    self._m_cosine_queries.inc(n)
                 if indexed:
                     handle = self._submit_indexed(entries, cosine, kk, nprobe, padded)
                 else:
@@ -829,6 +841,7 @@ class TopNBatcher:
         self._settle()  # here too: under a queue that is never empty nothing asks `_close_at`
         self._flight.append(timing)
         self._submit_s.append(t_submit - t_slot)
+        self._m_submit_seconds.observe(t_submit - t_slot)
         self._m_passes.inc()
         self._m_pass_rows.inc(n)
         self._m_pass_padded_rows.inc(padded)
@@ -843,36 +856,33 @@ class TopNBatcher:
     def _submit_vectors(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
         """Dispatch one coalesced group of uploaded query vectors (caller
         holds the inflight slot and delivers errors); returns the handle."""
-        queries = np.stack([e.query for e in entries])
-        _metrics.counter("serving.scan.vector.queries").inc(len(entries))
+        n = len(entries)
+        uploaded = entries[0].uploaded
+        self._m_vector_queries.inc(n)
+        if n > self.MULTI_THRESHOLD and isinstance(uploaded, topn_ops.IVFIndex):
+            padded = n  # an IVF index groups its queries itself: no whole scan groups
+        # the block the device is given, made once: the bucket's rows past
+        # the entries stay zero queries, whose results are discarded
+        queries = np.zeros((padded, entries[0].query.shape[-1]), np.float32)
+        np.stack([e.query for e in entries], out=queries[:n])
+        self._m_upload_bytes.inc(queries.nbytes)
         # tiered item store: hint the cells this group will probe so
         # the store's disk->RAM promotions overlap the dispatch below
         # instead of stalling the stage-1 gather (advisory; no-op on
         # flat-plane indexes)
-        prefetch = getattr(entries[0].uploaded, "prefetch_for_queries", None)
+        prefetch = getattr(uploaded, "prefetch_for_queries", None)
         if prefetch is not None:
             try:
-                prefetch(queries, nprobe=nprobe, cosine=cosine)
+                prefetch(queries[:n], nprobe=nprobe, cosine=cosine)
             except Exception:  # never let a hint fail a dispatch
                 pass
-        pad_rows = padded - len(entries)
-        if len(entries) > self.MULTI_THRESHOLD and isinstance(
-            entries[0].uploaded, topn_ops.IVFIndex
-        ):
-            pad_rows = 0  # an IVF index groups its queries itself: no whole scan groups
-        if pad_rows:  # bucketed shapes: zero queries, results discarded
-            queries = np.concatenate(
-                [queries, np.zeros((pad_rows, queries.shape[1]), queries.dtype)]
-            )
-        return topn_ops.submit_top_k(
-            entries[0].uploaded, queries, kk, cosine=cosine, nprobe=nprobe
-        )
+        return topn_ops.submit_top_k(uploaded, queries, kk, cosine=cosine, nprobe=nprobe)
 
     def _submit_indexed(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
         """Dispatch one coalesced index-entry group (caller holds the
         inflight slot and delivers errors); returns the handle."""
         rows = np.asarray([e.row for e in entries], dtype=np.int32)
-        _metrics.counter("serving.scan.indexed.queries").inc(len(entries))
+        self._m_indexed_queries.inc(len(entries))
         pad = padded - len(rows)
         if pad:  # bucketed shapes: row 0 repeats, results discarded
             rows = np.concatenate([rows, np.zeros(pad, np.int32)])

@@ -3,7 +3,10 @@ that no benchmark cell compiles: tests/benchmark/test_bench_compile_v5e.py
 holds the float32 programs of the cells (batch buckets 8-128, k bucket 32);
 here are the other item dtypes (bfloat16; int8 two-plane, whose kernel keeps
 128 candidates for the rescore), the k buckets 16, 128 and 256, and the
-widest scan group (256 rows), at 50 and at 250 features. What the chip's
+widest scan group (256 rows), at 50 and at 250 features; and the VECTOR
+submit's program as it is served (`_streaming_topk_multi`: the query block
+an operand, not rows of a staged matrix; `/similarity`, anonymous users,
+several users at once), cosine and dot. What the chip's
 compiler would refuse (VMEM, tiling, the lane roll of the running top-k)
 is refused here. A compile that passes is not a
 chip run and says nothing about time.
@@ -47,6 +50,15 @@ SPLIT_CASES = {
     "split-250f-b128": (250, 128, False),
     "split-50f-cosine": (50, 16, True),
     "split-250f-b256-cosine": (250, 256, True),
+}
+
+# The VECTOR submit's program as it is served (`_streaming_topk_multi`: a
+# [1, b, features] float32 block uploaded with the pass, split operands):
+# what `/similarity` (cosine, k bucket 16 at howMany=10) and every request
+# whose query is not a staged row run. name: (features, batch rows, cosine)
+VECTOR_CASES = {
+    f"vector-{f}f-b{b}-{'cosine' if cosine else 'dot'}": (f, b, cosine)
+    for f in (250, 50) for b in (8, 128) for cosine in (True, False)
 }
 
 
@@ -152,6 +164,41 @@ def test_served_split_scan_program_compiles_for_the_v5e(case, one_chip, no_persi
     ).compile().memory_analysis().argument_size_in_bytes
     padding = (pallas_topn._ceil_to(features, 8) - features) * n_pad * 4
     assert stored == whole - padding
+
+
+@pytest.mark.parametrize("case", VECTOR_CASES)
+def test_served_vector_scan_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
+    """The same kernel behind the other submit kind: the queries arrive as
+    an operand of the program, and the cosine variant divides by the norms
+    row that the dot variant streams and ignores."""
+    import jax.numpy as jnp
+
+    from oryx_tpu.ops import pallas_topn
+
+    features, batch, cosine = VECTOR_CASES[case]
+    items, k = SHAPES[features], 16
+    n_pad = pallas_topn._ceil_to(items, pallas_topn.BLOCK_N)
+    tail = pallas_topn.tail_rows(features, jnp.float32)
+
+    shape = functools.partial(_shape, one_chip)
+    lowered = pallas_topn._streaming_topk_multi.lower(
+        shape((features - tail, n_pad), jnp.float32), shape((1, n_pad), jnp.float32),
+        None, None, None, shape((1, batch, features), jnp.float32),
+        k=k, n_items=items, cosine=cosine, interpret=False, download_dtype=None,
+        tail=shape((tail, n_pad), jnp.float32),
+    )
+    compiled = lowered.compile()  # raises what the chip's compiler would raise
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%oryx_topn_scan" in text
+    (vals, idxs) = lowered.out_info
+    assert vals.shape == idxs.shape == (1, batch, k)
+    mem = compiled.memory_analysis()
+    # the matrix at its logical width, its norms, and the query block (which
+    # the device tiles to whole 128-lane columns); no [b, n] scores anywhere
+    planes = (features * n_pad + n_pad) * 4
+    block = batch * pallas_topn._ceil_to(features, 128) * 4
+    assert planes < mem.argument_size_in_bytes <= planes + block
+    assert mem.temp_size_in_bytes < 64 * 2**20
 
 
 def test_served_split_sharded_program_compiles_for_a_v5e_host(no_persistent_cache):

@@ -1,6 +1,7 @@
 """Micro-batcher: concurrent scoring calls coalesce into batched device
 submits without changing any per-request answer."""
 
+import contextlib
 import threading
 import time
 
@@ -287,6 +288,144 @@ def test_a_dispatch_that_raises_releases_its_slot_and_counts_no_pass(monkeypatch
     # the failed attempt waited in the queue like any other and was no pass
     assert got["waits"] == 2 and got["passes"] == 1 and got["rows"] == 1
     assert got["pass_seconds"] == 1
+
+
+# -- submit kind and scoring on the record ------------------------------------------
+
+
+def _scan_record() -> dict:
+    """The counts by submit kind and by scoring, the bytes of query block
+    handed to the device, and the submit histogram (deltas, as above)."""
+    snap = batcher_mod._metrics.snapshot()
+    submit = snap["serving.batcher.submit.seconds"]
+    return {
+        "cosine": snap["serving.scan.cosine.queries"]["value"],
+        "vector": snap["serving.scan.vector.queries"]["value"],
+        "indexed": snap["serving.scan.indexed.queries"]["value"],
+        "upload": snap["serving.scan.vector.upload-bytes"]["value"],
+        "submits": submit.get("count", 0),
+        "submit_s": submit.get("sum", 0.0),
+        "passes": snap["serving.batcher.passes"]["value"],
+        "padded": snap["serving.batcher.pass.padded-rows"]["value"],
+    }
+
+
+def test_a_cosine_group_and_a_dot_group_in_one_queue_leave_as_two_passes():
+    """Both slots are held until every request is in the dispatcher's one
+    batch: it leaves as a cosine pass and a dot pass, each answer that of
+    its own scoring, and the record moves by what was submitted."""
+    y, up = _make(n=600, kf=8, seed=21)
+    queries = np.random.default_rng(22).standard_normal((8, 8)).astype(np.float32)
+    cosine = [True, False, True, True, False, True, False, True]  # 5 cosine, 3 dot
+    b = TopNBatcher()
+    b._acquire_slot(), b._acquire_slot()
+    queued = threading.Semaphore(0)
+    put = b._queue.put
+    b._queue.put = lambda e: (put(e), queued.release())
+    before, results = _scan_record(), {}
+
+    def worker(j):
+        results[j] = b.score(up, queries[j], 6, cosine=cosine[j])
+
+    threads = [threading.Thread(target=worker, args=(j,)) for j in range(8)]
+    try:
+        for t in threads:
+            t.start()
+        for _ in threads:
+            assert queued.acquire(timeout=30)
+        time.sleep(0.05)  # the dispatcher's 1 ms waits take the last arrival in
+        b._release_slot(), b._release_slot()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        b.close()
+    assert len(results) == 8
+    for j, (idx, vals) in results.items():
+        ridx, rvals = topn_ops.top_k_scores(up, queries[j], 6, cosine=cosine[j])
+        np.testing.assert_array_equal(idx, ridx)
+        np.testing.assert_allclose(vals, rvals, atol=1e-5)
+        if cosine[j]:
+            assert np.all(np.abs(vals) <= 1.0 + 1e-6)
+    got = {k: v - before[k] for k, v in _scan_record().items()}
+    assert got["passes"] == got["submits"] == 2 and got["submit_s"] > 0.0
+    assert (got["vector"], got["cosine"], got["indexed"]) == (8, 5, 0)
+    assert got["upload"] == 2 * 8 * 8 * 4  # two blocks of the 8-row bucket, 8 float32 a row
+
+
+@pytest.mark.parametrize("indexed, cosine, moved", [
+    (True, False, {"indexed": 3, "vector": 0, "cosine": 0, "upload": 0}),
+    (True, True, {"indexed": 3, "vector": 0, "cosine": 3, "upload": 0}),
+    (False, False, {"indexed": 0, "vector": 3, "cosine": 0, "upload": 8 * 8 * 4}),
+    (False, True, {"indexed": 0, "vector": 3, "cosine": 3, "upload": 8 * 8 * 4}),
+], ids=["indexed-dot", "indexed-cosine", "vector-dot", "vector-cosine"])
+def test_one_pass_moves_the_record_by_its_kind_and_its_scoring(monkeypatch, indexed, cosine, moved):
+    y, up = _make(n=300, kf=8, seed=23)
+    queries = np.random.default_rng(24).standard_normal((3, 8)).astype(np.float32)
+    x_dev = topn_ops.upload_queries(queries)
+    marked = []
+    monkeypatch.setattr(
+        batcher_mod.profiling, "annotate",
+        lambda name, **attrs: marked.append((name, attrs)) or contextlib.nullcontext(),
+    )
+    if indexed:
+        entries = [batcher_mod._Entry(up, None, 4, cosine, x_dev=x_dev, row=j) for j in range(3)]
+    else:
+        entries = [batcher_mod._Entry(up, q, 4, cosine) for q in queries]
+    b = TopNBatcher()
+    before = _scan_record()
+    try:
+        b._submit_group(entries, cosine)
+        for j, e in enumerate(entries):
+            assert e.done.wait(30) and e.error is None
+            np.testing.assert_array_equal(
+                e.idx, topn_ops.top_k_scores(up, queries[j], 4, cosine=cosine)[0]
+            )
+    finally:
+        b.close()
+    got = {k: v - before[k] for k, v in _scan_record().items()}
+    assert got["passes"] == got["submits"] == 1
+    assert {k: got[k] for k in moved} == moved
+    (attrs,) = [a for name, a in marked if name == "serving.pass.submit"]
+    assert attrs["kind"] == ("indexed" if indexed else "vector") and attrs["cosine"] == int(cosine)
+    assert (attrs["rows"], attrs["padded_rows"]) == (3, 8)
+
+
+@pytest.mark.parametrize("n, padded", [(1, 8), (11, 16), (300, 512)],
+                         ids=["one-row", "second-bucket", "two-scan-groups"])
+def test_a_vector_pass_uploads_its_padded_rows_at_four_bytes_a_feature(n, padded):
+    """What a reader may derive the uplink from: the rows after padding
+    (a power-of-two bucket; whole scan groups past `MULTI_THRESHOLD`),
+    times the features, times 4. The block is made once, and every row of
+    it that is a request gets its own answer."""
+    y, up = _make(n=300, kf=8, seed=25)
+    queries = np.random.default_rng(26).standard_normal((n, 8)).astype(np.float32)
+    entries = [batcher_mod._Entry(up, q, 4, False) for q in queries]
+    b = TopNBatcher()
+    before = _scan_record()
+    try:
+        b._submit_group(entries, False)
+        assert all(e.done.wait(30) and e.error is None for e in entries)
+    finally:
+        b.close()
+    for j in sorted({0, n // 2, n - 1}):
+        np.testing.assert_array_equal(entries[j].idx, topn_ops.top_k_scores(up, queries[j], 4)[0])
+    got = {k: v - before[k] for k, v in _scan_record().items()}
+    assert (got["passes"], got["vector"], got["padded"]) == (1, n, padded)
+    assert got["upload"] == padded * 8 * 4
+
+
+def test_a_batcher_that_served_nothing_reads_zero_not_nothing():
+    """Handles taken in __init__: a reader's delta on a cell that never
+    feeds a counter is 0, where a missing counter would be nothing."""
+    b = TopNBatcher()
+    try:
+        snap = batcher_mod._metrics.snapshot()
+    finally:
+        b.close()
+    for name in ("serving.scan.cosine.queries", "serving.scan.vector.queries",
+                 "serving.scan.indexed.queries", "serving.scan.vector.upload-bytes"):
+        assert snap[name]["type"] == "counter" and snap[name]["value"] >= 0.0
+    assert snap["serving.batcher.submit.seconds"]["type"] == "histogram"
 
 
 # -- the in-flight depth ---------------------------------------------------------

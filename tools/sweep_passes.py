@@ -51,6 +51,7 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         return 1000.0 * d(hist, "sum") / max(d(hist, "count"), 1.0)
 
     passes = max(d("serving.batcher.passes"), 1.0)
+    queries = max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0)
     held = d("serving.batcher.pass.held")  # 0 on a program without the later close
     row = {
         "rate_per_s": rate,
@@ -75,8 +76,10 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         "hold_rows": d("serving.batcher.hold.rows"),
         "hold_error_mean_ms": mean_ms("serving.batcher.hold.error.seconds"),
         "hold_lag_ms": (after.get("serving.batcher.hold.lag-ms") or {}).get("value"),
-        "indexed_pct": 100.0 * d("serving.scan.indexed.queries")
-        / max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0),
+        "indexed_pct": 100.0 * d("serving.scan.indexed.queries") / queries,
+        "cosine_pct": 100.0 * d("serving.scan.cosine.queries") / queries,
+        "submit_mean_ms": mean_ms("serving.batcher.submit.seconds"),
+        "vector_upload_kb_per_pass": d("serving.scan.vector.upload-bytes") / passes / 1024.0,
         "unstaged_requests": d("serving.users.unstaged-requests"),
         "compiles": d("jax.compile.seconds", "count"),
         "server_pause_max_ms": session.pause["max_ms"],
