@@ -479,8 +479,10 @@ def columns_from_payload(payload, count: int, flags: int):
 
 # per-record fixed header inside a KIND_HTTP payload:
 #   u32 conn_id, u32 req_id, u8 method, u8 flags, u16 n_headers,
-#   u32 target_len, u32 body_len, u32 rec_len (8-aligned total)
-_HTTP_REC = struct.Struct("<IIBBHIII")
+#   u32 target_len, u32 body_len, u32 rec_len (8-aligned total),
+#   u64 t_parsed_ns (the front's CLOCK_MONOTONIC when the request's last
+#   byte was parsed)
+_HTTP_REC = struct.Struct("<IIBBHIIIQ")
 _HTTP_METHODS = ("GET", "POST", "DELETE", "HEAD", "OTHER")
 HTTP_FLAG_HTTP10 = 1
 HTTP_FLAG_CLOSE = 2
@@ -492,10 +494,10 @@ class HttpRecord:
     case-insensitive lookup wrap it (serving.native_front._Headers)."""
 
     __slots__ = ("conn_id", "req_id", "method", "flags", "target",
-                 "headers", "body")
+                 "headers", "body", "t_parsed")
 
     def __init__(self, conn_id, req_id, method, flags, target, headers,
-                 body) -> None:
+                 body, t_parsed: float) -> None:
         self.conn_id = conn_id
         self.req_id = req_id
         self.method = method
@@ -503,6 +505,7 @@ class HttpRecord:
         self.target = target
         self.headers = headers
         self.body = body
+        self.t_parsed = t_parsed  # seconds on CLOCK_MONOTONIC
 
 
 def decode_http_records(payload, count: int) -> list[HttpRecord]:
@@ -514,7 +517,7 @@ def decode_http_records(payload, count: int) -> list[HttpRecord]:
         if pos + _HTTP_REC.size > len(buf):
             raise FrameError("truncated http record header")
         (conn_id, req_id, method, flags, n_headers, target_len, body_len,
-         rec_len) = _HTTP_REC.unpack_from(buf, pos)
+         rec_len, t_parsed_ns) = _HTTP_REC.unpack_from(buf, pos)
         if pos + rec_len > len(buf) or rec_len < _HTTP_REC.size:
             raise FrameError(f"http record length {rec_len} overruns payload")
         off = pos + _HTTP_REC.size
@@ -539,6 +542,7 @@ def decode_http_records(payload, count: int) -> list[HttpRecord]:
                 target,
                 headers,
                 body,
+                t_parsed_ns / 1e9,
             )
         )
         pos += rec_len

@@ -172,6 +172,10 @@ struct ParsedRequest {
   std::string target;                                  // raw, incl. query
   std::vector<std::pair<std::string, std::string>> headers;
   std::string body;
+  // CLOCK_MONOTONIC when the request's last byte was parsed: carried in
+  // the record so that Python can time the way from here to its handler
+  // (serving.front.ingress.seconds)
+  uint64_t t_parsed_ns = 0;
 };
 
 struct Conn {
@@ -200,6 +204,12 @@ double now_mono() {
   struct timespec ts;
   clock_gettime(CLOCK_MONOTONIC, &ts);
   return ts.tv_sec + ts.tv_nsec * 1e-9;
+}
+
+uint64_t now_mono_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return uint64_t(ts.tv_sec) * 1000000000ull + uint64_t(ts.tv_nsec);
 }
 
 uint64_t now_wall_ms() {
@@ -626,6 +636,7 @@ struct Front {
   // classify a fully parsed request: answer natively or queue to Python.
   // Returns false when the connection was closed.
   bool finish_request(Conn* c) {
+    c->cur.t_parsed_ns = now_mono_ns();
     c->cur.req_id = c->next_req_id++;
     c->outstanding++;
     c->last_activity = now_mono();
@@ -1027,7 +1038,7 @@ struct Front {
     uint32_t count = 0;
     while (!pending.empty()) {
       const ParsedRequest& r = pending.front();
-      size_t rec = 24 + r.target.size() + r.body.size();
+      size_t rec = 32 + r.target.size() + r.body.size();
       for (const auto& kv : r.headers) rec += 4 + kv.first.size() + kv.second.size();
       rec = pad8(rec);
       if (kFrameHeader + pad8(payload.size() + rec) > cap) break;
@@ -1040,6 +1051,7 @@ struct Front {
       put_u32(payload, (uint32_t)r.target.size());
       put_u32(payload, (uint32_t)r.body.size());
       put_u32(payload, (uint32_t)rec);
+      put_u64(payload, r.t_parsed_ns);
       payload += r.target;
       for (const auto& kv : r.headers) {
         put_u16(payload, (uint16_t)kv.first.size());

@@ -107,6 +107,7 @@ import numpy as np
 from oryx_tpu.common import profiling, tracing
 from oryx_tpu.common.metrics import registry as _metrics
 from oryx_tpu.ops import topn as topn_ops
+from oryx_tpu.serving import stages
 from oryx_tpu.serving.overload import active_probe_fraction
 from oryx_tpu.tenancy.context import current_tenant
 
@@ -181,11 +182,15 @@ class _Entry:
     t_enqueue: float = 0.0
     t_dispatch: float = 0.0
     t_submit: float = 0.0
-    # overload control: monotonic enqueue stamp feeding the queue-wait
-    # EWMA (always set, unlike the tracing stamps), plus the per-request
-    # reduced-probe override snapshotted from the admission contextvar on
-    # the request thread — it rides the entry across to the dispatcher.
+    # overload control: the enqueue stamp (perf_counter) feeding the
+    # queue-wait EWMA (always set, unlike the tracing stamps) and the
+    # stages of the host path, which also read `t_ready`, the instant the
+    # completer had the pass's results (0.0: the pass failed); plus the
+    # per-request reduced-probe override snapshotted from the admission
+    # contextvar on the request thread — it rides the entry across to the
+    # dispatcher.
     t_q: float = 0.0
+    t_ready: float = 0.0
     probe_fraction: float | None = None
     nprobe_applied: int | None = None
     # multi-tenancy: the tenant identity snapshotted from the request
@@ -481,6 +486,17 @@ class TopNBatcher:
         # number and not nothing, as it would where the counter is missing
         self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
         self._m_submit_seconds = _metrics.histogram("serving.batcher.submit.seconds")
+        # the host path, stage by stage (serving/stages.py): a request's time
+        # in here and the part of it after the results were on the host; the
+        # part of a submit inside the device call; and what the interpreter
+        # spends on the two threads
+        self._m_entry_seconds = _metrics.histogram("serving.batcher.entry.seconds")
+        self._m_wake_seconds = _metrics.histogram("serving.batcher.wake.seconds")
+        self._m_device_call_seconds = _metrics.histogram(
+            "serving.batcher.submit.device-call.seconds"
+        )
+        self._m_dispatch_cpu = _metrics.counter("serving.batcher.dispatch.cpu.seconds")
+        self._m_complete_cpu = _metrics.counter("serving.batcher.complete.cpu.seconds")
         self._m_pass_seconds = _metrics.histogram("serving.batcher.pass.seconds")
         self._m_deliver_seconds = _metrics.histogram("serving.batcher.deliver.seconds")
         self._m_coalesced = _metrics.counter("serving.batcher.coalesced")
@@ -550,7 +566,7 @@ class TopNBatcher:
         # both contextvars
         e.probe_fraction = active_probe_fraction()
         e.tenant = current_tenant()
-        e.t_q = time.monotonic()
+        e.t_q = time.perf_counter()
         with self._state_lock:  # an entry can never land after the sentinel
             if self._closed:
                 raise BatcherClosedError("batcher is closed")
@@ -581,6 +597,11 @@ class TopNBatcher:
         e.done.wait()
         if e.error is not None:
             raise e.error
+        if stages.staged():  # one request in eight of a front's thread
+            t_woken = time.perf_counter()
+            self._m_entry_seconds.observe(t_woken - e.t_q)
+            self._m_wake_seconds.observe(t_woken - e.t_ready)
+            stages.scanned(e.t_q, t_woken)
         return e.idx, e.vals
 
     # -- dispatcher ----------------------------------------------------------
@@ -697,6 +718,7 @@ class TopNBatcher:
         return batch
 
     def _dispatch_loop(self) -> None:
+        cpu = stages.ThreadCpu(self._m_dispatch_cpu)
         while True:
             batch = self._take_batch()
             if batch is None:
@@ -715,6 +737,7 @@ class TopNBatcher:
                 ).append(e)
             for (_, cosine, _xk, _pf), entries in groups.items():
                 self._submit_group(entries, cosine)
+            cpu.account(time.perf_counter())
 
     def _acquire_slot(self) -> int:
         """Block until an inflight slot is free, take it, and return the
@@ -755,7 +778,7 @@ class TopNBatcher:
         """EWMA the worst enqueue->dispatch wait of the group — the
         admission controller's primary pressure signal — and put every
         entry's own wait, between the same instants, into the histogram."""
-        now = time.monotonic()
+        now = time.perf_counter()
         worst = 0.0
         for e in entries:
             wait = now - e.t_q
@@ -767,7 +790,7 @@ class TopNBatcher:
                 WAIT_EWMA_ALPHA * wait_ms
                 + (1.0 - WAIT_EWMA_ALPHA) * self._queue_wait_ewma_ms
             )
-            self._last_wait_obs = now
+            self._last_wait_obs = time.monotonic()
             _metrics.gauge("serving.batcher.queue.wait-ewma-ms").set(
                 self._queue_wait_ewma_ms
             )
@@ -876,7 +899,9 @@ class TopNBatcher:
                 prefetch(queries[:n], nprobe=nprobe, cosine=cosine)
             except Exception:  # never let a hint fail a dispatch
                 pass
-        return topn_ops.submit_top_k(uploaded, queries, kk, cosine=cosine, nprobe=nprobe)
+        return self._device_call(
+            topn_ops.submit_top_k, uploaded, queries, kk, cosine=cosine, nprobe=nprobe
+        )
 
     def _submit_indexed(self, entries: list[_Entry], cosine: bool, kk: int, nprobe, padded: int):
         """Dispatch one coalesced index-entry group (caller holds the
@@ -886,7 +911,8 @@ class TopNBatcher:
         pad = padded - len(rows)
         if pad:  # bucketed shapes: row 0 repeats, results discarded
             rows = np.concatenate([rows, np.zeros(pad, np.int32)])
-        return topn_ops.submit_top_k_multi_indexed(
+        return self._device_call(
+            topn_ops.submit_top_k_multi_indexed,
             entries[0].uploaded,
             entries[0].x_dev,
             rows,
@@ -896,9 +922,21 @@ class TopNBatcher:
             nprobe=nprobe,
         )
 
+    def _device_call(self, submit, *args, **kwargs):
+        """The part of a submit inside `ops/topn.py` (row groups,
+        `jnp.asarray`, the jitted call, the two result copies), timed and
+        marked on the profiler's timeline; the rest of
+        `serving.batcher.submit.seconds` is the batcher's own Python."""
+        t0 = time.perf_counter()
+        with profiling.annotate("serving.pass.submit.device-call"):
+            handle = submit(*args, **kwargs)
+        self._m_device_call_seconds.observe(time.perf_counter() - t0)
+        return handle
+
     # -- completer -----------------------------------------------------------
 
     def _complete_loop(self) -> None:
+        cpu = stages.ThreadCpu(self._m_complete_cpu)
         while True:
             item = self._pending.get()
             if item is None:
@@ -914,6 +952,7 @@ class TopNBatcher:
                 for row, e in enumerate(entries):
                     e.idx = idx[row, : e.k]
                     e.vals = vals[row, : e.k]
+                    e.t_ready = t_ready
             except BaseException as exc:
                 item.timing.failed = True
                 for e in entries:
@@ -929,6 +968,7 @@ class TopNBatcher:
                 if latency is not None:
                     self._m_pass_seconds.observe(latency)
                     self._m_deliver_seconds.observe(time.perf_counter() - t_ready)
+                    cpu.account(t_ready)
 
     # -- lifecycle -----------------------------------------------------------
 

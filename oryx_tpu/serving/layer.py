@@ -40,6 +40,7 @@ from oryx_tpu.common.lang import load_instance_of
 from oryx_tpu.common.resilience import RetryPolicy, SupervisedThread
 from oryx_tpu.experiments import routing as _exp_routing
 from oryx_tpu.serving import overload as _overload
+from oryx_tpu.serving import stages as _stages
 from oryx_tpu.tenancy import context as _tenancy
 from oryx_tpu.serving.web import (
     OryxServingException,
@@ -500,12 +501,14 @@ def _experiments_report(ctx: ServingContext, req: Request) -> Response:
 def _observe_request(
     method: str, status: int, t0: float, layer=None, tenant: str | None = None
 ) -> None:
-    dt = time.perf_counter() - t0
+    now = time.perf_counter()
+    dt = now - t0
     metrics.registry.counter(f"serving.requests.{method}").inc()
     metrics.registry.counter(f"serving.responses.{status // 100}xx").inc()
     metrics.registry.histogram("serving.request.seconds").observe(dt)
     if layer is None:
         return
+    layer.stages.observed(now)  # the same instant: the stages tile `dt`
     # instance-scoped mirrors (per-replica truth in a multi-replica
     # process) plus the per-generation counter that makes a rotation
     # observable: the live generation at response time is stamped on the
@@ -726,6 +729,9 @@ class ServingLayer:
         # the module-global registry aggregates every replica; this registry
         # is this replica alone, and /metrics serves it shadowing the global
         self.instance_metrics = metrics.MetricsRegistry()
+        # the host path's instruments (serving/stages.py): handles taken
+        # here, fed by whichever front serves
+        self.stages = _stages.HostStages()
         self._inflight = 0
         self._inflight_cond = threading.Condition()
         # close() can race between the fleet driver and atexit/signal
@@ -985,6 +991,7 @@ class ServingLayer:
         from oryx_tpu.serving import native_front as _native_mod
 
         self._native_front = _native_mod.maybe_start(self, ctx, threads)
+        self.stages.native.set(1 if self._native_front is not None else 0)
         from oryx_tpu.common import ledger
 
         if self._native_front is not None:
@@ -1658,12 +1665,20 @@ def _make_handler(layer: ServingLayer, ctx: ServingContext):
         def log_message(self, fmt, *args):  # route to logging, not stderr
             log.debug("%s " + fmt, self.address_string(), *args)
 
+        def parse_request(self) -> bool:
+            ok = super().parse_request()
+            # the last byte of the request line and headers is parsed: the
+            # front's stamp
+            self._t_parsed = time.perf_counter()
+            return ok
+
         def _handle(self, method: str) -> None:
-            t0 = time.perf_counter()
+            t0 = layer.stages.begin(time.perf_counter() - self._t_parsed)
             layer._request_began()
             try:
                 self._handle_counted(method, t0)
             finally:
+                layer.stages.responded()
                 layer._request_ended()
 
         def _handle_counted(self, method: str, t0: float) -> None:

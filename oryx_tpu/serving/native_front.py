@@ -51,6 +51,7 @@ from oryx_tpu import native
 from oryx_tpu.bus import blockcodec
 from oryx_tpu.common import metrics, tracing
 from oryx_tpu.serving import overload as _overload
+from oryx_tpu.serving import stages as _stages
 from oryx_tpu.serving.web import OryxServingException, Request, render
 from oryx_tpu.tenancy import context as _tenancy
 
@@ -314,6 +315,9 @@ class NativeFront:
     def _poll_loop(self) -> None:
         lib, handle = self._lib, self._handle
         buf, cap = self._poll_buf, self._poll_cap
+        # what this thread's interpreter spends bringing requests to their
+        # workers (it blocks in hf_poll in between)
+        cpu = _stages.ThreadCpu(self._layer.stages.front_cpu)
         while True:
             n = lib.hf_poll(handle, buf, cap, 250)
             if n < 0:
@@ -332,11 +336,14 @@ class NativeFront:
                 continue
             for rec in records:
                 self._pool.submit(self._serve_one, rec)
+            cpu.account(time.perf_counter())
 
     def _serve_one(self, rec) -> None:
         """Mirror of Handler._handle for one pre-parsed request."""
         layer = self._layer
-        t0 = time.perf_counter()
+        # the C++ front stamped the record on CLOCK_MONOTONIC when it had
+        # parsed the request's last byte
+        t0 = layer.stages.begin(time.clock_gettime(time.CLOCK_MONOTONIC) - rec.t_parsed)
         layer._request_began()
         try:
             path = rec.target.split("?", 1)[0]
@@ -378,6 +385,7 @@ class NativeFront:
                                rec.method == "HEAD"),
             )
         finally:
+            layer.stages.responded()
             layer._request_ended()
 
     def _assemble(self, status, payload, ct, extra, accept_encoding,

@@ -828,31 +828,38 @@ def test_close_during_a_hold_submits_the_open_batch_and_joins(stub):
     assert got["hold_s"] < 0.5 * _PASS_S and closed_in < 2.5 * _PASS_S
 
 
-def test_one_slow_pass_does_not_stretch_the_estimate_of_the_next(stub):
+def test_one_slow_pass_does_not_stretch_the_estimate_of_the_next():
     """Four passes of 20 ms behind one another and one whose results were
-    stalled for 80 ms (a pause of the machine): the estimate stays the pass's."""
-    stub.pass_s = 0.020
+    stalled for 80 ms (a pause of the machine): the estimate stays the pass's.
+    Decided on the stub device's own stamps, not on sleeps: `a_pass` says,
+    by the stub's rule (one pass at a time, its results on the host when it
+    ends), when a pass was submitted and when its results were there, and
+    hands `_settle` the two as the dispatcher and the completer stamp them.
+    The batcher's own dispatcher waits on its empty queue and touches none
+    of this state."""
+    pass_s, free_at = 0.020, 0.0
+    key = ("a handle", False, False, None, 8, 16)
     b = TopNBatcher()
-    handle = object()
+
+    def a_pass(submitted_at: float, stalled_s: float = 0.0) -> None:
+        nonlocal free_at
+        free_at = max(submitted_at, free_at) + pass_s
+        b._flight.append(batcher_mod._PassTiming(key, submitted_at, t_ready=free_at + stalled_s))
+        b._settle()
+
     try:
-        _timed(b, stub, handle, behind=4)
-        n_groups = len(stub.groups)
-        ahead = _ask(b, [1], uploaded=handle)
-        _wait_until(lambda: len(stub.groups) == n_groups + 1)
-        gate = stub.gate = threading.Event()
-        stalled = _ask(b, [2], uploaded=handle)  # behind `ahead`, held or not
-        _wait_until(lambda: len(stub.groups) == n_groups + 2)
-        stub.gate = None
-        time.sleep(0.08)
-        gate.set()
-        _join(ahead)
-        _join(stalled)
-        b._settle()  # the dispatcher is idle: nothing else touches its state
-        (key,) = b._service_s
+        a_pass(100.000)  # on the idle device (no estimate yet: it reads nothing)
+        for n in range(1, 5):
+            a_pass(100.000 + 0.001 * n)  # each behind the one before it: the service time
+        assert b._service_estimate(key) == pytest.approx(pass_s) and not b._lag_s
+        a_pass(100.200)  # on the idle device again: the lag (the stub's: none)
+        a_pass(100.300)  # the pass ahead
+        a_pass(100.301, stalled_s=0.080)  # behind it, its results 80 ms late
         samples = sorted(b._service_s[key])
-        assert len(samples) == 5 and samples[-1] > 0.06
-        assert 0.015 < b._service_estimate(key) < 0.030
-        assert b._lag_s and b._lag() < 0.005  # the stub's results are on the host when its pass ends
+        assert len(samples) == 5 and samples[-1] == pytest.approx(pass_s + 0.080)
+        assert b._service_estimate(key) == pytest.approx(pass_s)
+        assert len(b._lag_s) == 2 and b._lag() == pytest.approx(0.0, abs=1e-9)
+        assert not b._flight
     finally:
         b.close()
 

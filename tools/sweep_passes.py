@@ -8,7 +8,12 @@ ladder's pressure at the window's end, rows and passes
 (`serving.batcher.pass.*`), queue wait and pass in flight, the later
 close (share of passes held, share of those submitted late, mean hold,
 rows that joined a hold, mean error of the prediction, the result lag it
-reckons with at the window's end), and, with
+reckons with at the window's end), the host path stage by stage in wall
+and in thread-CPU time (`serving.front.*`, `serving.handler.*`,
+`serving.batcher.entry` / `wake` / `submit.device-call`, the
+dispatcher's and the completer's CPU a pass,
+the process's CPU as cores busy: which stage grows towards the knee),
+and, with
 `--trace 1`, the device's idle share and the scan kernel's ms a pass from
 a profiler recording of the 4 s after the window at the same load; with
 `--raw`, every request's due time and latency of every window. A tool
@@ -50,6 +55,9 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
     def mean_ms(hist):
         return 1000.0 * d(hist, "sum") / max(d(hist, "count"), 1.0)
 
+    def per_request_ms(counter):
+        return 1000.0 * d(counter) / max(d("serving.handler.requests"), 1.0)
+
     passes = max(d("serving.batcher.passes"), 1.0)
     queries = max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0)
     held = d("serving.batcher.pass.held")  # 0 on a program without the later close
@@ -79,6 +87,25 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         "indexed_pct": 100.0 * d("serving.scan.indexed.queries") / queries,
         "cosine_pct": 100.0 * d("serving.scan.cosine.queries") / queries,
         "submit_mean_ms": mean_ms("serving.batcher.submit.seconds"),
+        # the host path, stage by stage (serving/stages.py): a request's
+        # wall time in order, then a pass's, then the CPU beside them
+        "front_native": (after.get("serving.front.native") or {}).get("value"),
+        "front_ingress_mean_ms": mean_ms("serving.front.ingress.seconds"),
+        "handler_pre_mean_ms": mean_ms("serving.handler.pre.seconds"),
+        "batcher_entry_mean_ms": mean_ms("serving.batcher.entry.seconds"),
+        "waiter_wake_mean_ms": mean_ms("serving.batcher.wake.seconds"),
+        "handler_post_mean_ms": mean_ms("serving.handler.post.seconds"),
+        "front_respond_mean_ms": mean_ms("serving.front.respond.seconds"),
+        "rescans": d("serving.handler.rescans"),
+        "deliver_mean_ms": mean_ms("serving.batcher.deliver.seconds"),
+        "submit_device_call_mean_ms": mean_ms("serving.batcher.submit.device-call.seconds"),
+        "handler_cpu_ms_per_request": per_request_ms("serving.handler.cpu.seconds"),
+        "front_cpu_ms_per_request": per_request_ms("serving.front.cpu.seconds"),
+        "server_cpu_ms_per_request": per_request_ms("serving.process.cpu.seconds"),
+        "dispatch_cpu_ms_per_pass": 1000.0 * d("serving.batcher.dispatch.cpu.seconds") / passes,
+        "complete_cpu_ms_per_pass": 1000.0 * d("serving.batcher.complete.cpu.seconds") / passes,
+        "process_cores_busy": d("serving.process.cpu.seconds") / seconds,
+        "requests_per_s": d("serving.handler.requests") / seconds,
         "vector_upload_kb_per_pass": d("serving.scan.vector.upload-bytes") / passes / 1024.0,
         "unstaged_requests": d("serving.users.unstaged-requests"),
         "compiles": d("jax.compile.seconds", "count"),
