@@ -240,7 +240,6 @@ class NativeFront:
         # the on-demand scrape drain in _serve_one
         self._drain_lock = threading.Lock()
         self._stop = threading.Event()
-        self._respond_lock = threading.Lock()
         self._closed = False
         self._close_lock = threading.Lock()
         self._closing = False
@@ -303,11 +302,11 @@ class NativeFront:
             self._drain_trace()
         except Exception:
             log.exception("final native stats drain failed")
-        # _handle itself is never reassigned: _closed (set under the
-        # respond lock) is the gate that keeps hf_respond from touching
-        # the handle after hf_close frees it
-        with self._respond_lock:
-            self._closed = True
+        # `_respond` runs on pool threads alone and the pool is joined
+        # above, so no thread is inside hf_respond now and none can enter
+        # it; _closed (the handle itself is never reassigned) turns away
+        # a caller that is not a pool thread
+        self._closed = True
         self._lib.hf_close(self._handle)
 
     # -- forwarded-request data plane ---------------------------------------
@@ -426,11 +425,20 @@ class NativeFront:
         return ("\r\n".join(lines) + "\r\n\r\n").encode("latin-1") + body
 
     def _respond(self, rec, data: bytes) -> None:
-        with self._respond_lock:
-            if self._closed:
-                return
-            self._lib.hf_respond(self._handle, rec.conn_id, rec.req_id,
-                                 _u8(data), len(data), 0)
+        """Hand one response to the C++ front, with no Python lock held:
+        a lock across this call, which drops the interpreter lock and
+        queues for it again on return, made 32 busy threads' responses
+        wait their turn one behind the other (PERF.md section 6, PR 38).
+        After hf_shutdown the live handle answers -1 and the response is
+        dropped."""
+        if self._closed:
+            return
+        staged = _stages.staged()
+        t_call = time.perf_counter() if staged else 0.0
+        self._lib.hf_respond(self._handle, rec.conn_id, rec.req_id,
+                             _u8(data), len(data), 0)
+        if staged:
+            _stages.respond_called(t_call, time.perf_counter())
 
     # -- control plane -------------------------------------------------------
 

@@ -20,6 +20,10 @@ others; one that scans twice feeds ``entry`` and ``wake`` a scan, ``pre``
 up to its first and ``post`` from its last, and counts in
 ``serving.handler.rescans``.
 
+Inside ``front.respond`` the native front stamps either side of its one
+call into the C++ front (``respond_called``): ``serving.front.respond.call``
+is that call and the interpreter's return from it, a staged request's only.
+
 Only every ``SAMPLE_EVERY``-th request of a thread is staged: the others
 pass three tests of one attribute and feed nothing, because a histogram's
 observation is 2 us of interpreter on a chip's host and a saturated
@@ -96,7 +100,8 @@ class _Thread:
     nothing here."""
 
     __slots__ = (
-        "cpu", "n", "counted", "ingress_s", "t_begin", "t_q", "t_woken", "scans", "t_observed"
+        "cpu", "n", "counted", "ingress_s", "t_begin", "t_q", "t_woken", "scans", "t_observed",
+        "call_s",
     )
 
     def __init__(self, counter: metrics.Counter) -> None:
@@ -122,6 +127,12 @@ def scanned(t_q: float, t_woken: float) -> None:
     st.t_woken = t_woken
 
 
+def respond_called(t_call: float, t_back: float) -> None:
+    """Native front, on a staged request's thread: the stamps either side
+    of its ``hf_respond`` call, both ``perf_counter``."""
+    _local.thread.call_s = t_back - t_call
+
+
 class HostStages:
     """The instruments of the front and the handlers, and the three calls
     a front makes a request: ``begin`` first, ``observed`` from
@@ -135,6 +146,10 @@ class HostStages:
         self.pre = registry.histogram("serving.handler.pre.seconds")
         self.post = registry.histogram("serving.handler.post.seconds")
         self.respond = registry.histogram("serving.front.respond.seconds")
+        # inside `respond`: the native front's hf_respond call and the
+        # interpreter's return from it (operator histogram; the Python
+        # front makes no such call and feeds nothing)
+        self.respond_call = registry.histogram("serving.front.respond.call.seconds")
         self.rescans = registry.counter("serving.handler.rescans")
         # every request a Python thread began, staged or not, counted when
         # its thread accounts its CPU: what the CPU counters are read over
@@ -163,6 +178,7 @@ class HostStages:
         st.ingress_s = ingress_s
         st.scans = 0
         st.t_observed = 0.0  # `_observe_request` has not run yet
+        st.call_s = 0.0  # nor has the native front made its hf_respond call
         st.t_begin = now = time.perf_counter()
         return now
 
@@ -190,6 +206,8 @@ class HostStages:
                 if scans > 1:
                     self.rescans.inc(scans - 1)
             self.respond.observe(now - t_observed)
+            if st.call_s:
+                self.respond_call.observe(st.call_s)
         st.scans = -1
         if st.cpu.account(now):
             self.requests.inc(st.n - st.counted)

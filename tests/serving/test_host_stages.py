@@ -30,6 +30,8 @@ REQUEST_STAGES = (
     "serving.batcher.wake.seconds", "serving.handler.post.seconds",
 )
 FRONT_STAGES = ("serving.front.ingress.seconds", "serving.front.respond.seconds")
+# inside `respond`, the native front's alone: its hf_respond call
+RESPOND_CALL = "serving.front.respond.call.seconds"
 PASS_STAGES = (
     "serving.batcher.submit.device-call.seconds", "serving.batcher.submit.seconds",
 )
@@ -38,7 +40,7 @@ CPU_COUNTERS = (
     "serving.batcher.dispatch.cpu.seconds", "serving.batcher.complete.cpu.seconds",
 )
 INSTRUMENTS = (
-    *REQUEST_STAGES, *FRONT_STAGES, *PASS_STAGES, *CPU_COUNTERS,
+    *REQUEST_STAGES, *FRONT_STAGES, RESPOND_CALL, *PASS_STAGES, *CPU_COUNTERS,
     "serving.handler.rescans", "serving.handler.requests", "serving.process.cpu.seconds",
     "serving.front.native", "serving.batcher.hold.lag-ms",
 )
@@ -111,17 +113,20 @@ def _routes(layer) -> None:
 
 @pytest.fixture(params=["python", "native"])
 def served(request, monkeypatch):
-    """A started layer behind the front the case names, the stub device,
-    and a fresh default batcher (one that predates a cleared registry
-    holds stale handles). Every request is staged here, where a serving
-    replica stages every eighth of a thread's."""
-    if request.param == "native" and not hasattr(native.get_library(), "hf_create"):
+    """A started layer behind the front the case names (`native-one-thread`:
+    the native front with one dispatch thread, so that every request is
+    that thread's), the stub device, and a fresh default batcher (one that
+    predates a cleared registry holds stale handles). Every request is
+    staged here, where a serving replica stages every eighth of a
+    thread's."""
+    front, _, one_thread = request.param.partition("-")
+    if front == "native" and not hasattr(native.get_library(), "hf_create"):
         pytest.skip("the native library does not build here")
     batcher_mod.close_default_batcher()
     monkeypatch.setattr(stages, "CPU_EVERY_S", 0.0)  # every thread accounts its CPU at every turn
     monkeypatch.setattr(stages, "SAMPLE_EVERY", 1)
     passes = _stub_device(monkeypatch)
-    enabled = "true" if request.param == "native" else "false"
+    enabled = "true" if front == "native" else "false"
     cfg = C.get_default().with_overlay(
         f"""
         oryx {{
@@ -130,6 +135,7 @@ def served(request, monkeypatch):
           serving {{
             api.port = 0
             native.enabled = "{enabled}"
+            {"native.dispatch-threads = 1" if one_thread else ""}
             model-manager-class = "oryx_tpu.example.serving:ExampleServingModelManager"
             application-resources = "oryx_tpu.example.serving"
           }}
@@ -141,7 +147,7 @@ def served(request, monkeypatch):
     layer.start()
     conn = http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30)
     try:
-        yield layer, conn, passes, request.param
+        yield layer, conn, passes, front
     finally:
         conn.close()
         layer.close()
@@ -195,6 +201,8 @@ def test_the_stages_tile_a_request_and_each_is_observed_once(served, kind):
         assert moved("serving.batcher.submit.device-call.seconds", "sum") <= moved(
             "serving.batcher.submit.seconds", "sum"
         )
+        assert moved(RESPOND_CALL, "count") == (1 if front == "native" else 0)
+        assert 0.0 <= moved(RESPOND_CALL, "sum") <= moved("serving.front.respond.seconds", "sum")
         # CPU only rises, and a thread cannot burn more than the wall it had:
         # the serving thread's from one answer handed over to the next (with
         # the Python front the parse of the next request is in it)
@@ -295,6 +303,35 @@ def test_a_thread_stages_its_first_request_and_every_eighth_after_it(served, mon
             for name in REQUEST_STAGES + FRONT_STAGES:
                 assert _counter_delta(before, after, name, "count") == 0, name
     assert len(passes) == 17
+
+
+@pytest.mark.parametrize("served", ["python", "native-one-thread"], indirect=True)
+def test_the_respond_call_is_a_staged_request_s_and_lies_inside_its_respond(served, monkeypatch):
+    """`serving.front.respond.call.seconds`: once a staged request of the
+    native front (the 1st, 9th and 17th of its one thread), inside that
+    request's `serving.front.respond.seconds`; never for the seven between
+    two of them, answers and errors alike, and never by the Python front,
+    which makes no such call."""
+    _layer, conn, _passes, front = served
+    monkeypatch.setattr(stages, "SAMPLE_EVERY", 8)
+    start = _snap()
+    for n in range(17):
+        path, status = ("/boom", 503) if n % 4 == 0 else (f"/scan/vector/{n}", 200)
+        if n % 8:
+            assert _get(conn, path)[0] == status
+        else:
+            before, after = _one_request(conn, path, status)
+            moved = lambda name, field="count": _counter_delta(before, after, name, field)
+            assert moved(RESPOND_CALL) == (1 if front == "native" else 0)
+            assert 0.0 <= moved(RESPOND_CALL, "sum") <= moved("serving.front.respond.seconds", "sum")
+            if front == "native":
+                assert moved(RESPOND_CALL, "sum") > 0.0
+    # the 17th answer's observations are the thread's last: the seven
+    # before it fed nothing that a later snapshot could still find
+    done = _snap()
+    assert _counter_delta(start, done, "serving.front.respond.seconds", "count") == 3
+    assert _counter_delta(start, done, RESPOND_CALL, "count") == (3 if front == "native" else 0)
+    assert _counter_delta(start, done, "serving.request.seconds", "count") == 17
 
 
 @pytest.mark.parametrize("name", INSTRUMENTS)

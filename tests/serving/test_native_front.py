@@ -19,6 +19,7 @@ Python version suffix. Everything that reaches dispatch is bit-equal.
 
 from __future__ import annotations
 
+import http.client
 import json
 import re
 import socket
@@ -431,8 +432,6 @@ def test_native_pipelined_burst_order(pair):
 
 @needs_native
 def test_native_keepalive_concurrent(pair):
-    import http.client
-
     publish_model(pair.broker, {"a": 1, "b": 2})
     assert wait_for(lambda: is_200(pair.native.port))
     errors = []
@@ -472,6 +471,185 @@ def test_native_mid_request_disconnect_is_isolated(pair):
         assert fetch(pair.native.port, path="/distinct").startswith(
             b"HTTP/1.1 200"
         )
+
+
+# -- the respond stage: no Python lock on the data path, and the close path ----
+
+
+class _Recorded:
+    """Stands in for a front's library: every call through it is noted by
+    name, in order, and made on the real one; what each `hf_respond`
+    returned is kept."""
+
+    def __init__(self, lib, calls, returned):
+        self._lib, self._calls, self._returned = lib, calls, returned
+
+    def __getattr__(self, name):
+        fn = getattr(self._lib, name)
+
+        def call(*args):
+            self._calls.append(name)
+            got = fn(*args)
+            if name == "hf_respond":
+                self._returned.append(got)
+            return got
+
+        return call
+
+
+@pytest.fixture()
+def recorded(request, monkeypatch):
+    """A native layer whose front calls its library through `_Recorded`
+    from before its threads start, with as many dispatch threads as the
+    case asks for. Yields (layer, front, calls, returned)."""
+    from oryx_tpu.serving import native_front as nf
+
+    calls, returned = [], []
+    start = nf.NativeFront.start
+
+    def recording_start(self):
+        self._lib = _Recorded(self._lib, calls, returned)
+        start(self)
+
+    monkeypatch.setattr(nf.NativeFront, "start", recording_start)
+    name = re.sub(r"[^a-z0-9]+", "-", request.node.name.lower())[:48]
+    layer = ServingLayer(make_config(
+        f"inproc://nf-{name}",
+        **{"native.enabled": '"true"',
+           "native.dispatch-threads": getattr(request, "param", 4)},
+    ))
+    layer.start()
+    try:
+        yield layer, layer._native_front, calls, returned
+    finally:
+        layer.close()
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [2, 8, 32], indirect=True)
+def test_responses_handed_over_together_each_reach_their_own_connection(recorded):
+    """As many requests as the front has threads wait at one barrier in
+    their handlers, so their `_respond` calls start together: each
+    connection reads its own answer, whole (20 KB: more than one send)."""
+    layer, front, calls, returned = recorded
+    n = front._pool._max_workers
+    barrier = threading.Barrier(n)
+
+    def answer(req):
+        barrier.wait(timeout=30)
+        return {"tag": req.params["tag"], "fill": req.params["tag"] * 5000}
+
+    layer.router.add("GET", "/together/{tag}", answer)
+    conns = [http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30) for _ in range(n)]
+    try:
+        for i, c in enumerate(conns):
+            c.request("GET", f"/together/t{i:02d}x")
+        for i, c in enumerate(conns):
+            resp = c.getresponse()
+            assert resp.status == 200
+            assert json.loads(resp.read()) == {"tag": f"t{i:02d}x", "fill": f"t{i:02d}x" * 5000}
+    finally:
+        for c in conns:
+            c.close()
+    assert calls.count("hf_respond") == n and returned == [0] * n
+
+
+@needs_native
+def test_a_respond_blocked_inside_the_call_holds_no_other_respond_back(recorded):
+    """No Python lock is held across `hf_respond`: while one thread's call
+    stands still inside the library, another thread's `_respond` returns."""
+    _layer, front, calls, _returned = recorded
+    inside, release = threading.Event(), threading.Event()
+    real = front._lib.hf_respond
+
+    def hf_respond(handle, conn_id, *rest):
+        if conn_id == 4_000_000_001:
+            inside.set()
+            assert release.wait(30)
+        return real(handle, conn_id, *rest)
+
+    front._lib.hf_respond = hf_respond  # an instance attribute: found before __getattr__
+    # connections that do not exist: the C++ side drops what it is handed
+    stuck = threading.Thread(
+        target=front._respond, args=(SimpleNamespace(conn_id=4_000_000_001, req_id=1), b"x")
+    )
+    stuck.start()
+    try:
+        assert inside.wait(10)
+        other = threading.Thread(
+            target=front._respond, args=(SimpleNamespace(conn_id=4_000_000_002, req_id=1), b"y")
+        )
+        other.start()
+        other.join(timeout=10)
+        assert not other.is_alive(), "a second _respond waited for the first one's call"
+        assert stuck.is_alive() and calls.count("hf_respond") == 1
+    finally:
+        release.set()
+        stuck.join(timeout=10)
+    assert not stuck.is_alive() and calls.count("hf_respond") == 2
+
+
+@needs_native
+@pytest.mark.parametrize("when", ["before", "during", "after"])
+def test_close_orders_the_library_s_calls_around_in_flight_responds(recorded, when):
+    """`close()` before / during / after a request's respond: hf_shutdown,
+    then whatever responds were still in their handlers (the live handle
+    answers -1 and they are dropped), then hf_close, after which no
+    hf_respond is made; nothing raises; a second close is no close."""
+    layer, front, calls, returned = recorded
+    entered, release = threading.Event(), threading.Event()
+
+    def slow(req):
+        entered.set()
+        assert release.wait(30)
+        return {"n": 1}
+
+    layer.router.add("GET", "/slow", slow)
+    errors = []
+    respond = front._respond
+
+    def checked_respond(rec, data):
+        try:
+            respond(rec, data)
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+            raise
+
+    front._respond = checked_respond
+    conn = http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30)
+    conn.connect()
+    closer = threading.Thread(target=front.close)
+    try:
+        if when != "before":
+            conn.request("GET", "/slow")
+            assert entered.wait(10)
+        if when == "after":
+            release.set()
+            assert conn.getresponse().read() == b'{"n": 1}'
+        closer.start()
+        if when == "during":
+            # close() stands in the pool's join until the handler returns
+            assert wait_for(lambda: "hf_shutdown" in calls)
+            closer.join(timeout=0.3)
+            assert closer.is_alive() and "hf_close" not in calls
+            release.set()
+        closer.join(timeout=30)
+        assert not closer.is_alive()
+    finally:
+        release.set()
+        conn.close()
+    # a respond that starts once the front is closed (no pool thread can)
+    front._respond(SimpleNamespace(conn_id=1, req_id=1), b"late")
+    front.close()
+    layer.close()
+    assert not errors
+    order = [c for c in calls if c in ("hf_respond", "hf_shutdown", "hf_close")]
+    responds = 0 if when == "before" else 1
+    if when == "after":
+        assert order == ["hf_respond", "hf_shutdown", "hf_close"] and returned == [0]
+    else:
+        assert order == ["hf_shutdown"] + ["hf_respond"] * responds + ["hf_close"]
+        assert returned == [-1] * responds  # started after hf_shutdown: dropped by the live handle
 
 
 # -- fallback: bit-compatible when the native path is unavailable ------------

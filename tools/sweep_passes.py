@@ -1,9 +1,9 @@
-"""python3 tools/sweep_passes.py --workload <open cell> --seed <n> --rates 500,1000,600:30,...
+"""python3 tools/sweep_passes.py --workload <cell> --seed <n> --rates 500,1000,600:30,c16:30,...
        [--seconds 10] [--trace 0|1] [--raw]
 
-`benchmark/sweep.py` for an open-loop cell, with the batcher's and the
-device's view beside the generator's: one set-up, one window a rate, and
-for each window p50 / p95, the shed share, how late the generator ran, the
+`benchmark/sweep.py` with the batcher's and the device's view beside the
+generator's: one set-up, one window a rate (or, `c16`, a closed loop of 16
+clients: answers/s and the clients' p95), and for each window p50 / p95, the shed share, how late the generator ran, the
 ladder's pressure at the window's end, rows and passes
 (`serving.batcher.pass.*`), queue wait and pass in flight, the later
 close (share of passes held, share of those submitted late, mean hold,
@@ -35,12 +35,18 @@ from benchmark import run, stats  # noqa: E402
 from benchmark import spec as spec_mod  # noqa: E402
 
 
-def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
+def window_row(session, load: str, seed: int, seconds: float, trace: bool,
                raw: dict | None = None) -> dict:
-    got, result, span, reduced, _w = session.window(
-        seed, seconds, trace, {"cell": {"rate_per_s": rate}}
-    )
-    if raw is not None:
+    """One window at ``load``: requests/s of an open loop, or `c<n>`, a
+    closed loop of n clients."""
+    closed = load.startswith("c")
+    rate = float(load.lstrip("c"))
+    if closed:
+        over = {"traffic": {"driver": "closed_http", "clients": int(rate)}}
+    else:
+        over = {"cell": {"rate_per_s": rate}}
+    got, result, span, reduced, _w = session.window(seed, seconds, trace, over)
+    if raw is not None and "due" in result:
         # every request's due time and latency, for a builder who asks how
         # a window's tail is made (what a pause of the machine moves)
         i = len(raw) // 2
@@ -62,15 +68,16 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
     queries = max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0)
     held = d("serving.batcher.pass.held")  # 0 on a program without the later close
     row = {
-        "rate_per_s": rate,
+        "clients" if closed else "rate_per_s": rate,
         "seconds": seconds,
         "attempted": got["attempted"],
         "failed": got["failed"],
         "shed_pct": 100.0 * got["failed"] / max(got["attempted"], 1),
         "kinds": result.get("kinds", {}),
-        **{k: got["values"].get(k) for k in (
-            "recommend_p50_ms", "recommend_p95_ms", "recommend_p99_ms", "generator_late_p99_ms"
-        )},
+        **{k: got["values"][k] for k in (
+            "recommend_p50_ms", "recommend_p95_ms", "recommend_p99_ms", "generator_late_p99_ms",
+            "recommend_qps", "closed_p95_ms",
+        ) if k in got["values"]},
         "overload_pressure": (after.get("serving.overload.pressure") or {}).get("value"),
         "handler_mean_ms": mean_ms("serving.request.seconds"),
         "queue_wait_mean_ms": mean_ms("serving.batcher.queue-wait.seconds"),
@@ -96,6 +103,8 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         "waiter_wake_mean_ms": mean_ms("serving.batcher.wake.seconds"),
         "handler_post_mean_ms": mean_ms("serving.handler.post.seconds"),
         "front_respond_mean_ms": mean_ms("serving.front.respond.seconds"),
+        # inside it: the hf_respond call and the interpreter's return from it
+        "front_respond_call_mean_ms": mean_ms("serving.front.respond.call.seconds"),
         "rescans": d("serving.handler.rescans"),
         "deliver_mean_ms": mean_ms("serving.batcher.deliver.seconds"),
         "submit_device_call_mean_ms": mean_ms("serving.batcher.submit.device-call.seconds"),
@@ -109,8 +118,12 @@ def window_row(session, rate: float, seed: int, seconds: float, trace: bool,
         "vector_upload_kb_per_pass": d("serving.scan.vector.upload-bytes") / passes / 1024.0,
         "unstaged_requests": d("serving.users.unstaged-requests"),
         "compiles": d("jax.compile.seconds", "count"),
+        # the longest stop of each process in the window and its second: one
+        # that both met at the same second is the machine's
         "server_pause_max_ms": session.pause["max_ms"],
+        "server_pause_at_s": session.pause["at_s"],
         "generator_pause_max_ms": result["pause"]["max_ms"],
+        "generator_pause_at_s": result["pause"]["at_s"],
     }
     if reduced is not None:
         from benchmark import trace as trace_mod
@@ -127,7 +140,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--rates", required=True,
-                    help="requests/s, comma-separated, in this order; `600:30` = a 30 s window")
+                    help="requests/s, comma-separated, in this order; `600:30` = a 30 s window; "
+                    "`c16` = a closed loop of 16 clients")
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=1)
     ap.add_argument("--raw", action="store_true",
@@ -144,8 +158,8 @@ def main(argv=None) -> int:
     try:
         print("setup: " + ", ".join(f"{k[:-2]} {v:.2f} s" for k, v in session.timings.items()))
         for i, item in enumerate(r for r in args.rates.split(",") if r):
-            rate, _, seconds = item.partition(":")
-            row = window_row(session, float(rate), args.seed + 1000 * i + int(float(rate)),
+            load, _, seconds = item.partition(":")
+            row = window_row(session, load, args.seed + 1000 * i + int(float(load.lstrip("c"))),
                              float(seconds or args.seconds), bool(args.trace), raw)
             rows.append(row)
             print("sweep_passes:", json.dumps(row), flush=True)
