@@ -272,9 +272,10 @@ class Session:
         stats = jax.local_devices()[0].memory_stats() or {}
         return int(stats.get("peak_bytes_in_use", 0))
 
-    def judge(self, got: dict, result: dict) -> tuple[bool, list[str]]:
+    def judge(self, got: dict, result: dict) -> tuple[bool, list[str], dict]:
         """`correct` for one window: the answers asked before it and the
-        seeded sample of its own answers against the plain reference."""
+        seeded sample of its own answers against the plain reference.
+        Returns (correct, lines, every number compared beside its limit)."""
         t0 = time.perf_counter()
         sampled = result.get("sampled", [])
         numbers = check.judge_answers(self.built, self.answers + sampled, self.how_many)
@@ -296,8 +297,13 @@ class Session:
             "window; the reference took %.2f s"
             % (self.asked, lost, len(sampled), time.perf_counter() - t0)
         )
+        compared = {
+            name: {"value": numbers[name], "limit": limit} for name, limit in check.LIMITS.items()
+        }
+        compared["answers_lost"] = {"value": lost, "limit": 0}
+        compared["window_answers_compared_min"] = {"value": len(sampled), "limit": 1}
         # a run that compared nothing of the window's own answers is not correct
-        return correct and lost == 0 and len(sampled) > 0, lines
+        return correct and lost == 0 and len(sampled) > 0, lines, compared
 
 
 def run_cell(
@@ -337,7 +343,7 @@ def run_cell(
         "(process start to window start; the warm phase at the cell's own load is in it, "
         "the reference is not)"
     )
-    correct, check_lines = session.judge(got, result)
+    correct, check_lines, compared = session.judge(got, result)
     lines.extend(check_lines)
 
     values = dict(got["values"])
@@ -378,6 +384,7 @@ def run_cell(
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         out["breakdown"] = reduced["breakdown"]
+    out["compared"] = compared  # last on the line: what a record of a failed run keeps
     return out, lines
 
 
@@ -405,6 +412,11 @@ def main(argv=None) -> int:
     for line in lines:
         print(line)
     print(json.dumps(result), flush=True)
+    # the numbers compared, each beside its limit, once more as the last
+    # lines of standard error
+    for line in lines:
+        if line.startswith("check: "):
+            print(line, file=sys.stderr)
     return 0
 
 

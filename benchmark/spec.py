@@ -77,8 +77,9 @@ class Spec:
     def layer_metric(self, name: str) -> dict:
         """The file of a per-layer metric: its own, or its quantity's. One
         quantity whose cells report different end-to-end metrics is split
-        in BENCHMARK.json (`scan_ms_per_pass.open` moves `recommend_p95_ms`,
-        `.sat` moves `recommend_qps`); how it is read is one file."""
+        in BENCHMARK.json (`scan_kernel_ms_per_pass.open` moves
+        `recommend_p95_ms` and lists the open cells, `.sat` moves
+        `recommend_qps`); how it is read is one file."""
         folder = self.bench_dir / "layer_metrics"
         own = folder / f"{name}.json"
         if own.exists() or "." not in name:

@@ -1,7 +1,10 @@
 """For tests/benchmark/: a temporary copy of the benchmark with tiny cells
 ADDED to it (a configuration, two traffic mixes, cells, and their entries
-in BENCHMARK.json). Nothing that is there is edited, which is also the
-proof that a later PR can add a cell as files plus entries."""
+in BENCHMARK.json). No file that is there is edited; in BENCHMARK.json the
+tiny cells' names are appended to the lists of the cells they are cut from
+and one entry of their own is added. (That a cell can also join by added
+entries alone, `.suffix` twins of its own, is
+tests/benchmark/test_bench_contract.py's `_grown("own-suffix")`.)"""
 
 from __future__ import annotations
 
@@ -60,11 +63,12 @@ def make_copy(tmp: Path, features: int = 16, items: int = 3000, users: int = 400
                              "chips": 1, "why": "tier-1 test"})
     doc["workloads"].append({"name": TINY_SAT, "config": TINY_CONFIG, "traffic": "tiny-closed4",
                              "chips": 1, "why": "tier-1 test"})
+    # each tiny cell joins the lists of the cell it is cut from, as a later
+    # PR's cell may join a folded entry's list
     for m in doc["end_to_end"] + doc["per_layer"]:
-        if "workloads" not in m:
-            continue
-        is_open = m["name"].endswith(".open") or m["name"] == "recommend_p95_ms"
-        m["workloads"] = m["workloads"] + [TINY_OPEN if is_open else TINY_SAT]
+        for twin, tiny in (("als50-recommend-open", TINY_OPEN), ("als250-recommend-sat", TINY_SAT)):
+            if twin in m.get("workloads", ()):
+                m["workloads"] = m["workloads"] + [tiny]
     doc["per_layer"].append(
         {"name": "scan_queries.tiny", "unit": "queries", "better": "higher",
          "source": "program_counter", "layer": "batcher", "moves": "recommend_p95_ms",

@@ -218,9 +218,11 @@ def test_a_recording_that_is_not_this_runs_is_not_read(monkeypatch, tmp_path):
 
 
 def test_a_counter_the_program_lacks_reads_nothing_not_zero():
-    """`inflight_cap_changes` on the parent program: its registry holds
-    no such counter, so the result line leaves the metric out."""
-    args = {"metric": ["serving.batcher.inflight-cap.changes", "value"], "span": "window"}
+    """`unstaged_requests` (the one `counter_delta` quantity since PR 39
+    took out `inflight_cap_changes`, which read 0 in every line since PR
+    28) on a program from before PR 31: its registry holds no such
+    counter, so the result line leaves the metric out."""
+    args = {"metric": ["serving.users.unstaged-requests", "value"], "scale": 1.0, "span": "window"}
     name = args["metric"][0]
     has = ({name: {"type": "counter", "value": 3}}, {name: {"type": "counter", "value": 11}})
     steady = ({name: {"type": "counter", "value": 3}}, {name: {"type": "counter", "value": 3}})
@@ -228,7 +230,7 @@ def test_a_counter_the_program_lacks_reads_nothing_not_zero():
     read = lambda span: counter_delta.read(SimpleNamespace(counters={"window": span}), args)
     assert read(has) == 8.0 and read(steady) == 0.0 and read(lacks) is None
     assert read(None) is None  # no window was taken
-    file = Spec().layer_metric("inflight_cap_changes.open")
+    file = Spec().layer_metric("unstaged_requests.users")
     assert file["reduction"] == "counter_delta" and file["args"] == args
 
 
@@ -263,10 +265,11 @@ def test_a_tiny_cpu_cell_prints_every_window_metric_of_the_pass(copy, monkeypatc
     assert 1.0 <= got["inflight_depth_mean"] <= 32.0
     # a request is in the handler at least as long as it queued and scanned
     assert got["handler_mean_ms"] >= got["queue_wait_mean_ms"]
+    # a quantity goes to the cells its entry lists and to no other
     if suffix == ".open":
-        assert got["inflight_cap_changes"] >= 0.0
+        assert got["compiles_in_window"] == 0.0
     else:
-        assert "inflight_cap_changes.sat" not in out["metrics"]
+        assert "compiles_in_window.sat" not in out["metrics"]
     # a CPU trace has no device plane: the readers of the profiler's timeline find nothing
     assert "scan_kernel_ms_per_pass" not in got
     assert not any("trace_pass" in line for line in lines)

@@ -21,7 +21,7 @@ from benchmark import run as bench_run
 from benchmark import testing
 from benchmark.spec import ROOT, Spec
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -57,6 +57,13 @@ def test_open_cell_end_to_end_prints_the_contracts_line(copy):
     for name in ("score_err_of_scale", "left_out_gap_of_scale", "order_gap_of_scale",
                  "known_items_served"):
         assert f"check: {name} = " in text and "limit" in text
+    # ... and once more on the line itself, under the key that comes last
+    assert list(out)[-1] == "compared"
+    assert set(out["compared"]) == set(bench_run.check.LIMITS) | {
+        "answers_lost", "window_answers_compared_min"}
+    for name, c in out["compared"].items():
+        assert set(c) == {"value", "limit"}, name
+        assert (c["value"] >= c["limit"]) if name.endswith("_min") else (c["value"] <= c["limit"])
     assert "window_failed_share = 0 (0 of 120;" in text
     assert "whole-window p50/p95/p99" in text and "setup: factors" in text
     assert "store_fill" in text and "collect " in text
@@ -97,7 +104,7 @@ def test_traced_run_reports_per_layer_metrics_and_the_added_one(copy, monkeypatc
     assert out["metrics"]["compiles_in_window.open"]["value"] == 0.0
     # ... a device-trace reader that finds nothing to read (no TPU plane in
     # a CPU trace) returns nothing, and the line leaves the metric out
-    assert not {"scan_ms_per_pass.open", "scan_roofline.open", "scan_rows_per_pass.open"} & got
+    assert not {"scan_kernel_ms_per_pass.open", "scan_roofline.open", "scan_rows_per_pass.open"} & got
     assert "recommend_p95_ms" not in got and "setup_s" not in got
 
 
@@ -109,6 +116,26 @@ def test_a_bfloat16_item_matrix_fails_the_check_at_the_stated_tolerance(copy):
     line = next(x for x in lines if "score_err_of_scale" in x)
     assert "FAIL" in line
     assert float(line.split("=")[1].split()[0]) > 1e-4  # limit 1e-5, float32 reads ~2e-7
+    err = out["compared"]["score_err_of_scale"]
+    assert err["value"] > 1e-4 and err["limit"] == 1e-5
+
+
+def test_the_command_prints_the_result_last_on_stdout_and_the_numbers_compared_last_on_stderr(
+    monkeypatch, capsys
+):
+    """The contract's two records of a failed run: the end of the result
+    line and the end of standard error both hold every number compared
+    beside its limit."""
+    result = {"correct": False, "attempted": 3, "failed": 0, "metrics": {}, "device": {},
+              "compared": {"score_err_of_scale": {"value": 4.2e-3, "limit": 1e-5},
+                           "answers_lost": {"value": 0, "limit": 0}}}
+    checks = ["check: score_err_of_scale = 0.0042 (limit <= 1e-05) FAIL",
+              "check: 65 answers asked before the window (0 lost), 3 sampled from the window"]
+    monkeypatch.setattr(bench_run, "run_cell", lambda *a, **kw: (result, ["a line", *checks]))
+    assert bench_run.main(["--workload", "w", "--seed", "2147483650", "--seconds", "1"]) == 0
+    said = capsys.readouterr()
+    assert json.loads(said.out.splitlines()[-1]) == result
+    assert said.err.splitlines() == checks and said.out.splitlines()[:3] == ["a line", *checks]
 
 
 def test_a_broken_timed_path_comes_out_not_correct(copy, monkeypatch):

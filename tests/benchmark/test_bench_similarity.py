@@ -36,8 +36,7 @@ PEAKS = json.loads((ROOT / "benchmark" / "peaks.json").read_text())["TPU v5 lite
 DOC = json.loads((ROOT / "BENCHMARK.json").read_text())
 SIM_METRICS = [m["name"] for m in DOC["per_layer"] if CELL in m["workloads"]]
 # what only a device trace with a named kernel in it can give
-DEVICE_TRACE = {"scan_roofline.sim", "scan_ms_per_pass.sim", "scan_kernel_ms_per_pass.sim",
-                "scan_rows_per_pass.sim"}
+DEVICE_TRACE = {"scan_roofline.open", "scan_kernel_ms_per_pass.open", "scan_rows_per_pass.open"}
 
 
 @pytest.fixture(scope="module")
@@ -79,11 +78,11 @@ def test_every_answer_goes_by_an_uploaded_vector_and_the_cosine_variant(copy, mo
     out, lines = _run(copy, 2**31 + 33, trace=True)
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] == 120
     got = out["metrics"]
-    assert got["indexed_submit_pct.sim"]["value"] == 0.0
+    assert got["indexed_submit_pct.open"]["value"] == 0.0
     assert got["cosine_submit_pct.sim"]["value"] == 100.0
-    assert got["compiles_in_window.sim"]["value"] == 0.0  # the warmed programs are the served ones
-    assert got["submit_mean_ms.sim"]["value"] > 0.0
-    # every .sim metric a run without a TPU plane can read is on the line ...
+    assert got["compiles_in_window.open"]["value"] == 0.0  # the warmed programs are the served ones
+    assert got["submit_mean_ms.open"]["value"] > 0.0
+    # every metric of the cell a run without a TPU plane can read is on the line ...
     assert set(SIM_METRICS) - DEVICE_TRACE <= set(got)
     # ... and a device-trace reader that finds no named kernel returns nothing
     assert not DEVICE_TRACE & set(got)
@@ -231,8 +230,8 @@ OLD = {k: v for k, v in AFTER.items() if k in ("serving.scan.vector.queries",
 
 @pytest.mark.parametrize("metric, reads, on_the_parent", [
     ("cosine_submit_pct.sim", 25.0, 0.0),
-    ("submit_mean_ms.sim", 0.5, None),
     ("submit_mean_ms.open", 0.5, None),
+    ("submit_mean_ms.sat", 0.5, None),
 ])
 def test_the_new_layer_metric_files_on_a_recorded_snapshot(metric, reads, on_the_parent):
     file = Spec().layer_metric(metric)

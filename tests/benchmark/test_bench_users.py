@@ -72,18 +72,18 @@ def test_under_the_real_budget_every_user_is_staged_and_every_answer_goes_by_row
     out, lines = _run(copy, 2**31 + 31)
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] == 120
     got = out["metrics"]
-    assert got["indexed_submit_pct.users"]["value"] == 100.0
+    assert got["indexed_submit_pct.open"]["value"] == 100.0
     assert got["unstaged_requests.users"]["value"] == 0.0
-    assert got["compiles_in_window.users"]["value"] == 0.0
+    assert got["compiles_in_window.open"]["value"] == 0.0
     assert got["stage_users_s.users"]["value"] > 0.0
-    assert {"handler_mean_ms.users", "queue_wait_mean_ms.users", "pass_inflight_mean_ms.users",
-            "deliver_mean_ms.users", "window_rows_per_pass.users", "useful_rows_pct.users",
-            "inflight_depth_mean.users", "recommend_p50_ms.users", "recommend_p99_ms.users",
-            "window_failed_pct.users", "generator_late_p99_ms.users",
-            "generator_pause_max_ms.users", "server_pause_max_ms.users"} <= set(got)
+    assert {"handler_mean_ms.open", "queue_wait_mean_ms.open", "pass_inflight_mean_ms.open",
+            "deliver_mean_ms.open", "window_rows_per_pass.open", "useful_rows_pct.open",
+            "inflight_depth_mean.open", "recommend_p50_ms.open", "recommend_p99_ms.open",
+            "window_failed_pct.open", "generator_late_p99_ms.open",
+            "generator_pause_max_ms.open", "server_pause_max_ms.open"} <= set(got)
     # a CPU trace holds no named kernel: the device-trace readers return nothing
-    assert not {"scan_roofline.users", "scan_ms_per_pass.users", "scan_kernel_ms_per_pass.users",
-                "scan_rows_per_pass.users"} & set(got)
+    assert not {"scan_roofline.open", "scan_kernel_ms_per_pass.open",
+                "scan_rows_per_pass.open"} & set(got)
     assert _gauge("serving.users.stage.refused") == 0
     assert _gauge("serving.users.staged-rows") == 3000
     assert _gauge("serving.users.staged-bytes") == 3750 * 16 * 4  # 25 % headroom
@@ -110,7 +110,7 @@ def test_under_a_budget_too_small_nothing_is_staged_and_the_vector_path_is_still
         out, lines = _run(copy, 32)
     assert out["correct"] is True and out["failed"] == 0
     got = out["metrics"]
-    assert got["indexed_submit_pct.users"]["value"] == 0.0
+    assert got["indexed_submit_pct.open"]["value"] == 0.0
     assert got["unstaged_requests.users"]["value"] == 120.0  # every request of the window
     assert _gauge("serving.users.stage.refused") == 1
     assert _gauge("serving.users.staged-rows") == 0
