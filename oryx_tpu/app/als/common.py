@@ -111,6 +111,16 @@ class FeatureVectors:
         for id_, v in self.items():
             fn(id_, v)
 
+    def fold_in(self, ids: list[str], values, solver, xu, implicit: bool) -> np.ndarray | None:
+        """The vector ``xu`` (None: a new user) after an interaction of
+        strength ``values[j]`` with each of ``ids`` that is here, in turn;
+        None when nothing asked for a change. Interface parity with the
+        native store, which does the look-ups and the recurrence in one
+        call; here ``compute_updated_xu_basket`` does it in NumPy."""
+        vectors, known = self.get_batch(ids)
+        values = [v for v, there in zip(values, known) if there]
+        return compute_updated_xu_basket(solver, values, xu, vectors[known], implicit)
+
     def get_vtv(self) -> np.ndarray | None:
         """V^T V over all vectors (FeatureVectors.getVTV:150-154)."""
         with self._lock.read():
@@ -173,6 +183,50 @@ def compute_updated_xu(
     if xu is None:
         return d_xu
     return np.asarray(xu, dtype=np.float32) + d_xu
+
+
+def compute_updated_xu_basket(
+    solver: Solver,
+    values,
+    xu: np.ndarray | None,
+    ys: np.ndarray,
+    implicit: bool,
+) -> np.ndarray | None:
+    """``compute_updated_xu`` applied to each ``(values[j], ys[j])`` in turn,
+    starting from ``xu`` (None: a new user), in ONE pass of matrix products:
+    the vector after the whole basket, float32, or None when no interaction
+    asked for a change. Every step's ``dXu`` is a multiple of ``z_j =
+    (YtY)^-1 ys[j]``, so ``Xu = xu + sum_j c_j z_j`` and the estimate a step
+    needs is ``Qui_j = xu . ys[j] + sum_{i<j} c_i (ys[j] . z_i)``: one solve
+    for all the ``z`` and one small Gram matrix, then the recurrence over k
+    scalars. What a request pays is a few numpy calls whatever its basket's
+    length, where item by item it paid a dozen an item, each a hand-over of
+    the interpreter lock under load. Sums are float64 throughout (item by
+    item the vector is rounded to float32 after every step)."""
+    ys = np.asarray(ys, dtype=np.float64)
+    k = ys.shape[0]
+    if k == 0:
+        return None
+    z = solver.solve_d_to_d(ys.T).T  # [k, f]: row j = (YtY)^-1 ys[j]
+    gram = (ys @ z.T).tolist()  # [k][k]: ys[j] . z_i
+    started = xu is not None
+    base = (ys @ np.asarray(xu, dtype=np.float64)).tolist() if started else [0.0] * k
+    c = [0.0] * k
+    for j in range(k):
+        row = gram[j]
+        qui = base[j] + sum(c[i] * row[i] for i in range(j))
+        # 0.5 reflects a "don't know" prior for a brand-new user
+        target_qui = compute_target_qui(implicit, float(values[j]), qui if started else 0.5)
+        if math.isnan(target_qui):
+            continue
+        c[j] = target_qui - qui
+        started = True
+    if not started:
+        return None
+    moved = np.asarray(c) @ z
+    if xu is not None:
+        moved += np.asarray(xu, dtype=np.float64)
+    return moved.astype(np.float32)
 
 
 # ---------------------------------------------------------------------------

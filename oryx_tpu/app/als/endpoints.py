@@ -12,11 +12,11 @@ Responses are (id, value) records rendered as JSON objects or text/csv.
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass
 
 import numpy as np
 
-from oryx_tpu.app.als.common import compute_updated_xu
 from oryx_tpu.app.serving_common import (
     check_not_read_only,
     get_ready_model,
@@ -104,20 +104,30 @@ def _parse_item_value_pairs(segments: list[str]) -> list[tuple[str, float]]:
     return out
 
 
-def _anonymous_user_vector(model, pairs: list[tuple[str, float]]) -> np.ndarray:
-    """Fold-in temporary user vector from (item, strength) pairs
-    (EstimateForAnonymous.buildTemporaryUserVector:73-87)."""
+def _fold_in(model, xu, pairs: list[tuple[str, float]]):
+    """`xu` (None: a new user) after each (item, strength) pair in turn, by
+    ALSUtils.computeUpdatedXu against the model's cached YtY solver; an
+    unknown item or a pair that asks no change is passed over. The item
+    store does the look-ups and the recurrence in one call (`fold_in`:
+    natively where the native store serves, so that the serving thread
+    gives up the interpreter lock once and not once a numpy call), and
+    that call is one observation of the model's fold-in instruments; the
+    solver's own build is not in it."""
     solver = model.get_yty_solver()
     if solver is None:
         raise OryxServingException(503, "model not yet loaded")
-    xu = None
-    for item, value in pairs:
-        yi = model.get_item_vector(item)
-        if yi is None:
-            continue
-        updated = compute_updated_xu(solver, value, xu, yi, model.implicit)
-        if updated is not None:
-            xu = updated
+    t0 = time.perf_counter()
+    updated = model.y.fold_in(
+        [item for item, _ in pairs], [value for _, value in pairs], solver, xu, model.implicit
+    )
+    model.observe_fold_in(time.perf_counter() - t0, len(pairs))
+    return xu if updated is None else updated
+
+
+def _anonymous_user_vector(model, pairs: list[tuple[str, float]]) -> np.ndarray:
+    """Fold-in temporary user vector from (item, strength) pairs
+    (EstimateForAnonymous.buildTemporaryUserVector:73-87)."""
+    xu = _fold_in(model, None, pairs)
     if xu is None:
         raise OryxServingException(400, "no valid items")
     return xu
@@ -197,16 +207,7 @@ def recommend_with_context(ctx: ServingContext, req: Request):
     if xu is None:
         raise OryxServingException(404, f"unknown user {user}")
     pairs = _parse_item_value_pairs(req.params["itemValuePairs"])
-    solver = model.get_yty_solver()
-    if solver is None:
-        raise OryxServingException(503, "model not yet loaded")
-    for item, value in pairs:
-        yi = model.get_item_vector(item)
-        if yi is None:
-            continue
-        updated = compute_updated_xu(solver, value, xu, yi, model.implicit)
-        if updated is not None:
-            xu = updated
+    xu = _fold_in(model, xu, pairs)
     how_many, offset = _paging(req)
     exclude = model.get_known_items(user) | {i for i, _ in pairs}
     rescorer = _rescorer(ctx, "recommend", req, [user])

@@ -15,7 +15,12 @@ device arrays).
 State mirrored from the reference: X and Y FeatureVectors, per-user
 known-item sets, expected-ID sets driving get_fraction_loaded
 (ALSServingModel.java:461-475), a cached YtY solver invalidated on Y
-writes (:357-373), and retain-recent rotation (:382-441).
+writes (:357-373), and retain-recent rotation (:382-441). YtY itself
+comes from the packed device copy wherever that copy holds the item rows
+as they are (one pass of ``ops/gram.py`` over it; float64 sums of
+per-block partials), and from the host store's double-precision loop
+(``get_vtv``) for a model whose device copy is quantized, an IVF index, or
+absent.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ from oryx_tpu.common.lang import ReadWriteLock
 from oryx_tpu.common.text import read_json
 from oryx_tpu.common.vectormath import Solver, get_solver
 from oryx_tpu.native.store import make_feature_vectors
+from oryx_tpu.ops import gram as gram_ops
 from oryx_tpu.ops import ivf as ivf_ops
 from oryx_tpu.ops import topn as topn_ops
 from oryx_tpu.serving.batcher import score_default, score_indexed_default
@@ -171,6 +177,14 @@ class ALSServingModel(ServingModel):
         # taken here so that the counter is in every snapshot from the
         # start: absent means the program does not count this, never 0
         self._unstaged_requests = metrics.registry.counter("serving.users.unstaged-requests")
+        self._m_foldin_seconds = metrics.registry.histogram("serving.foldin.seconds")
+        self._m_foldin_requests = metrics.registry.counter("serving.foldin.requests")
+        self._m_foldin_items = metrics.registry.counter("serving.foldin.items")
+        self._m_yty_seconds = metrics.registry.histogram("serving.yty.build.seconds")
+        self._m_yty_builds = {
+            "device": metrics.registry.counter("serving.yty.builds.device"),
+            "host": metrics.registry.counter("serving.yty.builds.host"),
+        }
 
     # -- vectors -------------------------------------------------------------
 
@@ -193,11 +207,10 @@ class ALSServingModel(ServingModel):
         self.y.set_vector(item, vector)
         with self._expected_lock:
             self._expected_items.discard(item)
-        with self._solver_lock:
-            self._yty_solver = None
         with self._cache_lock:
             self._y_dirty = True
             self._dirty_ids.add(item)
+        self._drop_yty_solver()
 
     def set_user_vectors(self, users: list[str], vectors: np.ndarray) -> None:
         """Batched set: one native store call + one lock round for the
@@ -214,11 +227,10 @@ class ALSServingModel(ServingModel):
         self.y.set_batch(items, vectors)
         with self._expected_lock:
             self._expected_items.difference_update(items)
-        with self._solver_lock:
-            self._yty_solver = None
         with self._cache_lock:
             self._y_dirty = True
             self._dirty_ids.update(items)
+        self._drop_yty_solver()
 
     # -- known items (ALSServingModel.java:189-258) --------------------------
 
@@ -297,12 +309,11 @@ class ALSServingModel(ServingModel):
 
     def retain_recent_and_item_ids(self, ids: set[str]) -> None:
         self.y.retain_recent_and_ids(ids)
-        with self._solver_lock:
-            self._yty_solver = None  # rotation invalidates the cached YtY
         with self._cache_lock:
             self._y_dirty = True
             self._y_full_rebuild = True  # membership may have shrunk
             self._y_rotation_epoch += 1
+        self._drop_yty_solver()  # rotation invalidates the cached YtY
 
     def retain_recent_and_known_items(self, user_ids: set[str]) -> None:
         with self._known_lock.write():
@@ -311,10 +322,49 @@ class ALSServingModel(ServingModel):
 
     # -- solver --------------------------------------------------------------
 
+    def observe_fold_in(self, seconds: float, items: int) -> None:
+        """One request's fold-in (endpoints._fold_in): its item look-ups
+        and recurrence took ``seconds`` over ``items`` basket items."""
+        self._m_foldin_seconds.observe(seconds)
+        self._m_foldin_requests.inc()
+        self._m_foldin_items.inc(items)
+
+    def _drop_yty_solver(self) -> None:
+        """A write to Y drops the cached solver. Called AFTER the write has
+        marked the device copy dirty: a build that starts in between then
+        refreshes that copy first, and one already under way holds the
+        solver lock, so its result is dropped here as soon as it lands."""
+        with self._solver_lock:
+            self._yty_solver = None
+
     def get_yty_solver(self) -> Solver | None:
+        """The cached solver over YtY, built on first use and after every
+        write to Y. Where the device copy holds the item rows as they are
+        (``gram_ops.supported``: float32 / bfloat16, one chip or sharded,
+        and the plain pair) YtY is one pass over THAT copy, refreshed with
+        the pending writes first, so it is the Gram matrix of the very
+        matrix the scan scores against; a quantized or IVF copy, and a model
+        with no items, take the host store's ``get_vtv``. The solver lock is
+        held across the build: only requests that need this solver, and the
+        writer that is about to drop it, wait behind it; the device copy's
+        own lock is not held while the Gram pass runs."""
         with self._solver_lock:
             if self._yty_solver is None:
-                self._yty_solver = get_solver(self.y.get_vtv())
+                y_mat = self._ensure_y_matrix()[2]
+                on_device = y_mat is not None and gram_ops.supported(y_mat)
+                if on_device:  # the copy as the pending writes leave it, whatever the refresh interval
+                    y_mat = self._ensure_y_matrix(force=True)[2]
+                    gram_ops.wait_ready(y_mat)  # an upload still on its way is not the build
+                t0 = time.perf_counter()
+                if on_device:
+                    with profiling.annotate("serving.yty.build", **gram_ops.pass_stats(y_mat)):
+                        yty = gram_ops.gram(y_mat)
+                else:
+                    yty = self.y.get_vtv()
+                self._yty_solver = get_solver(yty)
+                if yty is not None:
+                    self._m_yty_seconds.observe(time.perf_counter() - t0)
+                    self._m_yty_builds["device" if on_device else "host"].inc()
             return self._yty_solver
 
     # -- device-side scoring ---------------------------------------------------

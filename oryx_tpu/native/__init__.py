@@ -213,6 +213,12 @@ def _declare(lib: ctypes.CDLL) -> None:
         c.c_void_p, c.POINTER(c.c_int64), c.c_char_p, c.c_int64,
         c.POINTER(c.c_float), c.POINTER(c.c_uint8),
     ]
+    lib.fs_fold_in.restype = c.c_int64
+    lib.fs_fold_in.argtypes = [
+        c.c_void_p, c.POINTER(c.c_int64), c.c_char_p, c.c_int64,
+        c.POINTER(c.c_double), c.POINTER(c.c_double), c.POINTER(c.c_float),
+        c.c_int32, c.POINTER(c.c_float),
+    ]
     lib.fs_set_batch.argtypes = [
         c.c_void_p, c.POINTER(c.c_int64), c.c_char_p, c.c_int64,
         c.POINTER(c.c_float),

@@ -399,6 +399,82 @@ int64_t fs_get_batch(void* p, const int64_t* offs, const char* payload,
   return n;
 }
 
+// ALSUtils.computeTargetQui: the estimate an interaction of `value` asks
+// for, NaN for "no change".
+static double target_qui(bool implicit, double value, double current) {
+  if (!implicit) return value;
+  if (value > 0.0 && current < 1.0)
+    return current + (value / (1.0 + value)) * (1.0 - std::max(0.0, current));
+  if (value < 0.0 && current > 0.0)
+    return current + (value / (value - 1.0)) * -std::min(1.0, current);
+  return std::nan("");
+}
+
+// Fold a basket of items into a user vector in ONE call: the look-ups and
+// ALSUtils.computeUpdatedXu applied to each id found, in order, against
+// `inv` = (V^T V)^-1 (row-major [dim][dim], symmetric), starting from
+// `xu0` (null: a new user, whose first estimate is taken as 0.5). The
+// arithmetic of app/als/common.py compute_updated_xu_basket, in doubles:
+// every step's change is a multiple c_j of z_j = inv * y_j, so the vector
+// is xu0 + sum_j c_j z_j and a step's estimate is xu0 . y_j + sum_{i<j}
+// c_i (y_j . z_i). A serving thread that does this in numpy gives up the
+// interpreter lock four or five times a request and queues for it each
+// time; here it does so once. Returns the ids found, or -1 when none asked
+// for a change (`out` untouched).
+int64_t fs_fold_in(void* p, const int64_t* offs, const char* payload,
+                   int64_t n, const double* values, const double* inv,
+                   const float* xu0, int32_t implicit, float* out) {
+  auto* s = static_cast<Store*>(p);
+  const int64_t k = s->dim;
+  std::vector<double> ys, vals;
+  std::string key;
+  for (int64_t i = 0; i < n; ++i) {
+    key.assign(payload + offs[i], static_cast<size_t>(offs[i + 1] - offs[i]));
+    Shard& sh = s->shard_for(key);
+    std::shared_lock lock(sh.mu);
+    auto it = sh.index.find(key);
+    if (it == sh.index.end()) continue;
+    const float* row = sh.row(it->second, k);
+    ys.insert(ys.end(), row, row + k);
+    vals.push_back(values[i]);
+  }
+  const int64_t m = static_cast<int64_t>(vals.size());
+  std::vector<double> z(static_cast<size_t>(m * k)), c(static_cast<size_t>(m), 0.0);
+  bool started = xu0 != nullptr;
+  for (int64_t j = 0; j < m; ++j) {
+    const double* yj = ys.data() + j * k;
+    double* zj = z.data() + j * k;
+    // inv is symmetric: z_j as a sum of its rows, each scaled, so that the
+    // inner loop has no chain of dependent adds and vectorizes
+    for (int64_t q = 0; q < k; ++q) {
+      const double* row = inv + q * k;
+      const double w = yj[q];
+      for (int64_t r = 0; r < k; ++r) zj[r] += w * row[r];
+    }
+    double qui = 0.0;
+    if (xu0 != nullptr)
+      for (int64_t q = 0; q < k; ++q) qui += static_cast<double>(xu0[q]) * yj[q];
+    for (int64_t i = 0; i < j; ++i) {
+      if (c[i] == 0.0) continue;
+      const double* zi = z.data() + i * k;
+      double dot = 0.0;
+      for (int64_t q = 0; q < k; ++q) dot += yj[q] * zi[q];
+      qui += c[i] * dot;
+    }
+    const double target = target_qui(implicit != 0, vals[j], started ? qui : 0.5);
+    if (std::isnan(target)) continue;
+    c[j] = target - qui;
+    started = true;
+  }
+  if (!started) return -1;
+  for (int64_t q = 0; q < k; ++q) {
+    double acc = xu0 != nullptr ? static_cast<double>(xu0[q]) : 0.0;
+    for (int64_t j = 0; j < m; ++j) acc += c[j] * z[j * k + q];
+    out[q] = static_cast<float>(acc);
+  }
+  return m;
+}
+
 // Format n rows of float32 [n][k] as JSON number arrays "[v,v,...]" with
 // %.9g (shortest round-trip for float32 needs <= 9 significant digits).
 // Rows are written back-to-back; offsets[i]..offsets[i+1] bounds row i.

@@ -150,6 +150,30 @@ class NativeFeatureVectors:
         )
         return mat, valid.astype(bool)
 
+    def fold_in(self, ids: list[str], values, solver, xu, implicit: bool) -> np.ndarray | None:
+        """The vector ``xu`` (None: a new user) after an interaction of
+        strength ``values[j]`` with each of ``ids`` that is here, in turn,
+        against ``solver`` over V^T V of THESE vectors; None when nothing
+        asked for a change. Look-ups and recurrence in one native call
+        (``fs_fold_in``: the arithmetic of ``app/als/common.py``
+        ``compute_updated_xu_basket``, whose twin of this method the
+        Python store has)."""
+        if self._ptr is None or not ids:
+            return None
+        offs, payload = _offsets_payload(ids)
+        vals = np.ascontiguousarray(values, dtype=np.float64)
+        inv = solver.inverse
+        start = None if xu is None else np.ascontiguousarray(xu, dtype=np.float32)
+        out = np.empty(self._dim, dtype=np.float32)
+        as_float, as_double = ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_double)
+        found = self._lib.fs_fold_in(
+            self._ptr, _offsets_ptr(offs), payload, len(ids),
+            vals.ctypes.data_as(as_double), inv.ctypes.data_as(as_double),
+            None if start is None else start.ctypes.data_as(as_float),
+            int(bool(implicit)), out.ctypes.data_as(as_float),
+        )
+        return None if found < 0 else out
+
     def remove_vector(self, id_: str) -> None:
         if self._ptr is not None:
             key = id_.encode("utf-8")

@@ -481,6 +481,7 @@ class TopNBatcher:
         self._m_pass_rows = _metrics.counter("serving.batcher.pass.rows")
         self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
         self._m_pass_depth_sum = _metrics.counter("serving.batcher.pass.inflight-depth-sum")
+        self._m_pass_k_sum = _metrics.counter("serving.batcher.pass.k-bucket-sum")
         # counts the times the depth target takes a new value: the depth is
         # fixed, so it stays 0; registered so that a reader of it reads a
         # number and not nothing, as it would where the counter is missing
@@ -869,6 +870,7 @@ class TopNBatcher:
         self._m_pass_rows.inc(n)
         self._m_pass_padded_rows.inc(padded)
         self._m_pass_depth_sum.inc(inflight)
+        self._m_pass_k_sum.inc(kk)
         if self._hold_s:  # the first pass of a batch whose close was held
             self._m_held.inc()
             self._m_hold_seconds.observe(self._hold_s)

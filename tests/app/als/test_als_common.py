@@ -6,7 +6,12 @@ import numpy as np
 import pytest
 
 from oryx_tpu.app.als import data as als_data
-from oryx_tpu.app.als.common import FeatureVectors, compute_target_qui, compute_updated_xu
+from oryx_tpu.app.als.common import (
+    FeatureVectors,
+    compute_target_qui,
+    compute_updated_xu,
+    compute_updated_xu_basket,
+)
 from oryx_tpu.common.vectormath import Solver
 
 
@@ -109,3 +114,77 @@ def test_to_rating_matrix_and_known_items():
     assert rm.user_ids == ["u1", "u2"]
     assert rm.item_ids == ["i1", "i2"]
     assert rm.known_items == {"u1": {"i1", "i2"}, "u2": {"i1"}}
+
+
+@pytest.mark.parametrize("implicit", [True, False])
+@pytest.mark.parametrize("known_user", [False, True])
+def test_the_basket_form_is_the_recurrence_item_by_item(implicit, known_user):
+    """One pass of matrix products for a whole basket against
+    `compute_updated_xu` applied to each item in turn: strengths other than
+    1, a negative one, one that asks no change (implicit 0), and a start
+    from a known user's vector or from none."""
+    rng = np.random.default_rng(7)
+    y = rng.standard_normal((4000, 24)).astype(np.float32)
+    solver = Solver(y.astype(np.float64).T @ y.astype(np.float64))
+    x0 = (1e-3 * rng.standard_normal(24)).astype(np.float32) if known_user else None
+    rows, values = [3, 77, 1500, 9, 2000, 77], [1.0, 2.5, -0.5, 0.0, 1.0, 3.0]
+    xu = x0
+    for row, value in zip(rows, values):
+        updated = compute_updated_xu(solver, value, xu, y[row], implicit)
+        xu = xu if updated is None else updated
+    got = compute_updated_xu_basket(solver, values, x0, y[rows], implicit)
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got, xu, rtol=0, atol=5e-7 * np.abs(xu).max())
+    for k in range(1, len(rows)):  # and every prefix of it
+        want = x0
+        for row, value in zip(rows[:k], values[:k]):
+            updated = compute_updated_xu(solver, value, want, y[row], implicit)
+            want = want if updated is None else updated
+        got = compute_updated_xu_basket(solver, values[:k], x0, y[rows[:k]], implicit)
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-7 * np.abs(want).max())
+
+
+def test_a_basket_that_asks_no_change_gives_none():
+    solver = Solver(np.array([[2.0, 0.0], [0.0, 2.0]]))
+    ys = np.array([[1.0, 0.0], [0.0, 1.0]], dtype=np.float32)
+    assert compute_updated_xu_basket(solver, [0.0, 0.0], None, ys, True) is None
+    assert compute_updated_xu_basket(solver, [], None, ys[:0], True) is None
+    # a first item that asks nothing leaves the new user's prior to the next
+    got = compute_updated_xu_basket(solver, [0.0, 1.0], None, ys, True)
+    np.testing.assert_allclose(got, compute_updated_xu(solver, 1.0, None, ys[1], True))
+
+
+@pytest.mark.parametrize("implicit", [True, False])
+def test_both_stores_fold_a_basket_in_alike(implicit):
+    """`fold_in` of the store that serves (the native one where the library
+    is built: look-ups and recurrence in one call) against the Python
+    store's (`compute_updated_xu_basket`), an unknown id among the ids, from
+    a known user's vector and from none; and against the recurrence item by
+    item."""
+    from oryx_tpu.native.store import make_feature_vectors
+
+    rng = np.random.default_rng(11)
+    y = rng.standard_normal((3000, 50)).astype(np.float32)
+    ids = [f"i{i}" for i in range(len(y))]
+    serving, plain = make_feature_vectors(), FeatureVectors()
+    serving.set_batch(ids, y)
+    plain.set_batch(ids, y)
+    solver = Solver(y.astype(np.float64).T @ y.astype(np.float64))
+    asked = ["i3", "nope", "i77", "i1500", "i9", "i2000", "i77"]
+    values = [1.0, 9.0, 2.5, -0.5, 0.0, 1.0, 3.0]
+    for x0 in (None, (1e-3 * rng.standard_normal(50)).astype(np.float32)):
+        got = serving.fold_in(asked, values, solver, x0, implicit)
+        want = plain.fold_in(asked, values, solver, x0, implicit)
+        assert got.dtype == want.dtype == np.float32 and got.shape == (50,)
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-7 * np.abs(want).max())
+        xu = x0
+        for item, value in zip(asked, values):
+            updated = compute_updated_xu(solver, value, xu, plain.get_vector(item), implicit)
+            xu = xu if updated is None else updated
+        np.testing.assert_allclose(got, xu, rtol=0, atol=5e-7 * np.abs(xu).max())
+    for store in (serving, plain):
+        assert store.fold_in(["nope"], [1.0], solver, None, implicit) is None
+        assert store.fold_in([], [], solver, None, implicit) is None
+    if implicit:  # a strength of 0 asks for no change
+        assert serving.fold_in(["i3"], [0.0], solver, None, True) is None
+    assert make_feature_vectors().fold_in(["i3"], [1.0], solver, None, implicit) is None  # empty

@@ -74,6 +74,67 @@ def test_yty_solver_invalidated_on_write():
     assert m.get_yty_solver() is not s1
 
 
+def _counts():
+    from oryx_tpu.common import metrics
+
+    snap = metrics.registry.snapshot()
+    return {
+        "device": snap["serving.yty.builds.device"]["value"],
+        "host": snap["serving.yty.builds.host"]["value"],
+        "seconds": snap["serving.yty.build.seconds"].get("count", 0),
+        "foldin": snap["serving.foldin.requests"]["value"],
+        "items": snap["serving.foldin.items"]["value"],
+        "foldin_seconds": snap["serving.foldin.seconds"].get("count", 0),
+    }
+
+
+def test_yty_comes_from_the_device_matrix_and_is_rebuilt_from_the_refreshed_one(monkeypatch):
+    """`YtY` is the Gram matrix of the device copy (not of the store), and
+    a write to `Y` rebuilds it from that copy REFRESHED with the write,
+    whatever the refresh interval says: one build, one count, each time."""
+    m = build_model(refresh_sec=3600.0)  # the copy would otherwise wait an hour
+    monkeypatch.setattr(
+        m.y, "get_vtv", lambda: pytest.fail("the host store's loop ran beside a device copy")
+    )
+    before = _counts()
+    s1 = m.get_yty_solver()
+    y = np.asarray(list(ITEM_VECS.values()), dtype=np.float64)
+    np.testing.assert_allclose(s1.matrix, y.T @ y, atol=1e-6)
+    assert m.get_yty_solver() is s1
+    now = _counts()
+    assert now["device"] - before["device"] == 1 and now["host"] == before["host"]
+    assert now["seconds"] - before["seconds"] == 1
+    # a write: a new item and a rewritten one
+    m.set_item_vectors(["I9", "I0"], np.asarray([[0.3, -2.0], [4.0, 0.0]], dtype=np.float32))
+    s2 = m.get_yty_solver()
+    assert s2 is not s1
+    y2 = np.asarray([[4.0, 0.0], [0.0, 1.0], [0.9, 0.1], [0.5, 0.5], [0.3, -2.0]])
+    np.testing.assert_allclose(s2.matrix, y2.T @ y2, atol=1e-5)
+    assert _counts()["device"] - before["device"] == 2
+    # and the scan reads the same refreshed copy
+    assert m.top_n(np.asarray([0.0, -1.0], dtype=np.float32), 1)[0][0] == "I9"
+    # a rotation drops it too
+    m.retain_recent_and_item_ids({"I0", "I1", "I2", "I3", "I9"})
+    assert m.get_yty_solver() is not s2
+
+
+def test_a_model_with_no_usable_device_copy_answers_by_the_host_store():
+    """An int8 item matrix holds codes, not the rows: `YtY` stays the host
+    store's double-precision loop, counted as such; and a model with no
+    items has no solver."""
+    assert ALSServingModel(2, implicit=True).get_yty_solver() is None
+    m = ALSServingModel(2, implicit=True, refresh_sec=0.0, score_dtype="int8")
+    for i, v in ITEM_VECS.items():
+        m.set_item_vector(i, np.asarray(v, dtype=np.float32))
+    before = _counts()
+    solver = m.get_yty_solver()
+    y = np.asarray(list(ITEM_VECS.values()), dtype=np.float64)
+    np.testing.assert_allclose(solver.matrix, y.T @ y, atol=1e-12)
+    now = _counts()
+    assert now["host"] - before["host"] == 1 and now["device"] == before["device"]
+    assert now["seconds"] - before["seconds"] == 1
+
+
 # ---------------------------------------------------------------------------
 # manager consume protocol
 # ---------------------------------------------------------------------------
@@ -195,6 +256,32 @@ def test_recommend_to_many_and_anonymous(server):
     assert status == 200
     assert all(r["id"] not in ("I0", "I2") for r in recs)
     assert get_json(base, "/recommendToAnonymous/NOPE")[0] == 400
+
+
+def test_the_fold_in_s_instruments_are_fed_once_a_request_and_once_a_build(server):
+    """Every endpoint that folds items into a user vector observes once a
+    request: its seconds, itself, and the items its URL named (an unknown
+    one included); the solver is built once for all of them."""
+    base, _ = server
+    get_json(base, "/recommendToAnonymous/I0")  # whoever is first builds the solver
+    before = _counts()
+    for path, items in [
+        ("/recommendToAnonymous/I0=2.0/I2", 2),
+        ("/recommendToAnonymous/I1/NOPE/I3", 3),
+        ("/estimateForAnonymous/I2/I0=1.0", 1),
+        ("/recommendWithContext/U0/I1/I3", 2),
+    ]:
+        assert get_json(base, path)[0] == 200, path
+        now = _counts()
+        assert now["foldin"] - before["foldin"] == 1, path
+        assert now["foldin_seconds"] - before["foldin_seconds"] == 1, path
+        assert now["items"] - before["items"] == items, path
+        assert (now["device"], now["host"], now["seconds"]) == (
+            before["device"], before["host"], before["seconds"]), path
+        before = now
+    # a request that does not fold in feeds none of them
+    assert get_json(base, "/recommend/U0")[0] == 200
+    assert _counts() == before
 
 
 def test_similarity_family(server):

@@ -40,6 +40,55 @@ def test_solver_rejects_singular():
     assert ei.value.apparent_rank == 1
 
 
+@pytest.mark.parametrize("features, rows, seed", [(2, 10, 0), (50, 4000, 1), (250, 3000, 2)])
+def test_solver_by_its_stored_inverse_equals_a_direct_solve(features, rows, seed):
+    """The solver applies a stored float64 inverse (with one refinement
+    step) where it used to hand LAPACK the factor for every right-hand
+    side: the answers are `np.linalg.solve`'s on the full matrix."""
+    rng = np.random.default_rng(seed)
+    v = rng.standard_normal((rows, features))
+    a = v.T @ v
+    solver = vm.Solver(a)
+    assert np.array_equal(solver.matrix, a)
+    for _ in range(5):
+        b = rng.standard_normal(features)
+        want = np.linalg.solve(a, b)
+        got = solver.solve_d_to_d(b)
+        assert got.dtype == np.float64
+        assert np.max(np.abs(got - want)) <= 1e-12 * np.max(np.abs(want))
+        as_float = solver.solve_f_to_f(b.astype(np.float32))
+        assert as_float.dtype == np.float32
+        np.testing.assert_allclose(as_float, np.linalg.solve(a, b.astype(np.float32)), rtol=1e-5)
+
+
+def test_solver_near_its_singularity_threshold_still_solves():
+    """Condition 1e4 passes the rank check (threshold 1e-5 of the largest
+    diagonal of R): the refinement step keeps a stored inverse at a direct
+    solve's accuracy there too."""
+    rng = np.random.default_rng(3)
+    q, _ = np.linalg.qr(rng.standard_normal((40, 40)))
+    a = q @ np.diag(np.logspace(0, -4, 40)) @ q.T
+    a = (a + a.T) / 2
+    solver = vm.Solver(a)
+    b = rng.standard_normal(40)
+    want = np.linalg.solve(a, b)
+    assert np.max(np.abs(solver.solve_d_to_d(b) - want)) <= 1e-10 * np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("features", [3, 50])
+def test_solver_still_raises_on_a_rank_deficient_matrix(features):
+    rng = np.random.default_rng(4)
+    v = rng.standard_normal((200, features))
+    v[:, -1] = v[:, 0] + v[:, 1]  # one column is the sum of two others
+    with pytest.raises(vm.SingularMatrixSolverException) as ei:
+        vm.Solver(v.T @ v)
+    assert ei.value.apparent_rank == features - 1
+    with pytest.raises(vm.SingularMatrixSolverException) as ei:
+        vm.Solver(np.zeros((features, features)))
+    assert ei.value.apparent_rank == 0
+    assert vm.get_solver(None) is None
+
+
 def test_collect_in_parallel_ordered():
     out = lang.collect_in_parallel(10, lambda i: i * i, parallelism=4)
     assert out == [i * i for i in range(10)]

@@ -6,7 +6,10 @@ here are the other item dtypes (bfloat16; int8 two-plane, whose kernel keeps
 widest scan group (256 rows), at 50 and at 250 features; and the VECTOR
 submit's program as it is served (`_streaming_topk_multi`: the query block
 an operand, not rows of a staged matrix; `/similarity`, anonymous users,
-several users at once), cosine and dot. What the chip's
+several users at once), cosine and dot, at the k bucket 16 and, for the
+anonymous-visitor cell's baskets of 7-8 items, dot at 32; and the Gram
+program of the same device matrix (`ops/gram.py` `oryx_gram`, the fold-in's
+`YtY`). What the chip's
 compiler would refuse (VMEM, tiling, the lane roll of the running top-k)
 is refused here. A compile that passes is not a
 chip run and says nothing about time.
@@ -267,3 +270,121 @@ def test_counting_scan_compiles_for_the_v5e(one_chip, no_persistent_cache):
     )
     assert "tpu_custom_call" in lowered.compile().as_text()
     assert [o.shape for o in lowered.out_info] == [(batch, 32), (batch, 32), (1, 2)]
+
+
+# The anonymous-visitor cell (PR 40): baskets of 7-8 items ask for howMany + 8
+# candidates, the k bucket 32 of the vector DOT program. name: batch rows
+ANON_K32_CASES = {f"vector-250f-b{b}-dot-k32": b for b in (8, 32, 128)}
+
+
+@pytest.mark.parametrize("case", ANON_K32_CASES)
+def test_vector_dot_program_at_k_bucket_32_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
+    import jax.numpy as jnp
+
+    from oryx_tpu.ops import pallas_topn
+
+    features, batch, k = 250, ANON_K32_CASES[case], 32
+    items = SHAPES[features]
+    n_pad = pallas_topn._ceil_to(items, pallas_topn.BLOCK_N)
+    tail = pallas_topn.tail_rows(features, jnp.float32)
+    shape = functools.partial(_shape, one_chip)
+    lowered = pallas_topn._streaming_topk_multi.lower(
+        shape((features - tail, n_pad), jnp.float32), shape((1, n_pad), jnp.float32),
+        None, None, None, shape((1, batch, features), jnp.float32),
+        k=k, n_items=items, cosine=False, interpret=False, download_dtype=None,
+        tail=shape((tail, n_pad), jnp.float32),
+    )
+    compiled = lowered.compile()  # raises what the chip's compiler would raise
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%oryx_topn_scan" in text
+    (vals, idxs) = lowered.out_info
+    assert vals.shape == idxs.shape == (1, batch, k)
+
+
+# name: (features, items, item dtype)
+GRAM_CASES = {
+    "gram-250f-5m": (250, 5_000_000, "float32"),
+    "gram-50f-20m": (50, 20_000_000, "float32"),
+    "gram-250f-5m-bfloat16": (250, 5_000_000, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", GRAM_CASES)
+def test_gram_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
+    """`oryx_gram` over the planes as they are served (`[250, 5013504]` as a
+    main plane of 248 rows and a tail plane of 2): one `[f, f]` float32
+    partial a block of 16384 columns, nothing of the matrix copied (the
+    program's temporaries are a block's, not the matrix's), float32 products
+    at the highest precision."""
+    import jax.numpy as jnp
+
+    from oryx_tpu.ops import gram, pallas_topn
+
+    features, items, dtype = GRAM_CASES[case]
+    n_pad = pallas_topn._ceil_to(items, pallas_topn.BLOCK_N)
+    tail = pallas_topn.tail_rows(features, jnp.dtype(dtype))
+    shape = functools.partial(_shape, one_chip)
+    n_blocks = n_pad // gram.GRAM_BLOCK
+    lowered = gram.oryx_gram.lower(
+        shape((features - tail, n_pad), jnp.dtype(dtype)),
+        shape((tail, n_pad), jnp.float32) if tail else None,
+        shape((), jnp.int32), n_blocks=n_blocks,
+    )
+    compiled = lowered.compile()  # raises what the chip's compiler would raise
+    assert lowered.out_info.shape == (n_blocks, features, features)
+    assert lowered.out_info.dtype == jnp.float32
+    assert "operand_precision={highest,highest}" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    planes = features * n_pad * jnp.dtype(dtype).itemsize
+    # (a bfloat16 plane of 250 rows is held in 16-row tiles: 256)
+    assert planes <= mem.argument_size_in_bytes < planes * 1.03
+    # the partials, each tiled to whole (8, 128) vregs at the most
+    tiled = pallas_topn._ceil_to(features, 8) * pallas_topn._ceil_to(features, 128)
+    assert n_blocks * features * features * 4 <= mem.output_size_in_bytes <= n_blocks * tiled * 4
+    assert mem.temp_size_in_bytes < 64 * 2**20  # a block and its product, never a plane
+
+
+def test_sharded_gram_program_compiles_for_a_v5e_host(no_persistent_cache):
+    """`oryx_gram` under `shard_map` over the four-chip configuration's
+    matrix (20M x 250 float32, each chip its `[248, cols]` main and `[2,
+    cols]` tail plane): every chip its own blocks' partials, masked by its
+    own count, and nothing crosses a chip."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from oryx_tpu.ops import gram, pallas_topn
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe it is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    d, f = 4, 250
+    cols = pallas_topn._ceil_to(SHAPES[f], pallas_topn.BLOCK_N)
+    n_blocks = cols // gram.GRAM_BLOCK
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    lowered = gram._sharded_gram_fn(mesh, n_blocks, True).lower(
+        shape((f - 2, d * cols), jnp.float32, P(None, "data")),
+        (shape((2, d * cols), jnp.float32, P(None, "data")),),
+        shape((d,), jnp.int32, P("data")),
+    )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "operand_precision={highest,highest}" in text
+    assert not any(c in text for c in ("all-gather", "all-reduce", "all-to-all", "collective-permute"))
+    assert lowered.out_info.shape == (d * n_blocks, f, f) and lowered.out_info.dtype == jnp.float32
+    mem = compiled.memory_analysis()  # a device's own: its two planes in, its blocks' partials out
+    planes = f * cols * 4
+    assert planes <= mem.argument_size_in_bytes < planes * 1.03
+    tiled = pallas_topn._ceil_to(f, 8) * pallas_topn._ceil_to(f, 128)
+    assert n_blocks * f * f * 4 <= mem.output_size_in_bytes <= n_blocks * tiled * 4
+    assert mem.temp_size_in_bytes < 64 * 2**20

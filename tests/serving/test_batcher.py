@@ -609,8 +609,9 @@ def test_an_explicit_max_inflight_pins_the_depth(stub, depth):
 
 
 def test_the_cap_changes_counter_is_there_and_reads_zero():
-    """`inflight_cap_changes.open/.x4` (benchmark/layer_metrics) read the
-    counter's delta: a missing counter reads nothing, not 0."""
+    """An operator counter since PR 39 pruned the per-layer metric that
+    read it (the depth is fixed since PR 28, so it can only read 0): the
+    handle is taken at start, so the counter is in every snapshot."""
     b = TopNBatcher()
     try:
         snap = batcher_mod._metrics.snapshot()

@@ -26,7 +26,12 @@ What is checked (ISSUE 21 item 5):
 - the fused Pallas k-means sweep, full and mini-batch, at d = 250 with k
   at the ``fits_vmem`` edge;
 - the IVF device probe at >= 1M items;
-- the device fold-in program against the float64 host fold.
+- the device fold-in program against the float64 host fold;
+- the Gram pass over the uploaded item matrix (ops/gram.py ``oryx_gram``,
+  the anonymous fold-in's ``YtY``) against float64 NumPy of the rows the
+  handle holds: the streaming layout in f32 (both widths) and bf16, and
+  the mesh-sharded layout on whatever devices exist, each to ``GRAM_TOL``
+  of the largest entry.
 
 Tolerances. A result row is (ids, scores). Against exact f32 scores S of
 the SAME original matrix, a check demands (a) every returned score within
@@ -75,6 +80,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 TOL = {"float32": 1e-5, "bfloat16": 1e-2, "int8": 2.5e-4}
 MIN_RECALL = {"float32": 1.0, "bfloat16": 1.0, "int8": 0.99}
+# largest |YtY - float64 reference| over the largest entry: what the fold-in's
+# scores can bear under the benchmark's 1e-5 of scale (docs/serving-scan.md);
+# bfloat16 against the rows as the format holds them, with the room the CPU
+# rehearsal needs (its dot of converted operands reads 1.1e-6 over one block)
+GRAM_TOL = {"float32": 1e-6, "bfloat16": 1e-5}
 
 
 class CheckFailed(Exception):
@@ -199,6 +209,8 @@ class Checks:
         self.kmeans_checks()
         self.ivf_checks()
         self.fold_check()
+        for features in (250, 50):
+            self.gram_checks(features)
 
     # -- streaming scan ------------------------------------------------------
 
@@ -539,6 +551,69 @@ class Checks:
             return compare_folds(dev, host)
 
         self.check(f"fold-in/{kf}f/device-vs-host", fold)
+
+
+    # -- Gram matrix of the item matrix -----------------------------------------
+
+    def gram_checks(self, features: int) -> None:
+        import jax.numpy as jnp
+
+        from oryx_tpu.ops import gram as gram_ops
+        from oryx_tpu.ops import pallas_topn as pt
+        from oryx_tpu.ops import topn as topn_ops
+        from oryx_tpu.parallel.mesh import get_mesh
+
+        prefix = f"gram/{features}f"
+        if self.only and not any(
+            prefix.startswith(p) or p.startswith(prefix) for p in self.only
+        ):
+            return  # no matrix drawn for checks that will not run
+        # not a whole number of blocks, on one chip or a shard: the last
+        # block of each is part padding, which the pass has to leave out
+        n = self.n_items + 1234
+        mat = self.gen.standard_normal((n, features), dtype=np.float32)
+
+        def run(upload, rows, tol=GRAM_TOL["float32"]):
+            up = upload()
+            expect(gram_ops.supported(up), "a handle the Gram pass refuses")
+            got = gram_ops.gram(up)  # compiles
+            t0 = time.perf_counter()
+            again = gram_ops.gram(up)  # the pass, the partials' download, their float64 sum
+            pass_s = time.perf_counter() - t0
+            want = float64_gram(rows)
+            err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+            expect(np.array_equal(got, again), "two passes over one handle differ")
+            expect(err < tol, f"{err:.3g} of the largest entry >= {tol}")
+            return {"err_of_largest": err, "pass_s": round(pass_s, 4), **gram_ops.pass_stats(up)}
+
+        self.check(f"{prefix}/float32", lambda: run(lambda: pt.upload_streaming(mat), mat))
+        if features == 250:
+            # against the rows as bfloat16 holds them: the format's rounding
+            # of a row is the handle's, not the pass's
+            held = np.asarray(jnp.asarray(mat).astype(jnp.bfloat16).astype(jnp.float32))
+            self.check(
+                f"{prefix}/bfloat16",
+                lambda: run(
+                    lambda: pt.upload_streaming(mat, dtype=jnp.bfloat16), held, GRAM_TOL["bfloat16"]
+                ),
+            )
+
+        def sharded():
+            mesh = get_mesh()
+            row = run(lambda: topn_ops.upload_sharded(mat, mesh, dtype=jnp.float32), mat)
+            return {**row, "devices": int(mesh.devices.size)}
+
+        self.check(f"{prefix}/float32/sharded", sharded)
+
+
+def float64_gram(rows: np.ndarray, block: int = 1 << 16) -> np.ndarray:
+    """``rows^T rows`` with every product and sum in float64, a block of
+    rows at a time (the whole matrix in float64 would be twice its size)."""
+    total = np.zeros((rows.shape[1], rows.shape[1]), dtype=np.float64)
+    for lo in range(0, rows.shape[0], block):
+        part = rows[lo : lo + block].astype(np.float64)
+        total += part.T @ part
+    return total
 
 
 def compare_folds(dev, host) -> dict:

@@ -13,7 +13,9 @@ and in thread-CPU time (`serving.front.*`, `serving.handler.*`,
 `serving.batcher.entry` / `wake` / `submit.device-call`, the
 dispatcher's and the completer's CPU a pass,
 the process's CPU as cores busy: which stage grows towards the knee),
-and, with
+for a cell that folds in (`/recommendToAnonymous`) the fold-in's mean,
+its items a request and the mean k bucket of a pass (whose knee is it:
+the host fold-in's, or the device's), and, with
 `--trace 1`, the device's idle share and the scan kernel's ms a pass from
 a profiler recording of the 4 s after the window at the same load; with
 `--raw`, every request's due time and latency of every window. A tool
@@ -67,6 +69,7 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
     passes = max(d("serving.batcher.passes"), 1.0)
     queries = max(d("serving.scan.indexed.queries") + d("serving.scan.vector.queries"), 1.0)
     held = d("serving.batcher.pass.held")  # 0 on a program without the later close
+    k_sum = d("serving.batcher.pass.k-bucket-sum")  # 0 on a program without the counter
     row = {
         "clients" if closed else "rate_per_s": rate,
         "seconds": seconds,
@@ -94,6 +97,14 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
         "indexed_pct": 100.0 * d("serving.scan.indexed.queries") / queries,
         "cosine_pct": 100.0 * d("serving.scan.cosine.queries") / queries,
         "submit_mean_ms": mean_ms("serving.batcher.submit.seconds"),
+        # the fold-in of a request without a user row (endpoints._fold_in), and the k
+        # bucket its baskets give a pass: a pass runs at its largest entry's bucket,
+        # so with buckets 16 and 32 alone (mean - 16) / 16 is the share of passes at 32
+        "foldin_mean_ms": mean_ms("serving.foldin.seconds"),
+        "foldin_items_per_request": d("serving.foldin.items")
+        / max(d("serving.foldin.requests"), 1.0),
+        "k_bucket_mean": k_sum / passes if k_sum else None,
+        "k32_pass_pct": 100.0 * (k_sum / passes - 16.0) / 16.0 if k_sum else None,
         # the host path, stage by stage (serving/stages.py): a request's
         # wall time in order, then a pass's, then the CPU beside them
         "front_native": (after.get("serving.front.native") or {}).get("value"),
