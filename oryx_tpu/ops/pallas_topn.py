@@ -540,6 +540,30 @@ def _rescore_topk(vals, idxs, q, qn, resid, resid_scales, norms, *, k, cosine):
     return v, jnp.take_along_axis(ii, pos, axis=1)
 
 
+def pack_hits(vals, idxs, download_dtype=None):
+    """What a scan program hands back for its float32 ``vals`` and int32
+    ``idxs``, both [K, b, k]: ONE int32 array [K, b, 2k], the scores' bit
+    patterns then the ids, so that a pass is one download (the same 8 B a
+    hit; the bit-cast loses nothing). Where the scores travel narrower
+    (``download_dtype``: the bf16 / int8 handles' 6 B a hit) the two
+    arrays have no common width and stay a pair. ``split_hits`` undoes
+    either."""
+    if download_dtype is not None:
+        return vals.astype(download_dtype), idxs
+    return jnp.concatenate([jax.lax.bitcast_convert_type(vals, jnp.int32), idxs], axis=-1)
+
+
+def split_hits(hits):
+    """(scores [..., k], ids [..., k]) of what a scan program handed back
+    (``pack_hits``), on the device or, for a NumPy array, as views."""
+    if isinstance(hits, tuple):
+        return hits
+    k = hits.shape[-1] // 2
+    if isinstance(hits, np.ndarray):
+        return hits[..., :k].view(np.float32), hits[..., k:]
+    return jax.lax.bitcast_convert_type(hits[..., :k], jnp.float32), hits[..., k:]
+
+
 @functools.partial(
     jax.jit, static_argnames=("k", "n_items", "cosine", "interpret", "download_dtype")
 )
@@ -551,9 +575,10 @@ def _streaming_topk_multi(
     sequentially over [K, b, feat] query groups inside a single jitted
     program. Host dispatch and the device round-trip are paid once per K
     scans instead of once per scan.
-    Returns (vals [K, b, k], idxs [K, b, k]); ``download_dtype`` rounds
-    the returned scores (selection itself always runs in f32) so a
-    result-byte-bound link ships 6 B/hit instead of 8."""
+    Returns ``pack_hits`` of (vals [K, b, k], idxs [K, b, k]): one array,
+    or, where ``download_dtype`` rounds the returned scores (selection
+    itself always runs in f32) so that a result-byte-bound link ships
+    6 B/hit instead of 8, the pair."""
 
     def one(q):
         return _streaming_topk_impl(
@@ -562,9 +587,7 @@ def _streaming_topk_multi(
         )
 
     vals, idxs = jax.lax.map(one, queries_kb)
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
+    return pack_hits(vals, idxs, download_dtype)
 
 
 # Scoped VMEM the kernel asks Mosaic for. A v5e core has 128 MiB; the
@@ -980,9 +1003,7 @@ def _xla_streaming_topk_multi(
         mat_t, norms, scales, resid, resid_scales, queries_kb,
         k=k, n_items=n_items, cosine=cosine, tail=tail,
     )
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
+    return pack_hits(vals, idxs, download_dtype)
 
 
 @functools.partial(
@@ -997,9 +1018,7 @@ def _xla_streaming_topk_multi_indexed(
         x_dev[idx_kb].astype(jnp.float32),
         k=k, n_items=n_items, cosine=cosine, tail=tail,
     )
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
+    return pack_hits(vals, idxs, download_dtype)
 
 
 def _use_xla_scan(interpret) -> bool:
@@ -1029,9 +1048,7 @@ def _streaming_topk_multi_indexed(
         )
 
     vals, idxs = jax.lax.map(one, idx_kb)
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
+    return pack_hits(vals, idxs, download_dtype)
 
 
 def group_rows(rows: np.ndarray, scan_batch: int = MAX_GROUP_ROWS) -> np.ndarray:
@@ -1048,19 +1065,22 @@ def group_rows(rows: np.ndarray, scan_batch: int = MAX_GROUP_ROWS) -> np.ndarray
 
 def scan_groups(
     up: StreamingItemMatrix,
-    groups: jax.Array,
+    groups: np.ndarray | jax.Array,
     k: int,
     cosine: bool = False,
     interpret: bool | None = None,
     download_dtype=None,
     x_dev: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """(scores [K, b, k], indices [K, b, k]) as device arrays, K scans of
-    the whole matrix in one dispatch: the one entry of the exact scan.
+) -> jax.Array | tuple[jax.Array, jax.Array]:
+    """``pack_hits`` of (scores [K, b, k], indices [K, b, k]) on the
+    device (``split_hits`` gives the two), K scans of the whole matrix in
+    one dispatch: the one entry of the exact scan.
     ``groups`` is [K, b, feat] query vectors or, with ``x_dev`` (a query
     matrix staged on the device), [K, b] int32 rows of it, so that the
-    uplink carries 4 B a query. ``interpret=None`` picks per backend: the
-    compiled kernel on TPU, the fused XLA blocked scan elsewhere."""
+    uplink carries 4 B a query; a NumPy array goes in as it is (the
+    jitted call transfers it: no ``device_put`` of the caller's).
+    ``interpret=None`` picks per backend: the compiled kernel on TPU, the
+    fused XLA blocked scan elsewhere."""
     planes = (up.mat_t, up.norms, up.scales, up.resid, up.resid_scales)
     queries = (groups,) if x_dev is None else (x_dev, groups)
     shared = dict(
@@ -1085,10 +1105,10 @@ def top_k_streaming_device(
     """(scores [b, k], indices [b, k]) as device arrays for [b, feat]
     query vectors (tests and tools; serving submits through ops/topn.py)."""
     q = np.atleast_2d(np.asarray(queries, dtype=np.float32))
-    vals, idxs = scan_groups(
-        up, jnp.asarray(group_rows(q)), k, cosine=cosine, interpret=interpret,
+    vals, idxs = split_hits(scan_groups(
+        up, group_rows(q), k, cosine=cosine, interpret=interpret,
         download_dtype=download_dtype,
-    )
+    ))
     kk = vals.shape[-1]
     return vals.reshape(-1, kk)[: len(q)], idxs.reshape(-1, kk)[: len(q)]
 

@@ -21,7 +21,10 @@ into groups of at most 256 and walks the kinds once:
 ``submit_top_k`` (query vectors) and ``submit_top_k_multi_indexed`` (rows
 of a query matrix staged on the device) enqueue the device computation
 and a non-blocking device→host copy, returning a :class:`TopNHandle`
-whose ``result()`` materializes the answer. Callers that keep several
+whose ``result()`` materializes the answer. A pass is ONE dispatch and
+ONE download: the row groups go into the jitted program as the NumPy
+array they are, and float32 scores come back in one int32 array with
+their ids (``pallas_topn.pack_hits``). Callers that keep several
 requests in flight (the serving batcher) overlap device compute and
 host<->device transfers instead of paying a full round-trip per request.
 ``top_k_scores`` / ``top_k_scores_batch`` are the blocking forms for
@@ -49,8 +52,10 @@ from oryx_tpu.ops.pallas_topn import (
     _quantize_rows,
     group_rows,
     note_feature_rows,
+    pack_hits,
     scan_groups,
     split_features,
+    split_hits,
     tail_rows,
     upload_streaming,
 )
@@ -116,12 +121,11 @@ def _plain_topk_groups(mat, norms, x_dev, groups, k, cosine, download_dtype):
     """The plain pair's twin of the fused multi-scan: lax.map over query
     groups keeps peak memory at one [b, n] score block instead of
     [K*b, n]. ``groups`` is [K, b, feat] vectors or, with ``x_dev``,
-    [K, b] rows of it, gathered on the device."""
+    [K, b] rows of it, gathered on the device. Returns ``pack_hits`` of
+    (vals, idxs), as the fused scan does."""
     q_kb = (groups if x_dev is None else x_dev[groups]).astype(mat.dtype)
     vals, idxs = jax.lax.map(lambda q: _dot_topk_batch(mat, norms, q, k, cosine), q_kb)
-    if download_dtype is not None:
-        vals = vals.astype(download_dtype)
-    return vals, idxs
+    return pack_hits(vals, idxs, download_dtype)
 
 
 # -- mesh-sharded scan --------------------------------------------------------
@@ -344,25 +348,36 @@ def _sharded_scan_fn(
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _packed(scan):
+    """``scan`` (a jitted program that returns float32 scores and their
+    ids) with ``pack_hits`` jitted around it: still one program and one
+    dispatch, whose one output is the array a pass downloads."""
+    def packed_scan(*operands):
+        return pack_hits(*scan(*operands))
+
+    return jax.jit(packed_scan)
+
+
 def _submit_sharded(up: ShardedItemMatrix, groups: np.ndarray, k: int, cosine: bool, x_dev=None):
     """Enqueue one sharded pass for ``groups`` ([K, b, feat] query rows,
-    or [K, b] int32 rows of ``x_dev``); returns device (vals, idxs)
-    [K, b, k], replicated."""
-    from oryx_tpu.parallel.mesh import replicated
-
+    or [K, b] int32 rows of ``x_dev``; NumPy, which the program's
+    replicated in-spec places on every device inside the call); returns
+    ``pack_hits`` of the device (vals, idxs) [K, b, k], replicated."""
     k = max(1, min(int(k), up.n_items))
     quantized = up.scales is not None
+    download = _auto_download_dtype(up)
     fn = _sharded_scan_fn(
-        up.mesh, k, bool(cosine), quantized, x_dev is not None, _auto_download_dtype(up),
+        up.mesh, k, bool(cosine), quantized, x_dev is not None, download,
         tailed=up.tail is not None,
     )
+    if download is None:
+        fn = _packed(fn)
     return fn(
         up.mat_t, up.norms,
         (up.scales, up.resid, up.resid_scales) if quantized
         else () if up.tail is None else (up.tail,),
-        up.base, up.valid,
-        jax.device_put(groups, replicated(up.mesh)),
-        x_dev if x_dev is not None else (),
+        up.base, up.valid, groups, x_dev if x_dev is not None else (),
     )
 
 
@@ -569,11 +584,20 @@ class TopNHandle:
     (indices [n, k], scores [n, k]) as numpy arrays for the n query rows
     submitted, whatever groups and padding the device was given."""
 
-    _vals: jax.Array  # [..., k]
-    _idxs: jax.Array
+    _vals: jax.Array  # [..., k] scores; or, packed, [..., 2k] int32 (``pack_hits``)
+    _idxs: jax.Array | None  # [..., k] ids; None where ``_vals`` holds them too
     _n: int
 
+    @property
+    def packed(self) -> bool:
+        """Whether the pass comes back as one array: one fetch."""
+        return self._idxs is None
+
     def result(self) -> tuple[np.ndarray, np.ndarray]:
+        if self.packed:
+            hits = np.asarray(self._vals)
+            vals, idxs = split_hits(hits.reshape(-1, hits.shape[-1])[: self._n])
+            return idxs, vals
         k = self._vals.shape[-1]
         idxs = np.asarray(self._idxs).reshape(-1, k)[: self._n]
         # scores may travel as bf16 (download_dtype); callers always see f32
@@ -599,40 +623,44 @@ def _submit(
 ) -> TopNHandle:
     """Enqueue the top-k of ``rows`` ([n, feat] float32 query vectors or,
     with ``x_dev``, [n] int32 rows of that staged query matrix) against
-    any handle kind, and the device→host copies behind it, without
+    any handle kind, and the device→host copy behind it, without
     blocking. Every kind but IVF (whose program groups its queries
     itself, and alone knows ``nprobe``) runs ceil(n / scan_batch) scans
     of the whole matrix inside ONE dispatch (lax.map over zero-padded
     groups), so per-dispatch host work and the device round-trip are paid
     once; ``scan_batch`` bounds a scan's VMEM ([scan_batch, SCORE_TILE]
-    f32 scores). ``wire_dtype=False`` keeps float32 scores where a served
-    pass would download bfloat16 (``_auto_download_dtype``; the sharded
-    layout's one program downloads what it serves either way)."""
+    f32 scores). The groups go in as NumPy (the jitted call transfers
+    them) and a program whose scores leave the device as float32 hands
+    back one array (``pack_hits``): one copy, one fetch; the IVF index
+    and the bfloat16 wire keep their pair. ``wire_dtype=False`` keeps
+    float32 scores where a served pass would download bfloat16
+    (``_auto_download_dtype``; the sharded layout's one program downloads
+    what it serves either way)."""
     if isinstance(uploaded, IVFIndex):
         if x_dev is None:
-            vals, idxs = ivf_ops.top_k_device(uploaded, rows, k, cosine=cosine, nprobe=nprobe)
+            hits = ivf_ops.top_k_device(uploaded, rows, k, cosine=cosine, nprobe=nprobe)
         else:
-            vals, idxs = ivf_ops.top_k_device_indexed(
+            hits = ivf_ops.top_k_device_indexed(
                 uploaded, x_dev, rows, k, cosine=cosine, nprobe=nprobe
             )
     else:
         groups = group_rows(rows, scan_batch)
         download = _auto_download_dtype(uploaded) if wire_dtype else None
         if isinstance(uploaded, ShardedItemMatrix):
-            vals, idxs = _submit_sharded(uploaded, groups, k, cosine, x_dev=x_dev)
+            hits = _submit_sharded(uploaded, groups, k, cosine, x_dev=x_dev)
         elif isinstance(uploaded, StreamingItemMatrix):
-            vals, idxs = scan_groups(
-                uploaded, jnp.asarray(groups), k, cosine=cosine,
-                download_dtype=download, x_dev=x_dev,
+            hits = scan_groups(
+                uploaded, groups, k, cosine=cosine, download_dtype=download, x_dev=x_dev
             )
         else:
             mat, norms = uploaded
-            vals, idxs = _plain_topk_groups(
-                mat, norms, x_dev, jnp.asarray(groups), max(1, min(int(k), mat.shape[0])),
-                cosine, download,
+            hits = _plain_topk_groups(
+                mat, norms, x_dev, groups, max(1, min(int(k), mat.shape[0])), cosine, download
             )
+    vals, idxs = hits if isinstance(hits, tuple) else (hits, None)
     vals.copy_to_host_async()
-    idxs.copy_to_host_async()
+    if idxs is not None:
+        idxs.copy_to_host_async()
     return TopNHandle(vals, idxs, rows.shape[0])
 
 

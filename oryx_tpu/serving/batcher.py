@@ -482,6 +482,10 @@ class TopNBatcher:
         self._m_pass_padded_rows = _metrics.counter("serving.batcher.pass.padded-rows")
         self._m_pass_depth_sum = _metrics.counter("serving.batcher.pass.inflight-depth-sum")
         self._m_pass_k_sum = _metrics.counter("serving.batcher.pass.k-bucket-sum")
+        # passes whose handle held ONE result array (ops/topn.py `pack_hits`:
+        # every float32 handle): over `passes`, the share that made one
+        # download and one fetch
+        self._m_pass_packed = _metrics.counter("serving.batcher.pass.packed")
         # counts the times the depth target takes a new value: the depth is
         # fixed, so it stays 0; registered so that a reader of it reads a
         # number and not nothing, as it would where the counter is missing
@@ -871,6 +875,8 @@ class TopNBatcher:
         self._m_pass_padded_rows.inc(padded)
         self._m_pass_depth_sum.inc(inflight)
         self._m_pass_k_sum.inc(kk)
+        if handle.packed:
+            self._m_pass_packed.inc()
         if self._hold_s:  # the first pass of a batch whose close was held
             self._m_held.inc()
             self._m_hold_seconds.observe(self._hold_s)
@@ -925,9 +931,10 @@ class TopNBatcher:
         )
 
     def _device_call(self, submit, *args, **kwargs):
-        """The part of a submit inside `ops/topn.py` (row groups,
-        `jnp.asarray`, the jitted call, the two result copies), timed and
-        marked on the profiler's timeline; the rest of
+        """The part of a submit inside `ops/topn.py` (row groups, the
+        jitted call, which takes them as NumPy, and the one result copy;
+        two where the scores travel as bfloat16 or an IVF index answers),
+        timed and marked on the profiler's timeline; the rest of
         `serving.batcher.submit.seconds` is the batcher's own Python."""
         t0 = time.perf_counter()
         with profiling.annotate("serving.pass.submit.device-call"):

@@ -350,11 +350,11 @@ def test_scratch_kernel_selection_is_a_stable_topk(case):
     k = min(k, n)  # as every public entry clamps it
 
     def scan(k, resid, resid_scales):
-        vals, idxs = ptn._streaming_topk_multi(
+        vals, idxs = ptn.split_hits(ptn._streaming_topk_multi(
             up.mat_t, up.norms, up.scales, resid, resid_scales,
             jnp.asarray(ptn.group_rows(queries)),
             k=k, n_items=n, cosine=cosine, interpret=True,
-        )
+        ))
         return np.asarray(vals).reshape(-1, k)[:b], np.asarray(idxs).reshape(-1, k)[:b]
 
     scores, q, qn = _tile_scores(up, queries, cosine)
@@ -571,13 +571,13 @@ def test_split_layout_scan_equals_the_plain_scan(features, metric, backend):
     rows = np.arange(b, dtype=np.int32) * 3
     x_dev = topn_ops.upload_queries(x)
     norms = up.norms[0, :n]
-    rv, ri = topn_ops._plain_topk_groups(
+    rv, ri = ptn.split_hits(topn_ops._plain_topk_groups(
         jnp.asarray(y), norms, x_dev, jnp.asarray(rows[None, :]), k, cosine, None
-    )
+    ))
     interpret = True if backend == "kernel" else None
-    vals, idxs = ptn.scan_groups(
+    vals, idxs = ptn.split_hits(ptn.scan_groups(
         up, jnp.asarray(rows[None, :]), k, cosine=cosine, interpret=interpret, x_dev=x_dev
-    )
+    ))
     np.testing.assert_array_equal(np.asarray(idxs), np.asarray(ri))
     if cosine:
         np.testing.assert_allclose(np.asarray(vals), np.asarray(rv), rtol=0, atol=3e-7)

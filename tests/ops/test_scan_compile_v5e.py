@@ -126,8 +126,8 @@ def test_scan_program_compiles_for_the_v5e(case, one_chip, no_persistent_cache):
     compiled = lowered.compile()  # raises what the chip's compiler would raise
     assert "tpu_custom_call" in compiled.as_text()
     assert "oryx_topn_scan" in compiled.as_text()  # the one scan kernel
-    (vals, idxs) = lowered.out_info
-    assert vals.shape == idxs.shape == (1, batch, k)
+    hits = lowered.out_info  # ONE array a pass: the scores' bits, then the ids
+    assert hits.shape == (1, batch, 2 * k) and hits.dtype == jnp.int32
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
 
@@ -159,8 +159,8 @@ def test_served_split_scan_program_compiles_for_the_v5e(case, one_chip, no_persi
     assert text.count('custom_call_target="tpu_custom_call"') == 1  # one kernel, the named one
     assert "%oryx_topn_scan" in text
     assert f"f32[{tail},{n_pad}]{{1,0:T({tail},128)}}" in text  # the tail is stored 2 rows high
-    (vals, idxs) = lowered.out_info
-    assert vals.shape == idxs.shape == (1, batch, 32)
+    hits = lowered.out_info  # ONE array a pass: the scores' bits, then the ids
+    assert hits.shape == (1, batch, 2 * 32) and hits.dtype == jnp.int32
     stored = compiled.memory_analysis().argument_size_in_bytes
     whole = pallas_topn._streaming_topk_multi_indexed.lower(
         shape((features, n_pad), jnp.float32), row, None, None, None, users, rows, **static
@@ -193,8 +193,8 @@ def test_served_vector_scan_program_compiles_for_the_v5e(case, one_chip, no_pers
     compiled = lowered.compile()  # raises what the chip's compiler would raise
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%oryx_topn_scan" in text
-    (vals, idxs) = lowered.out_info
-    assert vals.shape == idxs.shape == (1, batch, k)
+    hits = lowered.out_info  # ONE array a pass: the scores' bits, then the ids
+    assert hits.shape == (1, batch, 2 * k) and hits.dtype == jnp.int32
     mem = compiled.memory_analysis()
     # the matrix at its logical width, its norms, and the query block (which
     # the device tiles to whole 128-lane columns); no [b, n] scores anywhere
@@ -229,7 +229,8 @@ def test_served_split_sharded_program_compiles_for_a_v5e_host(no_persistent_cach
     def shape(dims, dtype, spec):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
 
-    fn = topn._sharded_scan_fn(mesh, 32, False, False, True, None, False, tailed=True)
+    # as `_submit_sharded` serves it: the pack jitted around the scan
+    fn = topn._packed(topn._sharded_scan_fn(mesh, 32, False, False, True, None, False, tailed=True))
     lowered = fn.lower(
         shape((f - 2, d * cols), jnp.float32, P(None, "data")),
         shape((1, d * cols), jnp.float32, P(None, "data")),
@@ -242,6 +243,7 @@ def test_served_split_sharded_program_compiles_for_a_v5e_host(no_persistent_cach
     text = compiled.as_text()
     assert "oryx_topn_scan" in text and "all-gather" in text
     assert f"f32[{f - 2},{cols}]" in text and f"f32[2,{cols}]" in text
+    assert lowered.out_info.shape == (1, batch, 64) and lowered.out_info.dtype == jnp.int32
     mem = compiled.memory_analysis()  # a device's own: its two planes, norms, the staged users
     assert mem.argument_size_in_bytes < (SHAPES[f] * 1.01 + 1_250_000) * f * 4 + 2 * cols * 4
 
@@ -297,8 +299,8 @@ def test_vector_dot_program_at_k_bucket_32_compiles_for_the_v5e(case, one_chip, 
     compiled = lowered.compile()  # raises what the chip's compiler would raise
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%oryx_topn_scan" in text
-    (vals, idxs) = lowered.out_info
-    assert vals.shape == idxs.shape == (1, batch, k)
+    hits = lowered.out_info  # ONE array a pass: the scores' bits, then the ids
+    assert hits.shape == (1, batch, 2 * k) and hits.dtype == jnp.int32
 
 
 # name: (features, items, item dtype)

@@ -456,6 +456,7 @@ class _StubDevice:
 
 
 class _StubHandle:
+    packed = False  # a pair, as an IVF index hands back
     def __init__(self, ready_at, gate, idx, vals) -> None:
         self._ready_at, self._gate, self._out = ready_at, gate, (idx, vals)
 

@@ -313,17 +313,17 @@ class Checks:
                 )
             elif dispatch == "multi":
                 q = x[: 2 * b]
-                vals, idx = pt.scan_groups(
-                    handle(dtype), jnp.asarray(q.reshape(-1, small_b, features)), k,
+                vals, idx = pt.split_hits(pt.scan_groups(
+                    handle(dtype), q.reshape(-1, small_b, features), k,
                     cosine=cosine, interpret=self.interpret,
-                )
+                ))
             else:
                 rows = self.gen.integers(0, n_users, (2 * b // small_b, small_b)).astype(np.int32)
                 q = x[rows.reshape(-1)]
-                vals, idx = pt.scan_groups(
-                    handle(dtype), jnp.asarray(rows), k, cosine=cosine,
+                vals, idx = pt.split_hits(pt.scan_groups(
+                    handle(dtype), rows, k, cosine=cosine,
                     interpret=self.interpret, x_dev=x_dev,
-                )
+                ))
             idx, vals = np.asarray(idx).reshape(-1, k), np.asarray(vals).reshape(-1, k)
             if len(q) > big_b:  # the reference holds a [rows, n_items] f32 block
                 q, idx, vals = q[::2], idx[::2], vals[::2]
@@ -348,9 +348,11 @@ class Checks:
             shards = [(s.device.id, tuple(s.data.shape)) for s in up.mat_t.addressable_shards]
             q = x[:64]
             idx, vals = topn_ops.top_k_scores_batch(up, q, k)
-            by_row, _ = topn_ops.submit_top_k_multi_indexed(
+            handle = topn_ops.submit_top_k_multi_indexed(
                 up, topn_ops.upload_queries(x, mesh=mesh), np.arange(64, dtype=np.int32), k
-            ).result()
+            )
+            expect(handle.packed, "a float32 pass came back as two arrays")
+            by_row, _ = handle.result()
             expect(np.array_equal(idx, by_row), "indexed submit differs from vector submit")
             last = np.asarray(up.starts) + np.asarray(up.counts) - 1
             best = 50.0 * x[: len(last)]

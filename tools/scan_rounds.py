@@ -180,8 +180,11 @@ def _run_case(ptn, up, x_dev, idx, args, counting) -> dict:
         jax.block_until_ready([dispatch() for _ in range(args.passes)])
         return (time.perf_counter() - t0) * 1e3 / args.passes
 
+    # one array of score bits and ids since PR 41 (pallas_topn.pack_hits);
+    # a checkout from before hands back the pair
+    split_hits = getattr(ptn, "split_hits", lambda pair: pair)
     t0 = time.perf_counter()
-    vals, idxs = jax.block_until_ready(dispatch())
+    vals, idxs = split_hits(jax.block_until_ready(dispatch()))
     first_s = time.perf_counter() - t0  # holds the compile, if there was one
     ms_per_pass()  # warm
     per_pass = [ms_per_pass() for _ in range(args.repeats)]

@@ -15,7 +15,8 @@ dispatcher's and the completer's CPU a pass,
 the process's CPU as cores busy: which stage grows towards the knee),
 for a cell that folds in (`/recommendToAnonymous`) the fold-in's mean,
 its items a request and the mean k bucket of a pass (whose knee is it:
-the host fold-in's, or the device's), and, with
+the host fold-in's, or the device's), the share of passes whose results
+came back as one array (`serving.batcher.pass.packed` over `passes`), and, with
 `--trace 1`, the device's idle share and the scan kernel's ms a pass from
 a profiler recording of the 4 s after the window at the same load; with
 `--raw`, every request's due time and latency of every window. A tool
@@ -105,6 +106,9 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
         / max(d("serving.foldin.requests"), 1.0),
         "k_bucket_mean": k_sum / passes if k_sum else None,
         "k32_pass_pct": 100.0 * (k_sum / passes - 16.0) / 16.0 if k_sum else None,
+        # the share of passes that came back as ONE array (one copy, one fetch):
+        # 100 on a float32 handle, 0 on a bf16 wire, an IVF index or a program from before
+        "packed_pass_pct": 100.0 * d("serving.batcher.pass.packed") / passes,
         # the host path, stage by stage (serving/stages.py): a request's
         # wall time in order, then a pass's, then the CPU beside them
         "front_native": (after.get("serving.front.native") or {}).get("value"),
