@@ -257,7 +257,7 @@ def _declare(lib: ctypes.CDLL) -> None:
         c.POINTER(c.c_int64), c.POINTER(c.c_int64), c.c_int64,
     ]
     # httpfront.cpp: epoll HTTP/1.1 front (serving/native_front.py owns
-    # the handle; ctypes releases the GIL for the blocking hf_poll)
+    # the handle; ctypes releases the GIL for the blocking hf_take)
     u8p = c.POINTER(c.c_uint8)
     lib.hf_create.restype = c.c_void_p
     lib.hf_create.argtypes = [
@@ -267,8 +267,8 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.hf_port.argtypes = [c.c_void_p]
     lib.hf_shutdown.argtypes = [c.c_void_p]
     lib.hf_close.argtypes = [c.c_void_p]
-    lib.hf_poll.restype = c.c_int64
-    lib.hf_poll.argtypes = [c.c_void_p, u8p, c.c_int64, c.c_int]
+    lib.hf_take.restype = c.c_int64
+    lib.hf_take.argtypes = [c.c_void_p, u8p, c.c_int64]
     lib.hf_respond.restype = c.c_int
     lib.hf_respond.argtypes = [
         c.c_void_p, c.c_uint32, c.c_uint32, u8p, c.c_int64, c.c_int,

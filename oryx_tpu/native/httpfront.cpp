@@ -12,11 +12,13 @@
 //   stale     champion-generation-gated answer-cache hits mirrored from
 //             AnswerCache.put (hf_cache_put; hf_cache_clear on swap)
 //
-// Everything else is assembled into micro-batches framed with the RBLK
-// wire codec (bus/blockcodec.py: same 32-byte header, KIND_HTTP payload)
-// and handed to the Python dispatch loop via hf_poll; responses come
-// back through hf_respond as fully rendered bytes and are written in
-// request order per connection (pipelining safety).
+// Everything else waits in one queue, from which each Python serving
+// thread takes ONE request itself (hf_take: blocks here, outside the
+// GIL, and is woken one thread a request), framed with the RBLK wire
+// codec (bus/blockcodec.py: same 32-byte header, a KIND_HTTP payload of
+// one record); responses come back through hf_respond as fully rendered
+// bytes and are written in request order per connection (pipelining
+// safety).
 //
 // Parity contract (tests/serving/test_native_front.py): natively
 // answered responses are byte-identical to the Python front's — the
@@ -28,7 +30,7 @@
 //
 // Ownership: hf_create starts the epoll thread and owns every fd it
 // accepts; hf_close stops the thread, closes all fds, and unblocks any
-// hf_poll caller (returns -1). All configuration setters may be called
+// hf_take caller (returns -1). All configuration setters may be called
 // from any thread; connection state is touched only by the epoll thread.
 
 #include <arpa/inet.h>
@@ -82,9 +84,24 @@ uint32_t crc32_zlib(const uint8_t* data, size_t len) {
   return c ^ 0xFFFFFFFFu;
 }
 
-inline void put_u16(std::string& b, uint16_t v) { b.append((const char*)&v, 2); }
-inline void put_u32(std::string& b, uint32_t v) { b.append((const char*)&v, 4); }
-inline void put_u64(std::string& b, uint64_t v) { b.append((const char*)&v, 8); }
+// One thread's place among those blocked in hf_take: woken by name, so
+// the front chooses whom it wakes.
+struct Taker {
+  std::condition_variable cv;
+  bool woken = false;
+};
+
+// little-endian fields and runs of bytes, written at a cursor into the
+// caller's buffer (hf_take checks the room first)
+struct Writer {
+  uint8_t* p;
+  void bytes(const void* src, size_t n) { memcpy(p, src, n); p += n; }
+  void u8(uint8_t v) { *p++ = v; }
+  void u16(uint16_t v) { bytes(&v, 2); }
+  void u32(uint32_t v) { bytes(&v, 4); }
+  void u64(uint64_t v) { bytes(&v, 8); }
+  void str(const std::string& v) { bytes(v.data(), v.size()); }
+};
 
 // ---------------------------------------------------------------------------
 // Latency bucketing (mirrors common/metrics.py Histogram: 1e-6 * 2^i s)
@@ -254,11 +271,11 @@ struct Front {
   std::unordered_map<int, uint32_t> fd_to_id;
   uint32_t next_conn_id = 1;
 
-  // pending parsed requests -> Python (hf_poll)
+  // pending parsed requests -> Python's serving threads (hf_take)
   std::mutex q_mu;
-  std::condition_variable q_cv;
   std::deque<ParsedRequest> pending;
-  uint64_t batch_seq = 0;
+  std::vector<Taker*> idle;  // blocked in hf_take, the last to come on top
+  uint64_t frame_seq = 0;
   bool q_closed = false;
   bool paused_reads = false;  // backpressure: queue full
 
@@ -330,11 +347,7 @@ struct Front {
     }
     wake();
     if (loop.joinable()) loop.join();
-    {
-      std::lock_guard<std::mutex> lk(q_mu);
-      q_closed = true;
-    }
-    q_cv.notify_all();
+    close_queue();
     for (auto& kv : conns) ::close(kv.second->fd);
     conns.clear();
     fd_to_id.clear();
@@ -394,12 +407,18 @@ struct Front {
         sweep_idle(t);
       }
     }
-    // unblock any hf_poll caller
-    {
-      std::lock_guard<std::mutex> lk(q_mu);
-      q_closed = true;
+    close_queue();
+  }
+
+  // unblock every hf_take caller
+  void close_queue() {
+    std::lock_guard<std::mutex> lk(q_mu);
+    q_closed = true;
+    for (Taker* t : idle) {
+      t->woken = true;
+      t->cv.notify_one();
     }
-    q_cv.notify_all();
+    idle.clear();
   }
 
   void accept_loop() {
@@ -665,16 +684,29 @@ struct Front {
       std::lock_guard<std::mutex> lk(s_mu);
       stats.forwarded++;
     }
-    bool notify;
+    Taker* taker = nullptr;
     {
       std::lock_guard<std::mutex> lk(q_mu);
-      notify = pending.empty();
       pending.push_back(std::move(c->cur));
-      std::lock_guard<std::mutex> lk2(s_mu);
-      if (pending.size() > stats.pending_hwm) stats.pending_hwm = pending.size();
+      {
+        std::lock_guard<std::mutex> lk2(s_mu);
+        if (pending.size() > stats.pending_hwm) stats.pending_hwm = pending.size();
+      }
+      // one request, one serving thread, and of those that wait the one
+      // that came last: its stack and its interpreter state are the
+      // warmest, and a load of five in flight is served by five or six
+      // threads however many stand (each samples its own requests,
+      // serving/stages.py)
+      if (!idle.empty()) {
+        taker = idle.back();
+        idle.pop_back();
+        taker->woken = true;
+      }
     }
+    // the taker is its thread's own for that thread's life, and no thread
+    // leaves before close_queue, which this thread runs or is joined before
+    if (taker) taker->cv.notify_one();
     c->cur = ParsedRequest();
-    if (notify) q_cv.notify_all();
     return true;
   }
 
@@ -1026,64 +1058,79 @@ struct Front {
     }
   }
 
-  // -- hf_poll frame assembly ----------------------------------------------
+  // -- hf_take: one pending request to one serving thread -------------------
 
-  int64_t poll_batch(uint8_t* buf, size_t cap, int timeout_ms) {
-    std::unique_lock<std::mutex> lk(q_mu);
-    if (pending.empty() && !q_closed)
-      q_cv.wait_for(lk, std::chrono::milliseconds(timeout_ms),
-                    [this] { return !pending.empty() || q_closed; });
-    if (pending.empty()) return q_closed ? -1 : 0;
-    std::string payload;
-    uint32_t count = 0;
-    while (!pending.empty()) {
-      const ParsedRequest& r = pending.front();
-      size_t rec = 32 + r.target.size() + r.body.size();
-      for (const auto& kv : r.headers) rec += 4 + kv.first.size() + kv.second.size();
-      rec = pad8(rec);
-      if (kFrameHeader + pad8(payload.size() + rec) > cap) break;
-      size_t start = payload.size();
-      put_u32(payload, r.conn_id);
-      put_u32(payload, r.req_id);
-      payload.push_back((char)r.method);
-      payload.push_back((char)r.flags);
-      put_u16(payload, (uint16_t)r.headers.size());
-      put_u32(payload, (uint32_t)r.target.size());
-      put_u32(payload, (uint32_t)r.body.size());
-      put_u32(payload, (uint32_t)rec);
-      put_u64(payload, r.t_parsed_ns);
-      payload += r.target;
-      for (const auto& kv : r.headers) {
-        put_u16(payload, (uint16_t)kv.first.size());
-        put_u16(payload, (uint16_t)kv.second.size());
-        payload += kv.first;
-        payload += kv.second;
+  static size_t record_bytes(const ParsedRequest& r) {
+    size_t rec = 32 + r.target.size() + r.body.size();
+    for (const auto& kv : r.headers) rec += 4 + kv.first.size() + kv.second.size();
+    return pad8(rec);
+  }
+
+  // Blocks (no timeout: an idle caller costs its process nothing) until a
+  // request is pending or the front is shut down, and hands the caller
+  // the OLDEST pending request as an RBLK frame of one KIND_HTTP record.
+  // Any number of threads may stand here; the enqueue wakes one of them a
+  // request. Returns the frame's bytes, -1 once the front is shut down,
+  // or, when the oldest request does not fit `cap`, minus the bytes it
+  // needs: the request stays at the head for whoever comes back with room.
+  int64_t take_one(uint8_t* buf, size_t cap) {
+    static thread_local Taker me;
+    ParsedRequest r;
+    size_t rec, frame_bytes;
+    uint64_t seq;
+    bool was_full;
+    {
+      std::unique_lock<std::mutex> lk(q_mu);
+      // a thread that comes back while requests wait takes one at once;
+      // one woken for a request that such a thread took waits again
+      while (pending.empty() && !q_closed) {
+        me.woken = false;
+        idle.push_back(&me);
+        // whoever sets `woken` has taken `me` off `idle`
+        me.cv.wait(lk, [] { return me.woken; });
       }
-      payload += r.body;
-      payload.resize(start + rec, '\0');
-      ++count;
+      if (q_closed) return -1;
+      rec = record_bytes(pending.front());
+      frame_bytes = kFrameHeader + rec;
+      // no wake-up is owed: this caller took the request's and returns for it
+      if (frame_bytes > cap) return -(int64_t)frame_bytes;
+      r = std::move(pending.front());
       pending.pop_front();
+      seq = frame_seq++;
+      was_full = pending.size() + 1 >= max_pending;
     }
-    if (count == 0) return 0;  // caller buffer too small for one record
-    uint64_t seq = batch_seq;
-    batch_seq += count;
-    bool was_full = pending.size() + count >= max_pending;
-    lk.unlock();
     if (was_full) wake();  // nudge the epoll thread to resume paused reads
-    std::string frame;
-    frame.reserve(kFrameHeader + pad8(payload.size()));
-    put_u32(frame, kMagic);
-    put_u16(frame, kKindHttp);
-    put_u16(frame, 0);
-    put_u64(frame, seq);
-    put_u32(frame, count);
-    put_u32(frame, (uint32_t)payload.size());
-    put_u32(frame, crc32_zlib((const uint8_t*)payload.data(), payload.size()));
-    put_u32(frame, 0);
-    frame += payload;
-    frame.resize(kFrameHeader + pad8(payload.size()), '\0');
-    memcpy(buf, frame.data(), frame.size());
-    return (int64_t)frame.size();
+    // written outside q_mu, which the epoll thread takes a request
+    uint8_t* payload = buf + kFrameHeader;
+    Writer w{payload};
+    w.u32(r.conn_id);
+    w.u32(r.req_id);
+    w.u8(r.method);
+    w.u8(r.flags);
+    w.u16((uint16_t)r.headers.size());
+    w.u32((uint32_t)r.target.size());
+    w.u32((uint32_t)r.body.size());
+    w.u32((uint32_t)rec);
+    w.u64(r.t_parsed_ns);
+    w.str(r.target);
+    for (const auto& kv : r.headers) {
+      w.u16((uint16_t)kv.first.size());
+      w.u16((uint16_t)kv.second.size());
+      w.str(kv.first);
+      w.str(kv.second);
+    }
+    w.str(r.body);
+    memset(w.p, 0, payload + rec - w.p);
+    w.p = buf;
+    w.u32(kMagic);
+    w.u16(kKindHttp);
+    w.u16(0);
+    w.u64(seq);
+    w.u32(1);
+    w.u32((uint32_t)rec);
+    w.u32(crc32_zlib(payload, rec));
+    w.u32(0);
+    return (int64_t)frame_bytes;
   }
 };
 
@@ -1120,15 +1167,15 @@ void* hf_create(int port, int backlog, int64_t max_header, int64_t max_body,
 int hf_port(void* h) { return ((Front*)h)->port; }
 
 // two-phase teardown: hf_shutdown stops the epoll thread, closes every
-// socket, and unblocks hf_poll (returns -1) while keeping the handle
+// socket, and unblocks every hf_take (returns -1) while keeping the handle
 // alive, so late hf_respond callers see a clean -1 instead of a freed
 // pointer; hf_close frees it once the binding has joined its threads.
 void hf_shutdown(void* h) { ((Front*)h)->do_close(); }
 
 void hf_close(void* h) { delete (Front*)h; }
 
-int64_t hf_poll(void* h, uint8_t* buf, int64_t cap, int timeout_ms) {
-  return ((Front*)h)->poll_batch(buf, (size_t)cap, timeout_ms);
+int64_t hf_take(void* h, uint8_t* buf, int64_t cap) {
+  return ((Front*)h)->take_one(buf, cap > 0 ? (size_t)cap : 0);
 }
 
 int hf_respond(void* h, uint32_t conn_id, uint32_t req_id, const uint8_t* data,

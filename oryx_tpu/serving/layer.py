@@ -996,11 +996,8 @@ class ServingLayer:
 
         if self._native_front is not None:
             self.port = self._native_front.port
-            ledger.register(
-                "thread",
-                self._native_front.poll_thread,
-                live=threading.Thread.is_alive,
-            )
+            for t in self._native_front.threads():
+                ledger.register("thread", t, live=threading.Thread.is_alive)
         else:
             self._server = _PooledHTTPServer(
                 ("0.0.0.0", self.port), handler_cls, threads, tls_ctx=tls_ctx

@@ -16,11 +16,16 @@ The division of labor (docs/serving-native.md):
   the REAL Python resources, so the bytes on the wire are the Python
   front's bytes (only the Date header is stamped in C++, in the same
   IMF-fixdate format).
-- Everything else crosses the boundary once, as a micro-batched RBLK
-  KIND_HTTP frame (bus/blockcodec.py), and runs through the exact same
-  ``layer._dispatch_parsed`` core the stdlib handler uses — tenant
-  resolution, admission ladder, tracing, experiments, rendering cannot
-  drift between fronts.
+- Everything else waits in the C++ front's queue until one of this
+  module's serving threads takes it: each ``NativeServe`` thread loops
+  ``hf_take`` (blocks in C++ with the interpreter released, for as long
+  as nothing is pending; the front wakes ONE thread a request) ->
+  decode its one RBLK KIND_HTTP record (bus/blockcodec.py) ->
+  ``_serve_one``. No thread stands between the parser and the thread
+  that serves: a request waits for the interpreter once on its way in.
+  It runs through the exact same ``layer._dispatch_parsed`` core the
+  stdlib handler uses — tenant resolution, admission ladder, tracing,
+  experiments, rendering cannot drift between fronts.
 - A control thread pushes ladder/tenant snapshots down (overload.py
   stays the single decision-maker; C++ only applies the last pushed
   stage), mirrors answer-cache puts, re-renders liveness snapshots, and
@@ -43,7 +48,6 @@ import sys
 import threading
 import time
 from collections import deque
-from concurrent.futures import ThreadPoolExecutor
 from email.utils import formatdate
 from http.server import BaseHTTPRequestHandler
 
@@ -76,6 +80,11 @@ _N_BUCKETS = 29  # 28 latency buckets + overflow (metrics.Histogram mirror)
 _TENANT_SLOTS = 4 + _N_BUCKETS
 _TRACE_REC = 184
 _TRACE_CAP = 4096  # matches kMaxEvents so one drain empties the ring
+
+# a serving thread's own buffer for hf_take: requests are a few hundred
+# bytes; the rare one over this (up to max-header-bytes + max-body-bytes)
+# gets a buffer of its size for the one call that takes it
+_TAKE_BYTES = 16 * 1024
 
 _METHOD_NAMES = ("GET", "POST", "DELETE", "HEAD", "OTHER")
 _RUNG_NAMES = ("snapshot", "shed", "stale")
@@ -204,15 +213,13 @@ def maybe_start(layer, ctx, threads):
         log.warning("native front failed to bind :%d; falling back",
                     layer.port)
         return None
-    front = NativeFront(layer, ctx, lib, handle, threads,
-                        max_header=max_header, max_body=max_body)
+    front = NativeFront(layer, ctx, lib, handle, threads)
     front.start()
     return front
 
 
 class NativeFront:
-    def __init__(self, layer, ctx, lib, handle, threads, *, max_header,
-                 max_body):
+    def __init__(self, layer, ctx, lib, handle, threads):
         self._layer = layer
         self._ctx = ctx
         self._lib = lib
@@ -223,12 +230,7 @@ class NativeFront:
             0.005, cfg.get_float("oryx.serving.native.control-interval-ms")
             / 1000.0)
         dispatch = cfg.get_optional_int("oryx.serving.native.dispatch-threads")
-        self._pool = ThreadPoolExecutor(
-            max_workers=dispatch or threads, thread_name_prefix="NativeServe"
-        )
-        # one full-size record always fits: header + target + headers + body
-        self._poll_cap = 64 * 1024 + int(max_header) + int(max_body) + 256
-        self._poll_buf = (ctypes.c_uint8 * self._poll_cap)()
+        self._n_workers = dispatch or threads
         self._trace_buf = (ctypes.c_uint8 * (_TRACE_CAP * _TRACE_REC))()
         self._tenant_names = (
             list(layer.tenants.ids()) if layer.tenants is not None else []
@@ -247,13 +249,18 @@ class NativeFront:
         # control tick renders and pushes the template down to C++
         self._cache_queue: deque = deque()
         self._mirror_generation = None
-        self.poll_thread: threading.Thread | None = None
+        self._workers: list[threading.Thread] = []
         self._control_thread: threading.Thread | None = None
+
+    def threads(self) -> list[threading.Thread]:
+        """Every thread this front started (``common.ledger``)."""
+        control = [self._control_thread] if self._control_thread else []
+        return self._workers + control
 
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> None:
-        if self.poll_thread is not None or self._control_thread is not None:
+        if self._workers or self._control_thread is not None:
             raise RuntimeError("NativeFront.start() called twice")
         layer = self._layer
         ctx_path = (layer.context_path or "").encode("latin-1")
@@ -269,10 +276,15 @@ class NativeFront:
         if layer.admission is not None:
             layer.admission.cache.listener = self._on_cache_put
         self.push_control()
-        self.poll_thread = threading.Thread(
-            target=self._poll_loop, name="NativePoll", daemon=True
-        )
-        self.poll_thread.start()
+        self._workers = [
+            threading.Thread(
+                target=self._serve_loop, name=f"NativeServe_{i}", daemon=True
+            )
+            for i in range(self._n_workers)
+        ]
+        for t in self._workers:
+            t.start()
+        layer.stages.workers.set(len(self._workers))
         self._control_thread = threading.Thread(
             target=self._control_loop, name="NativeControl", daemon=True
         )
@@ -286,14 +298,15 @@ class NativeFront:
         self._stop.set()
         if self._control_thread is not None:
             self._control_thread.join(timeout=5)
-        # two-phase teardown: shutdown unblocks hf_poll (-1) and closes
-        # sockets but keeps the handle alive so in-flight hf_respond
-        # calls return -1 instead of touching freed memory; hf_close
-        # only runs once every thread that could hold the handle is done
+        # two-phase teardown: shutdown unblocks every hf_take (-1) and
+        # closes sockets but keeps the handle alive so in-flight
+        # hf_respond calls return -1 instead of touching freed memory;
+        # hf_close only runs once every thread that could hold the handle
+        # is done: a worker in its handler is waited for, however long
         self._lib.hf_shutdown(self._handle)
-        if self.poll_thread is not None:
-            self.poll_thread.join(timeout=5)
-        self._pool.shutdown(wait=True, cancel_futures=True)
+        while self._workers:
+            self._workers.pop().join()
+        self._layer.stages.workers.set(0)
         adm = self._layer.admission
         if adm is not None and adm.cache.listener is self._on_cache_put:
             adm.cache.listener = None
@@ -302,40 +315,47 @@ class NativeFront:
             self._drain_trace()
         except Exception:
             log.exception("final native stats drain failed")
-        # `_respond` runs on pool threads alone and the pool is joined
+        # `_respond` runs on the serving threads alone and they are joined
         # above, so no thread is inside hf_respond now and none can enter
         # it; _closed (the handle itself is never reassigned) turns away
-        # a caller that is not a pool thread
+        # a caller that is not one of them
         self._closed = True
         self._lib.hf_close(self._handle)
 
     # -- forwarded-request data plane ---------------------------------------
 
-    def _poll_loop(self) -> None:
-        lib, handle = self._lib, self._handle
-        buf, cap = self._poll_buf, self._poll_cap
-        # what this thread's interpreter spends bringing requests to their
-        # workers (it blocks in hf_poll in between)
-        cpu = _stages.ThreadCpu(self._layer.stages.front_cpu)
+    def _serve_loop(self) -> None:
+        """One serving thread: take a request from the C++ front, serve
+        it, come back. The take blocks in C++ without the interpreter and
+        without a timeout, so an idle thread runs no Python at all."""
+        take, handle = self._lib.hf_take, self._handle
+        own = buf = (ctypes.c_uint8 * _TAKE_BYTES)()
+        self._layer.stages.takes_requests()
         while True:
-            n = lib.hf_poll(handle, buf, cap, 250)
+            n = take(handle, buf, len(buf))
             if n < 0:
-                return  # shutdown
-            if n == 0:
+                if n == -1:
+                    return  # shutdown
+                # the oldest request is larger than this thread's buffer
+                # and still heads the queue: come back with room for it
+                buf = (ctypes.c_uint8 * -n)()
                 continue
             raw = ctypes.string_at(buf, n)
+            buf = own
             try:
                 frame = blockcodec.decode_frame(raw)
-                records = blockcodec.decode_http_records(
-                    frame.payload, frame.count
-                )
+                (rec,) = blockcodec.decode_http_records(frame.payload, 1)
             except blockcodec.FrameError:
                 log.exception("native front produced an undecodable frame")
                 metrics.registry.counter("serving.http.frame.errors").inc()
                 continue
-            for rec in records:
-                self._pool.submit(self._serve_one, rec)
-            cpu.account(time.perf_counter())
+            try:
+                self._serve_one(rec)
+            except Exception:
+                # the answer could not be built or handed over; the thread
+                # stays to serve the next
+                log.exception("native front failed to answer %s %s",
+                              rec.method, rec.target)
 
     def _serve_one(self, rec) -> None:
         """Mirror of Handler._handle for one pre-parsed request."""

@@ -36,8 +36,9 @@ Wall time under one interpreter lock is mostly waiting for the lock, so
 every wall stage is read with its CPU beside it: what the interpreter
 spent on a thread is that thread's ``time.thread_time()``, the wall less
 that is what the thread waited. Each thread of the host path accounts its
-own CPU to its role's counter (``ThreadCpu``: a request's own thread, the
-native front's poll thread, the batcher's dispatcher and completer), and
+own CPU to its role's counter (``ThreadCpu``: a request's own thread, which
+on the native front also takes the request from the C++ front and decodes
+it, the batcher's dispatcher and completer), and
 only every ``CPU_EVERY_S``: on a chip's host one read of a CPU clock is a
 system call of 6 us under the interpreter lock, 70 times a wall stamp's
 cost. Handles are taken once, in ``ServingLayer.__init__``: nothing is
@@ -95,18 +96,20 @@ class ThreadCpu:
 class _Thread:
     """One serving thread's stamps of the staged request it serves
     (``perf_counter``), its CPU account and its count of requests (all of
-    them; ``counted`` of them are in ``serving.handler.requests``): made
-    at the thread's first request and kept, so a request allocates
-    nothing here."""
+    them; ``counted`` of them are in ``serving.handler.requests`` and,
+    where the thread ``takes`` its requests from the native front itself,
+    in ``serving.front.taken``): made at the thread's first request and
+    kept, so a request allocates nothing here."""
 
     __slots__ = (
-        "cpu", "n", "counted", "ingress_s", "t_begin", "t_q", "t_woken", "scans", "t_observed",
-        "call_s",
+        "cpu", "n", "counted", "takes", "ingress_s", "t_begin", "t_q", "t_woken", "scans",
+        "t_observed", "call_s",
     )
 
-    def __init__(self, counter: metrics.Counter) -> None:
+    def __init__(self, counter: metrics.Counter, takes: bool = False) -> None:
         self.cpu = ThreadCpu(counter)
         self.n = self.counted = 0
+        self.takes = takes
         self.scans = -1  # passes through the batcher so far; -1: no staged request is open
 
 
@@ -155,13 +158,26 @@ class HostStages:
         # its thread accounts its CPU: what the CPU counters are read over
         self.requests = registry.counter("serving.handler.requests")
         self.handler_cpu = registry.counter("serving.handler.cpu.seconds")
-        self.front_cpu = registry.counter("serving.front.cpu.seconds")
+        # requests a serving thread took from the native front itself (all
+        # of them there, none on the Python front; counted with `requests`,
+        # so their ratio is exact), and how many such threads stand:
+        # serving.front.taken over serving.handler.requests is the share
+        # that came in with no thread in between
+        self.taken = registry.counter("serving.front.taken")
+        self.workers = registry.gauge("serving.front.workers")
+        self.workers.set(0)
         # every thread of the process, XLA's and the native front's included;
         # it only rises, and a reader takes the delta of its value
         self.process_cpu = registry.gauge("serving.process.cpu.seconds")
         self.process_cpu.set(time.process_time())
         self._process_cpu_at = time.perf_counter()
         self.native = registry.gauge("serving.front.native")
+
+    def takes_requests(self) -> None:
+        """Native front, on each of its serving threads before its first
+        request: every request this thread begins it took from the C++
+        front itself, and counts in ``serving.front.taken``."""
+        _local.thread = _Thread(self.handler_cpu, takes=True)
 
     def begin(self, ingress_s: float) -> float:
         """The request's first stamp, which is also where
@@ -211,6 +227,8 @@ class HostStages:
         st.scans = -1
         if st.cpu.account(now):
             self.requests.inc(st.n - st.counted)
+            if st.takes:
+                self.taken.inc(st.n - st.counted)
             st.counted = st.n
         if now - self._process_cpu_at >= CPU_EVERY_S:
             self._process_cpu_at = now

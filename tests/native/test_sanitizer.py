@@ -117,12 +117,12 @@ def test_httpfront_suite_clean_under_asan_ubsan(sanitized_env):
     reaping, oversized-frame rejection, mid-request disconnects) replays
     against an httpfront.cpp compiled with ASan+UBSan. The epoll loop,
     per-connection buffer arithmetic, and the teardown path (hf_shutdown
-    unblocking hf_poll, then hf_close freeing connections) are exactly
+    unblocking every hf_take, then hf_close freeing connections) are exactly
     the code ASan's heap checks and UBSan's overflow checks target."""
     proc = _run(
         sanitized_env,
         "tests/serving/test_native_front.py",
-        "-k", "not fleet and not tenants",
+        "-k", "not fleet and not tenants and not hf_take",
         timeout=600,
     )
     output = proc.stdout + proc.stderr
@@ -138,6 +138,30 @@ def test_httpfront_suite_clean_under_asan_ubsan(sanitized_env):
         timeout=300,
     )
     assert "native toolchain unavailable" not in probe.stdout, probe.stdout
+
+
+def test_httpfront_take_respond_shutdown_race_clean_under_asan_ubsan(sanitized_env):
+    """The queue between the parser and the serving threads, on the
+    library's handle alone (the `hf_take` cases of test_native_front.py):
+    threads blocked in `hf_take` woken one a request, a record left at the
+    head for want of room, takers answering through `hf_respond` while
+    clients send and `hf_shutdown` lands among them, `hf_close` after the
+    last taker has left. A frame written past its buffer, a request moved
+    out of the queue twice or a front freed under a taker is what ASan
+    reports here."""
+    proc = _run(
+        sanitized_env,
+        "tests/serving/test_native_front.py",
+        "-k", "hf_take",
+        "-rs",
+        timeout=300,
+    )
+    output = proc.stdout + proc.stderr
+    assert proc.returncode == 0, f"sanitized hf_take run failed:\n{output[-8000:]}"
+    assert "ERROR: AddressSanitizer" not in output, output[-8000:]
+    assert "runtime error:" not in output, output[-8000:]
+    assert "native toolchain unavailable" not in proc.stdout, proc.stdout
+    assert " passed" in proc.stdout and "skipped" not in proc.stdout, proc.stdout
 
 
 def test_tier_store_suite_clean_under_asan_ubsan(sanitized_env):

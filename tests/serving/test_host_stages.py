@@ -36,13 +36,14 @@ PASS_STAGES = (
     "serving.batcher.submit.device-call.seconds", "serving.batcher.submit.seconds",
 )
 CPU_COUNTERS = (
-    "serving.handler.cpu.seconds", "serving.front.cpu.seconds",
+    "serving.handler.cpu.seconds",
     "serving.batcher.dispatch.cpu.seconds", "serving.batcher.complete.cpu.seconds",
 )
 INSTRUMENTS = (
     *REQUEST_STAGES, *FRONT_STAGES, RESPOND_CALL, *PASS_STAGES, *CPU_COUNTERS,
     "serving.handler.rescans", "serving.handler.requests", "serving.process.cpu.seconds",
     "serving.front.native", "serving.batcher.hold.lag-ms",
+    "serving.front.taken", "serving.front.workers",
 )
 
 # the quantities this PR's data files read, and the cells' entries on them
@@ -209,10 +210,69 @@ def test_the_stages_tile_a_request_and_each_is_observed_once(served, kind):
         for name in (*CPU_COUNTERS, "serving.process.cpu.seconds"):
             assert moved(name, "value") >= 0.0, name
         assert 0.0 < moved("serving.handler.cpu.seconds", "value") <= after["_at"] - before["_at"]
-        if front == "native":
-            assert moved("serving.front.cpu.seconds", "value") > 0.0  # the poll thread's
+        # no thread between the parser and the one that serves: the native
+        # front's request was taken by its own thread, the take and the
+        # decode are in that thread's CPU above
+        assert moved("serving.front.taken", "value") == (1 if front == "native" else 0)
         assert moved("serving.batcher.dispatch.cpu.seconds", "value") > 0.0
         assert moved("serving.batcher.complete.cpu.seconds", "value") > 0.0
+
+
+@pytest.mark.parametrize("served", ["python", "native", "native-one-thread"], indirect=True)
+def test_ingress_runs_from_the_last_byte_parsed_to_the_serving_thread_s_begin(served):
+    """`serving.front.ingress.seconds` of a request a serving thread took
+    itself, with one thread and with the default 64 idle in their takes:
+    once a request, not negative, and with `serving.request.seconds`,
+    which starts where it ends, inside the wall the client saw (one
+    machine, one monotonic clock); the front says how many threads stand."""
+    layer, conn, _passes, front = served
+    workers = _snap()["serving.front.workers"]["value"]
+    if front == "native":
+        one = layer.config.get_optional_int("oryx.serving.native.dispatch-threads")
+        assert one in (None, 1) and workers == len(layer._native_front._workers) == (one or 64)
+    else:
+        assert workers == 0
+    _one_request(conn, "/scan/vector/1")
+    for n in range(2, 8):
+        before, after = _one_request(conn, f"/scan/vector/{n}")
+        moved = lambda name, field: _counter_delta(before, after, name, field)
+        assert moved("serving.front.ingress.seconds", "count") == 1
+        ingress = moved("serving.front.ingress.seconds", "sum")
+        assert ingress >= 0.0
+        assert ingress + moved("serving.request.seconds", "sum") <= after["_at"] - before["_at"]
+        assert moved("serving.front.taken", "value") == (1 if front == "native" else 0)
+        assert moved("serving.handler.requests", "value") == 1
+
+
+def test_the_sweep_tool_reads_the_taken_share_100_on_the_native_front_and_0_on_the_python(served):
+    """`tools/sweep_passes.py` over a window of six requests: the share
+    their serving thread took itself, and its `stages:` line, which says
+    it with the number of serving threads and the wall stages in order."""
+    from tools import sweep_passes
+
+    _layer, conn, _passes, front = served
+    _one_request(conn, "/scan/vector/1")
+    before = _snap()
+    for n in range(2, 8):
+        _before, after = _one_request(conn, f"/scan/vector/{n}")
+    assert _counter_delta(before, after, "serving.handler.requests", "value") == 6
+    share = sweep_passes.taken_pct(before, after)
+    assert share == (100.0 if front == "native" else 0.0)
+    row = dict.fromkeys(
+        ("front_ingress_mean_ms", "handler_pre_mean_ms", "batcher_entry_mean_ms",
+         "queue_wait_mean_ms", "pass_inflight_mean_ms", "waiter_wake_mean_ms",
+         "handler_post_mean_ms", "front_respond_mean_ms"), 0.25)
+    row.update(
+        front_native=after["serving.front.native"]["value"], taken_pct=share,
+        front_workers=after["serving.front.workers"]["value"], requests_per_s=6.0,
+    )
+    line = sweep_passes.stages_line(row)
+    if front == "native":
+        assert line.startswith("stages: front_native 1, taken 100.0 % of 6 requests/s by 64 serving threads; ")
+    else:
+        assert line.startswith("stages: front_native 0, taken 0.0 % of 6 requests/s by 0 serving threads; ")
+    assert line.endswith("ingress 0.250 -> pre 0.250 -> entry 0.250 -> queue 0.250 -> in flight 0.250 "
+                         "-> wake 0.250 -> post 0.250 -> respond 0.250 ms")
 
 
 @pytest.mark.parametrize("what", ["cache-hit", "shed", "error", "unknown-path"])

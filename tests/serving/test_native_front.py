@@ -19,6 +19,7 @@ Python version suffix. Everything that reaches dispatch is bit-equal.
 
 from __future__ import annotations
 
+import ctypes
 import http.client
 import json
 import re
@@ -35,7 +36,9 @@ REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 sys.path.insert(0, str(REPO_ROOT / "tools"))
 
 from oryx_tpu import bus, native
+from oryx_tpu.bus import blockcodec
 from oryx_tpu.common import config as C
+from oryx_tpu.common import metrics
 from oryx_tpu.serving.layer import ServingLayer
 
 _HAVE_NATIVE = native.get_library() is not None and hasattr(
@@ -195,7 +198,10 @@ def _pin_stage(layer, stage: int) -> None:
 
 @needs_native
 def test_parity_basic_routes(pair):
-    # before any model: snapshots say 503, dynamic routes too
+    # before any model: snapshots say 503, dynamic routes too (each layer
+    # has polled its update stream once: `stream_healthy` is null before)
+    for layer in pair.layers():
+        assert wait_for(lambda l=layer: l.health.stream_healthy is not None)
     pair.tick()
     for path in ("/ready", "/healthz", "/readyz", "/distinct"):
         pair.assert_parity("GET", path, label="(pre-model)")
@@ -532,7 +538,7 @@ def test_responses_handed_over_together_each_reach_their_own_connection(recorded
     their handlers, so their `_respond` calls start together: each
     connection reads its own answer, whole (20 KB: more than one send)."""
     layer, front, calls, returned = recorded
-    n = front._pool._max_workers
+    n = len(front._workers)
     barrier = threading.Barrier(n)
 
     def answer(req):
@@ -633,7 +639,7 @@ def test_close_orders_the_library_s_calls_around_in_flight_responds(recorded, wh
             assert conn.getresponse().read() == b'{"n": 1}'
         closer.start()
         if when == "during":
-            # close() stands in the pool's join until the handler returns
+            # close() stands in the workers' join until the handler returns
             assert wait_for(lambda: "hf_shutdown" in calls)
             closer.join(timeout=0.3)
             assert closer.is_alive() and "hf_close" not in calls
@@ -643,7 +649,7 @@ def test_close_orders_the_library_s_calls_around_in_flight_responds(recorded, wh
     finally:
         release.set()
         conn.close()
-    # a respond that starts once the front is closed (no pool thread can)
+    # a respond that starts once the front is closed (no serving thread can)
     front._respond(SimpleNamespace(conn_id=1, req_id=1), b"late")
     front.close()
     layer.close()
@@ -655,6 +661,561 @@ def test_close_orders_the_library_s_calls_around_in_flight_responds(recorded, wh
     else:
         assert order == ["hf_shutdown"] + ["hf_respond"] * responds + ["hf_close"]
         assert returned == [-1] * responds  # started after hf_shutdown: dropped by the live handle
+
+
+# -- the pull path: serving threads take their requests themselves ------------
+
+
+def _taken() -> float:
+    return metrics.registry.snapshot()["serving.front.taken"]["value"]
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [1, 4, 16], indirect=True)
+def test_requests_sent_together_are_each_served_once_by_a_thread_that_took_it(recorded, monkeypatch):
+    """N requests on N connections at once (more than the front has
+    threads, and fewer): every one is answered exactly once, by a
+    `NativeServe` thread that took it from the C++ front itself, and
+    `serving.front.taken` counts each (a thread brings it up to date with
+    `serving.handler.requests` when it accounts its CPU after a staged
+    request: here after every one)."""
+    from oryx_tpu.serving import stages
+
+    monkeypatch.setattr(stages, "CPU_EVERY_S", 0.0)
+    monkeypatch.setattr(stages, "SAMPLE_EVERY", 1)
+    layer, front, calls, _returned = recorded
+    n = 12
+    served = []
+
+    def who(req):
+        served.append((req.params["tag"], threading.current_thread()))
+        return {"tag": req.params["tag"]}
+
+    layer.router.add("GET", "/who/{tag}", who)
+    assert [t.name for t in front._workers] == [f"NativeServe_{i}" for i in range(len(front._workers))]
+    before = _taken()
+    conns = [http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30) for _ in range(n)]
+    try:
+        for c in conns:
+            c.connect()
+        for i, c in enumerate(conns):
+            c.request("GET", f"/who/t{i:02d}")
+        for i, c in enumerate(conns):
+            resp = c.getresponse()
+            assert resp.status == 200 and json.loads(resp.read()) == {"tag": f"t{i:02d}"}
+    finally:
+        for c in conns:
+            c.close()
+    assert sorted(tag for tag, _ in served) == [f"t{i:02d}" for i in range(n)]
+    assert all(thread in front._workers for _, thread in served)
+    assert wait_for(lambda: _taken() - before == n)  # counted once the answer has left
+    # a take a request and one more a thread, which stands in it now
+    assert wait_for(lambda: calls.count("hf_take") == n + len(front._workers))
+    assert not any(t.name == "NativePoll" for t in threading.enumerate())
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [4, 16], indirect=True)
+def test_small_requests_behind_a_large_one_are_each_served_once(recorded):
+    """A body of 1 MB heads the queue while several threads wait, and
+    twelve small requests follow it at once on connections of their own:
+    whichever threads meet the large one come back with room, ONE of them
+    serves it whole, and every small request is answered once, none lost
+    behind it and none twice."""
+    from oryx_tpu.serving import native_front as nf
+
+    layer, front, calls, _returned = recorded
+    served = []
+
+    def echo(req):
+        served.append(req.params["tag"])
+        return {"tag": req.params["tag"], "bytes": len(req.body)}
+
+    layer.router.add("POST", "/echo/{tag}", echo)
+    assert wait_for(lambda: calls.count("hf_take") == len(front._workers))
+    size, n = 1_000_000, 12
+    assert size > nf._TAKE_BYTES
+    conns = [http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30) for _ in range(n + 1)]
+    try:
+        for c in conns:
+            c.connect()
+        conns[0].request("POST", "/echo/large", body=b"x" * size)
+        for i, c in enumerate(conns[1:]):
+            c.request("POST", f"/echo/s{i:02d}", body=b"small")
+        resp = conns[0].getresponse()
+        assert resp.status == 200 and json.loads(resp.read()) == {"tag": "large", "bytes": size}
+        for i, c in enumerate(conns[1:]):
+            resp = c.getresponse()
+            assert resp.status == 200 and json.loads(resp.read()) == {"tag": f"s{i:02d}", "bytes": 5}
+    finally:
+        for c in conns:
+            c.close()
+    assert sorted(served) == ["large"] + [f"s{i:02d}" for i in range(n)]
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [3, 32], indirect=True)
+def test_threads_racing_for_the_queue_answer_every_request_once(recorded):
+    """More client threads than cores on keep-alive connections, fewer and
+    more serving threads than clients, the interpreter switching every
+    10 us: each of the 600 requests is answered once, with its own tag, by
+    the one thread that took it (a lost or doubled take would show as a
+    missing or a repeated tag, or as a take too many)."""
+    layer, front, calls, _returned = recorded
+    clients, each = 12, 50
+    served, errors = [], []
+    layer.router.add("GET", "/tag/{tag}", lambda req: served.append(req.params["tag"]) or {"tag": req.params["tag"]})
+
+    def client(c):
+        conn = http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30)
+        try:
+            for i in range(each):
+                conn.request("GET", f"/tag/c{c}r{i}")
+                resp = conn.getresponse()
+                if resp.status != 200 or json.loads(resp.read()) != {"tag": f"c{c}r{i}"}:
+                    errors.append((c, i, resp.status))
+        except Exception as e:  # noqa: BLE001
+            errors.append((c, "exc", repr(e)))
+        finally:
+            conn.close()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(c,)) for c in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors[:5]
+    assert sorted(served) == sorted(f"c{c}r{i}" for c in range(clients) for i in range(each))
+    assert wait_for(lambda: calls.count("hf_take") == clients * each + len(front._workers))
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [1, 8], indirect=True)
+def test_an_idle_front_s_serving_threads_make_no_call_and_run_no_python(recorded):
+    """Every serving thread stands in ONE `hf_take` for as long as nothing
+    is pending: none returns to the interpreter to tick (the poll it
+    replaces came back four times a second), and a request after the
+    second of silence is served by a thread that was blocked all along."""
+    layer, front, calls, _returned = recorded
+    assert metrics.registry.snapshot()["serving.front.workers"]["value"] == len(front._workers)
+    assert wait_for(lambda: calls.count("hf_take") == len(front._workers))
+    time.sleep(1.0)
+    assert calls.count("hf_take") == len(front._workers)
+    assert all(t.is_alive() for t in front._workers)
+    assert fetch(layer.port, path="/nope").startswith(b"HTTP/1.1 404")
+    assert wait_for(lambda: calls.count("hf_take") == len(front._workers) + 1)
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [2, 8], indirect=True)
+def test_two_requests_on_one_connection_are_answered_in_the_order_they_were_sent(recorded):
+    """Two requests pipelined on one keep-alive connection are taken by
+    two threads; the first's handler returns only once the second's
+    answer has been handed over: the connection reads the first's answer,
+    then the second's, each whole."""
+    layer, front, calls, _returned = recorded
+    handed, second_out = [], threading.Event()
+    respond = front._respond
+
+    def noting_respond(rec, data):
+        respond(rec, data)
+        if rec.target.endswith("/second"):
+            second_out.set()
+
+    front._respond = noting_respond
+
+    def answer(req):
+        if req.params["tag"] == "first":
+            assert second_out.wait(30)
+        handed.append(req.params["tag"])
+        return {"tag": req.params["tag"]}
+
+    layer.router.add("GET", "/order/{tag}", answer)
+    both = b"".join(
+        f"GET /order/{tag} HTTP/1.1\r\nHost: x\r\n{extra}\r\n".encode()
+        for tag, extra in (("first", ""), ("second", "Connection: close\r\n"))
+    )
+    resp = raw(layer.port, both)
+    assert handed == ["second", "first"]  # two threads: the second did not wait for the first
+    assert re.findall(rb'\{"tag": "(\w+)"\}', resp) == [b"first", b"second"]
+    assert resp.count(b"HTTP/1.1 200") == 2 and calls.count("hf_respond") == 2
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [2, 64], indirect=True)
+def test_requests_one_after_the_other_are_served_by_the_thread_that_served_the_last(recorded):
+    """Forty requests, each sent once the one before is answered: the
+    thread that answered is the last to have come back to its take, and
+    the front wakes that one. However many threads stand, one or two
+    carry a load of one in flight (four are allowed here: a loaded machine
+    can hold a thread between its answer and its take; woken in turn, 40
+    of 64 would serve), so each meets its every eighth request, the one
+    `serving/stages.py` stages, soon."""
+    layer, front, _calls, _returned = recorded
+    served = []
+    layer.router.add("GET", "/who", lambda req: served.append(threading.current_thread()) or {"n": 1})
+    conn = http.client.HTTPConnection("127.0.0.1", layer.port, timeout=30)
+    try:
+        for _ in range(40):
+            conn.request("GET", "/who")
+            assert conn.getresponse().read() == b'{"n": 1}'
+            # the answer can reach the client before its thread is back in its take
+            time.sleep(0.005)
+    finally:
+        conn.close()
+    assert len(served) == 40 and set(served) <= set(front._workers)
+    assert len(set(served)) <= 4, sorted(t.name for t in set(served))
+
+
+@needs_native
+@pytest.mark.parametrize("recorded", [1, 64], indirect=True)
+def test_close_with_every_thread_blocked_in_its_take_joins_them_all(recorded):
+    """`hf_shutdown` answers every blocked `hf_take` with -1: `close()`
+    returns well inside a join's patience, no thread of the front is left
+    alive, the gauge says so, and the library is closed after the last."""
+    _layer, front, calls, _returned = recorded
+    threads, n = front.threads(), len(front._workers)
+    assert len(threads) == n + 1 and all(t.is_alive() for t in threads)
+    assert wait_for(lambda: calls.count("hf_take") == n)
+    t0 = time.monotonic()
+    front.close()
+    assert time.monotonic() - t0 < 5.0
+    assert not any(t.is_alive() for t in threads)
+    assert metrics.registry.snapshot()["serving.front.workers"]["value"] == 0
+    assert calls.index("hf_shutdown") < calls.index("hf_close") == len(calls) - 1
+    assert calls.count("hf_take") == n  # none came back for more
+
+
+@needs_native
+@pytest.mark.parametrize("where", ["handler", "respond"])
+@pytest.mark.parametrize("recorded", [1], indirect=True)
+def test_a_request_that_raises_leaves_its_thread_serving(recorded, where):
+    """The front's ONE serving thread meets a handler that raises (answered
+    500 by `_serve_one`) or a failure past it (the respond itself: nothing
+    can be answered, the exception is logged in the thread's loop): the
+    same thread takes and answers the requests that follow."""
+    layer, front, calls, _returned = recorded
+    (worker,) = front._workers
+    served = []
+
+    def boom(req):
+        served.append(threading.current_thread())
+        raise RuntimeError("boom")
+
+    layer.router.add("GET", "/boom", boom)
+    layer.router.add("GET", "/fine", lambda req: served.append(threading.current_thread()) or {"n": 1})
+    if where == "respond":
+        respond = front._respond
+
+        def failing_respond(rec, data):
+            if rec.target == "/boom":
+                raise OSError("no way out")
+            respond(rec, data)
+
+        front._respond = failing_respond
+    for _ in range(3):
+        if where == "handler":
+            assert fetch(layer.port, path="/boom").startswith(b"HTTP/1.1 500")
+        else:
+            assert raw(layer.port, request_bytes("GET", "/boom"), timeout=0.5) == b""
+        assert fetch(layer.port, path="/fine").endswith(b'{"n": 1}')
+    assert served == [worker] * 6 and worker.is_alive()
+    assert wait_for(lambda: calls.count("hf_take") == 7)
+
+
+@needs_native
+@pytest.mark.parametrize("size", [20_000, 300_000, 1_000_000])
+def test_a_request_larger_than_a_thread_s_buffer_is_served_whole(pair, size):
+    """A body of more than `_TAKE_BYTES` (up to the default
+    max-body-bytes): the thread that meets it at the head of the queue
+    comes back with room, both fronts answer the same bytes, and the
+    thread serves small requests from its own buffer again."""
+    from oryx_tpu.serving import native_front as nf
+
+    assert size > nf._TAKE_BYTES
+    publish_model(pair.broker, {"a": 1})
+    for layer in pair.layers():
+        assert wait_for(lambda l=layer: is_200(l.port))
+    seen = []
+
+    def echo(req):
+        seen.append(req.body)
+        return {"bytes": len(req.body), "tail": req.body[-8:].decode("latin-1")}
+
+    for layer in pair.layers():
+        layer.router.add("POST", "/echo", echo)
+    body = (b"%07d " % size) * (size // 8)
+    got = pair.assert_parity("POST", "/echo", body=body, label=f"({size} bytes)")
+    assert got.startswith(b"HTTP/1.1 200") and seen == [body, body]
+    pair.assert_parity("GET", "/distinct", label="(after the large one)")
+    pair.assert_parity("POST", "/echo", body=b"small", label="(after the large one)")
+
+
+# -- hf_take itself: the library's queue with no Python front on it -----------
+
+
+class _Bare:
+    """A front of the library alone (`hf_create`, no `NativeFront`): the
+    test's own threads stand in `hf_take`, as serving threads do. `close`
+    shuts the front down, joins every taker and only then frees it."""
+
+    def __init__(self):
+        self.lib = native.get_library()
+        self.handle = self.lib.hf_create(0, 128, 0, 0, 0.0, 0)
+        assert self.handle
+        self.port = self.lib.hf_port(self.handle)
+        self.threads: list[threading.Thread] = []
+        self._forwarded = 0
+
+    def take(self, cap=16384):
+        """One `hf_take` with a buffer of `cap` bytes: what it returned
+        and, where that is a frame, (seqno, the record)."""
+        buf = (ctypes.c_uint8 * max(cap, 1))()
+        n = self.lib.hf_take(self.handle, buf, cap)
+        if n < 0:
+            return n, None
+        frame = blockcodec.decode_frame(ctypes.string_at(buf, n))  # checks the CRC
+        assert frame.kind == blockcodec.KIND_HTTP and frame.count == 1
+        assert n == blockcodec.HEADER_BYTES + frame.length
+        (rec,) = blockcodec.decode_http_records(frame.payload, 1)
+        return n, (frame.seqno, rec)
+
+    def taker(self, fn):
+        t = threading.Thread(target=fn, daemon=True)
+        self.threads.append(t)
+        t.start()
+        return t
+
+    def respond(self, rec, body: bytes) -> int:
+        data = b"HTTP/1.1 200 OK\r\nContent-Length: %d\r\n\r\n%s" % (len(body), body)
+        buf = (ctypes.c_uint8 * len(data)).from_buffer_copy(data)
+        return self.lib.hf_respond(self.handle, rec.conn_id, rec.req_id, buf, len(data), 0)
+
+    def forwarded(self) -> int:
+        """Requests the parser has put in the queue so far (a read of
+        `hf_stats` takes what it reports)."""
+        from oryx_tpu.serving.native_front import _SCALARS
+
+        out = (ctypes.c_uint64 * 64)()
+        assert self.lib.hf_stats(self.handle, out, 64, 0) > 0
+        self._forwarded += out[_SCALARS.index("forwarded")]
+        return self._forwarded
+
+    def close(self):
+        self.lib.hf_shutdown(self.handle)
+        for t in self.threads:
+            t.join(timeout=10)
+        assert not any(t.is_alive() for t in self.threads)
+        self.lib.hf_close(self.handle)
+
+
+@pytest.fixture()
+def bare():
+    b = _Bare()
+    try:
+        yield b
+    finally:
+        b.close()
+
+
+def fetch_nowait(port, path) -> bool:
+    """Send one request and leave without reading (the front has parsed
+    and queued it by the time the taker's frame is checked)."""
+    with socket.create_connection(("127.0.0.1", port), timeout=5) as s:
+        s.sendall(f"GET {path} HTTP/1.1\r\nHost: x\r\n\r\n".encode())
+        time.sleep(0.05)
+    return True
+
+
+def _pipelined(n, path="/r") -> bytes:
+    return b"".join(f"GET {path}{i} HTTP/1.1\r\nHost: x\r\n\r\n".encode() for i in range(n))
+
+
+@needs_native
+@pytest.mark.parametrize("takers", [1, 4])
+def test_hf_take_hands_out_requests_oldest_first_across_takers(bare, takers):
+    """Twelve requests parsed in a known order (one connection,
+    pipelined), taken by one thread or by four racing for them: each is
+    handed out once, a frame of ONE record, and the frames' sequence
+    numbers, given under the queue's lock, follow the order of arrival."""
+    n = 12
+    got, lock = [], threading.Lock()
+
+    def loop():
+        while True:
+            size, frame = bare.take()
+            if size == -1:
+                return
+            with lock:
+                got.append(frame)
+
+    with socket.create_connection(("127.0.0.1", bare.port), timeout=5) as s:
+        s.sendall(_pipelined(n))
+        assert wait_for(lambda: bare.forwarded() == n)
+        for _ in range(takers):
+            bare.taker(loop)
+        assert wait_for(lambda: len(got) == n)
+        time.sleep(0.2)
+        assert len(got) == n  # none handed out twice
+    got.sort(key=lambda frame: frame[0])
+    assert [seq for seq, _ in got] == list(range(n))
+    assert [rec.target for _, rec in got] == [f"/r{i}" for i in range(n)]
+    assert [rec.req_id for _, rec in got] == sorted(rec.req_id for _, rec in got)
+    assert all(rec.method == "GET" and rec.t_parsed > 0.0 for _, rec in got)
+
+
+@needs_native
+@pytest.mark.parametrize("takers", [2, 8, 64])
+def test_hf_take_wakes_one_blocked_taker_a_request_and_shutdown_wakes_the_rest(bare, takers):
+    """N threads blocked in `hf_take` with no timeout, and ONE request:
+    one of them returns with it and the others stay blocked (a second
+    request wakes one more); `hf_shutdown` then releases every one that is
+    left with -1, and a take after it returns -1 at once."""
+    returned, lock = [], threading.Lock()
+
+    def once():
+        size, frame = bare.take()
+        with lock:
+            returned.append((size, frame))
+
+    threads = [bare.taker(once) for _ in range(takers)]
+    time.sleep(0.3)
+    assert returned == [] and all(t.is_alive() for t in threads)
+    for k in (1, 2):
+        assert fetch_nowait(bare.port, f"/one{k}")
+        assert wait_for(lambda: len(returned) == k)
+        time.sleep(0.3)
+        assert len(returned) == k and sum(t.is_alive() for t in threads) == takers - k
+    assert [frame[1].target for _, frame in returned] == ["/one1", "/one2"]
+    bare.lib.hf_shutdown(bare.handle)
+    for t in threads:
+        t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    assert [size for size, _ in returned[2:]] == [-1] * (takers - 2)
+    assert bare.take() == (-1, None)
+
+
+@needs_native
+def test_hf_take_wakes_the_taker_that_came_to_wait_last(bare):
+    """Takers come to wait one after the other, half a second apart; a
+    request wakes the LAST of them, a taker that comes after that goes on
+    top, and the first is woken only when no other waits: at a load one
+    thread can carry, one thread carries it, however many stand."""
+    returned, lock = [], threading.Lock()
+
+    def once(name):
+        size, frame = bare.take()
+        with lock:
+            returned.append((name, frame[1].target if frame else size))
+
+    def park(name):
+        bare.taker(lambda: once(name))
+        time.sleep(0.5)  # it stands in its take before the next comes
+
+    park("first")
+    park("second")
+    assert fetch_nowait(bare.port, "/a") and wait_for(lambda: len(returned) == 1)
+    park("third")
+    assert fetch_nowait(bare.port, "/b") and wait_for(lambda: len(returned) == 2)
+    assert fetch_nowait(bare.port, "/c") and wait_for(lambda: len(returned) == 3)
+    assert returned == [("second", "/a"), ("third", "/b"), ("first", "/c")]
+
+
+@needs_native
+@pytest.mark.parametrize("cap", [0, 31, 64, 4096])
+def test_hf_take_leaves_a_record_larger_than_the_buffer_at_the_head(bare, cap):
+    """A request of 5 KB heads the queue and a small one follows it. A
+    take with less room than the large one's frame returns minus the bytes
+    it needs, writes nothing, and hands out NEITHER (the small one does
+    not overtake); the retry with that room gets the large one whole, and
+    the small one comes after it."""
+    big = b"b" * 5000
+    with socket.create_connection(("127.0.0.1", bare.port), timeout=5) as s:
+        s.sendall(b"POST /big HTTP/1.1\r\nHost: x\r\nContent-Length: 5000\r\n\r\n" + big
+                  + b"GET /small HTTP/1.1\r\nHost: x\r\n\r\n")
+        assert wait_for(lambda: bare.forwarded() == 2)
+        for _ in range(2):  # the refusal repeats for as long as the room is short
+            size, frame = bare.take(cap)
+            assert frame is None and size < -5000
+        need = -size
+        assert bare.take(need - 1)[0] == -need
+        size, (seq, rec) = bare.take(need)
+        assert size == need and seq == 0
+        assert (rec.method, rec.target, rec.body) == ("POST", "/big", big)
+        size, (seq, rec) = bare.take()
+        assert seq == 1 and (rec.method, rec.target, rec.body) == ("GET", "/small", b"")
+
+
+@needs_native
+def test_hf_take_hands_out_nothing_that_was_queued_at_shutdown(bare):
+    """A request parsed and not yet taken when the front shuts down: its
+    connection is closed with nothing written, and a take returns -1, not
+    the request (its answer could reach no one)."""
+    with socket.create_connection(("127.0.0.1", bare.port), timeout=5) as s:
+        s.sendall(b"GET /late HTTP/1.1\r\nHost: x\r\n\r\n")
+        assert wait_for(lambda: bare.forwarded() == 1)
+        bare.lib.hf_shutdown(bare.handle)
+        assert bare.take() == (-1, None)
+        s.settimeout(5.0)
+        assert s.recv(4096) == b""
+
+
+@needs_native
+@pytest.mark.parametrize("takers", [2, 16])
+def test_hf_take_respond_and_shutdown_race(bare, takers):
+    """Takers answering what they take while clients keep sending, the
+    interpreter switching every 10 us, and `hf_shutdown` in the middle of
+    it: every answer a client read whole is its own request's, every taker
+    leaves with -1, a respond that lost the race returns -1 on the live
+    handle, and the front is freed only after the last of them (the
+    sanitizer's build runs this: tests/native/test_sanitizer.py)."""
+    answered, late, errors = [], [], []
+
+    def serve():
+        while True:
+            size, frame = bare.take()
+            if size == -1:
+                return
+            rec = frame[1]
+            (answered if bare.respond(rec, rec.target.encode()) == 0 else late).append(rec.target)
+
+    def client(c):
+        conn = http.client.HTTPConnection("127.0.0.1", bare.port, timeout=10)
+        try:
+            for i in range(10_000):
+                conn.request("GET", f"/c{c}r{i}")
+                body = conn.getresponse().read()
+                if body != f"/c{c}r{i}".encode():
+                    errors.append((c, i, body))
+        except (OSError, http.client.HTTPException):
+            pass  # the front went away under it: what the test is about
+        finally:
+            conn.close()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for _ in range(takers):
+            bare.taker(serve)
+        clients = [threading.Thread(target=client, args=(c,)) for c in range(8)]
+        for t in clients:
+            t.start()
+        assert wait_for(lambda: len(answered) >= 200)
+        bare.lib.hf_shutdown(bare.handle)
+        for t in clients + bare.threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in clients + bare.threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors[:5]
+    assert len(set(answered)) == len(answered) and not set(answered) & set(late)
+    assert bare.take() == (-1, None)
 
 
 # -- fallback: bit-compatible when the native path is unavailable ------------

@@ -12,7 +12,10 @@ reckons with at the window's end), the host path stage by stage in wall
 and in thread-CPU time (`serving.front.*`, `serving.handler.*`,
 `serving.batcher.entry` / `wake` / `submit.device-call`, the
 dispatcher's and the completer's CPU a pass,
-the process's CPU as cores busy: which stage grows towards the knee),
+the process's CPU as cores busy: which stage grows towards the knee;
+the wall stages once more as a `stages:` line, with the share of requests
+their serving thread took from the C++ front itself, `taken_pct`: 100 on
+the native front, 0 on the Python front),
 for a cell that folds in (`/recommendToAnonymous`) the fold-in's mean,
 its items a request and the mean k bucket of a pass (whose knee is it:
 the host fold-in's, or the device's), the share of passes whose results
@@ -36,6 +39,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import run, stats  # noqa: E402
 from benchmark import spec as spec_mod  # noqa: E402
+
+
+def taken_pct(before: dict, after: dict) -> float:
+    """The share of a window's requests that their serving thread took
+    from the C++ front itself, with no thread in between: 100 on the
+    native front, 0 on the Python front (and on a program from before)."""
+    taken = stats.counter_delta(before, after, "serving.front.taken", "value")
+    requests = stats.counter_delta(before, after, "serving.handler.requests", "value")
+    return 100.0 * taken / max(requests, 1.0)
 
 
 def window_row(session, load: str, seed: int, seconds: float, trace: bool,
@@ -112,6 +124,8 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
         # the host path, stage by stage (serving/stages.py): a request's
         # wall time in order, then a pass's, then the CPU beside them
         "front_native": (after.get("serving.front.native") or {}).get("value"),
+        "taken_pct": taken_pct(before, after),
+        "front_workers": (after.get("serving.front.workers") or {}).get("value"),
         "front_ingress_mean_ms": mean_ms("serving.front.ingress.seconds"),
         "handler_pre_mean_ms": mean_ms("serving.handler.pre.seconds"),
         "batcher_entry_mean_ms": mean_ms("serving.batcher.entry.seconds"),
@@ -124,7 +138,6 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
         "deliver_mean_ms": mean_ms("serving.batcher.deliver.seconds"),
         "submit_device_call_mean_ms": mean_ms("serving.batcher.submit.device-call.seconds"),
         "handler_cpu_ms_per_request": per_request_ms("serving.handler.cpu.seconds"),
-        "front_cpu_ms_per_request": per_request_ms("serving.front.cpu.seconds"),
         "server_cpu_ms_per_request": per_request_ms("serving.process.cpu.seconds"),
         "dispatch_cpu_ms_per_pass": 1000.0 * d("serving.batcher.dispatch.cpu.seconds") / passes,
         "complete_cpu_ms_per_pass": 1000.0 * d("serving.batcher.complete.cpu.seconds") / passes,
@@ -148,6 +161,24 @@ def window_row(session, load: str, seed: int, seconds: float, trace: bool,
         if n:
             row["scan_kernel_ms_per_pass"] = 1000.0 * s / n
     return row
+
+
+def stages_line(row: dict) -> str:
+    """A window's host path for the eye, one line: who brought the
+    requests to their serving threads, then a request's wall stages in
+    order (means over the staged requests, ms)."""
+    path = (
+        ("ingress", "front_ingress_mean_ms"), ("pre", "handler_pre_mean_ms"),
+        ("entry", "batcher_entry_mean_ms"), ("queue", "queue_wait_mean_ms"),
+        ("in flight", "pass_inflight_mean_ms"), ("wake", "waiter_wake_mean_ms"),
+        ("post", "handler_post_mean_ms"), ("respond", "front_respond_mean_ms"),
+    )
+    return (
+        "stages: front_native %s, taken %.1f %% of %.0f requests/s by %s serving threads; "
+        % (row["front_native"], row["taken_pct"], row["requests_per_s"], row["front_workers"])
+        + " -> ".join(f"{name} {row[key]:.3f}" for name, key in path)
+        + " ms"
+    )
 
 
 def main(argv=None) -> int:
@@ -177,7 +208,8 @@ def main(argv=None) -> int:
             row = window_row(session, load, args.seed + 1000 * i + int(float(load.lstrip("c"))),
                              float(seconds or args.seconds), bool(args.trace), raw)
             rows.append(row)
-            print("sweep_passes:", json.dumps(row), flush=True)
+            print("sweep_passes:", json.dumps(row))
+            print(stages_line(row), flush=True)
     finally:
         session.close()
     out = os.path.join("chiprun_out", f"sweep_passes_{args.workload}_{args.seed}.json")
