@@ -27,7 +27,7 @@ from oryx_tpu.analysis.core import (
 DEFAULT_TARGETS = [REPO_ROOT / "deploy"]
 
 # endpoints the serving layer's router registers unconditionally
-# (oryx_tpu/serving/layer.py _ready/_healthz/_readyz/_metrics)
+# (oryx_tpu/serving/framework.py _ready/_healthz/_readyz/_metrics)
 KNOWN_PROBE_PATHS = {"/ready", "/healthz", "/readyz", "/metrics"}
 
 _ARGS_LINE = re.compile(r"""(?:args|command):\s*\[\s*["']([^"']+)["']""")

@@ -486,10 +486,6 @@ class TopNBatcher:
         # every float32 handle): over `passes`, the share that made one
         # download and one fetch
         self._m_pass_packed = _metrics.counter("serving.batcher.pass.packed")
-        # counts the times the depth target takes a new value: the depth is
-        # fixed, so it stays 0; registered so that a reader of it reads a
-        # number and not nothing, as it would where the counter is missing
-        self._m_cap_changes = _metrics.counter("serving.batcher.inflight-cap.changes")
         self._m_submit_seconds = _metrics.histogram("serving.batcher.submit.seconds")
         # the host path, stage by stage (serving/stages.py): a request's time
         # in here and the part of it after the results were on the host; the

@@ -23,9 +23,10 @@ The division of labor (docs/serving-native.md):
   decode its one RBLK KIND_HTTP record (bus/blockcodec.py) ->
   ``_serve_one``. No thread stands between the parser and the thread
   that serves: a request waits for the interpreter once on its way in.
-  It runs through the exact same ``layer._dispatch_parsed`` core the
-  stdlib handler uses — tenant resolution, admission ladder, tracing,
-  experiments, rendering cannot drift between fronts.
+  It is served by the request core the stdlib handler calls too
+  (``serving/request.py`` ``answer``: tenant resolution, admission
+  ladder, tracing, experiments, rendering, error mapping, gzip rule);
+  this module only turns what comes back into bytes.
 - A control thread pushes ladder/tenant snapshots down (overload.py
   stays the single decision-maker; C++ only applies the last pushed
   stage), mirrors answer-cache puts, re-renders liveness snapshots, and
@@ -56,7 +57,8 @@ from oryx_tpu.bus import blockcodec
 from oryx_tpu.common import metrics, tracing
 from oryx_tpu.serving import overload as _overload
 from oryx_tpu.serving import stages as _stages
-from oryx_tpu.serving.web import OryxServingException, Request, render
+from oryx_tpu.serving.request import _shed_response, answer
+from oryx_tpu.serving.web import OryxServingException, Request, Response, render
 from oryx_tpu.tenancy import context as _tenancy
 
 log = logging.getLogger(__name__)
@@ -101,7 +103,7 @@ def _http_date() -> str:
 
 class _Headers:
     """Case-insensitive ``get`` over the original-cased header pairs —
-    the same contract email.Message gives ``_dispatch_parsed``."""
+    the same contract email.Message gives the request core."""
 
     __slots__ = ("_pairs",)
 
@@ -119,40 +121,48 @@ class _Headers:
         return list(self._pairs)
 
 
-def _template_pre(status):
-    """Everything up to the Date value; C++ stamps the date at send time
-    in the same IMF-fixdate format formatdate(usegmt=True) emits."""
-    return (
-        f"HTTP/1.1 {status} {_reason(status)}\r\nServer: {_SERVER}\r\nDate: "
-    ).encode("latin-1")
+def _wire(status, fields: str, body):
+    """A response as the Python front's `send_response` / `send_header`
+    write it (tests/serving/test_native_front.py compares the fronts'
+    bytes), split at the Date value, which C++ stamps into a template at
+    send time and `_dated` into a served answer: both in the IMF-fixdate
+    format formatdate(usegmt=True) emits. ``fields`` are the header lines
+    after Date, each led by its CRLF."""
+    pre = f"HTTP/1.1 {status} {_reason(status)}\r\nServer: {_SERVER}\r\nDate: "
+    return pre.encode("latin-1"), (fields + "\r\n\r\n").encode("latin-1") + body
+
+
+def _dated(pre: bytes, post: bytes) -> bytes:
+    return pre + _http_date().encode("latin-1") + post
+
+
+def _success_wire(status, payload, ct, extra):
+    """`_wire` of a rendered (render()) response."""
+    fields = f"\r\nContent-Type: {ct}\r\nContent-Length: {len(payload)}"
+    for k, v in extra.items():
+        fields += f"\r\n{k}: {v}"
+    return _wire(status, fields, payload)
+
+
+def _error_wire(status, message):
+    """`_wire` of the Python front's `_send_error`: a plain text body."""
+    body = f"{status} {message}\n".encode("utf-8")
+    fields = '\r\nWWW-Authenticate: Basic realm="Oryx"' if status == 401 else ""
+    fields += f"\r\nContent-Type: text/plain\r\nContent-Length: {len(body)}"
+    return _wire(status, fields, body)
 
 
 def _success_template(status, payload, ct, extra):
-    """Template for a rendered (render()) response: mirrors
-    Handler._handle_counted's write path byte for byte. The gzip rung is
-    handled by C++ forwarding instead (accept_blocks_native), so the
-    template always holds the identity body."""
-    body = payload
-    pre = _template_pre(status)
-    lines = [f"Content-Type: {ct}", f"Content-Length: {len(body)}"]
-    for k, v in dict(extra).items():
-        lines.append(f"{k}: {v}")
-    post = ("\r\n" + "\r\n".join(lines) + "\r\n\r\n").encode("latin-1") + body
-    return pre, post, len(body), status
+    """(pre, post, body_len, status) for C++. The gzip rung is handled by
+    C++ forwarding instead (accept_blocks_native), so the template always
+    holds the identity body."""
+    return *_success_wire(status, payload, ct, extra), len(payload), status
 
 
 def _error_template(status, message):
-    """Template for _send_error(): plain text body, written even for
-    HEAD (body_len 0 disables C++ HEAD stripping to match)."""
-    body = f"{status} {message}\n".encode("utf-8")
-    pre = _template_pre(status)
-    lines = []
-    if status == 401:
-        lines.append('WWW-Authenticate: Basic realm="Oryx"')
-    lines.append("Content-Type: text/plain")
-    lines.append(f"Content-Length: {len(body)}")
-    post = ("\r\n" + "\r\n".join(lines) + "\r\n\r\n").encode("latin-1") + body
-    return pre, post, 0, status
+    """The error body is written even for HEAD (body_len 0 disables C++
+    HEAD stripping to match)."""
+    return *_error_wire(status, message), 0, status
 
 
 def _u8(data: bytes):
@@ -180,29 +190,23 @@ def maybe_start(layer, ctx, threads):
         )
     if mode == "false":
         return None
-    forced = mode == "true"
+
+    def decline(why: str) -> None:
+        if mode == "true":
+            log.warning(
+                "oryx.serving.native.enabled=true but %s; falling back to the "
+                "Python front", why,
+            )
+
     if layer.use_tls or layer.user_name:
-        if forced:
-            log.warning(
-                "oryx.serving.native.enabled=true but TLS/auth is configured; "
-                "falling back to the Python front"
-            )
-        return None
+        return decline("TLS/auth is configured")
     if layer.tenants is not None and len(layer.tenants.ids()) > 64:
-        if forced:
-            log.warning(
-                "oryx.serving.native.enabled=true but >64 tenants configured; "
-                "falling back to the Python front"
-            )
-        return None
+        return decline(">64 tenants configured")
     lib = native.get_library()
     if lib is None or not hasattr(lib, "hf_create"):
-        if forced:
-            log.warning(
-                "oryx.serving.native.enabled=true but the native library is "
-                "unavailable (no toolchain or ORYX_NATIVE=0); falling back"
-            )
-        return None
+        return decline(
+            "the native library is unavailable (no toolchain or ORYX_NATIVE=0)"
+        )
     max_header = cfg.get_int("oryx.serving.native.max-header-bytes")
     max_body = cfg.get_int("oryx.serving.native.max-body-bytes")
     idle_s = cfg.get_float("oryx.serving.native.idle-timeout-s")
@@ -358,17 +362,15 @@ class NativeFront:
                               rec.method, rec.target)
 
     def _serve_one(self, rec) -> None:
-        """Mirror of Handler._handle for one pre-parsed request."""
+        """One request this thread took: from its record to the hand-over
+        of its answer's bytes."""
         layer = self._layer
         # the C++ front stamped the record on CLOCK_MONOTONIC when it had
         # parsed the request's last byte
         t0 = layer.stages.begin(time.clock_gettime(time.CLOCK_MONOTONIC) - rec.t_parsed)
         layer._request_began()
         try:
-            path = rec.target.split("?", 1)[0]
-            ctxp = layer.context_path or ""
-            if ctxp and path.startswith(ctxp):
-                path = path[len(ctxp):]
+            path = rec.target.split("?", 1)[0].removeprefix(layer.context_path)
             if path.startswith(("/metrics", "/trace")):
                 # an ops scrape must reflect every request answered so
                 # far — including ones C++ answered since the last
@@ -376,73 +378,20 @@ class NativeFront:
                 # before the handler renders the snapshot
                 self._drain_stats()
                 self._drain_trace()
-            headers = _Headers(rec.headers)
-            tenant_box = [None]
-            try:
-                from oryx_tpu.serving.layer import (_dispatch_parsed,
-                                                    _observe_request)
-                status, payload, ct, extra = _dispatch_parsed(
-                    layer, self._ctx, rec.method, rec.target, headers,
-                    rec.body, tenant_box,
-                )
-            except OryxServingException as e:
-                _observe_request(rec.method, e.status, t0, layer,
-                                 tenant_box[0])
-                self._respond(rec, self._error_bytes(e.status, e.message))
-                return
-            except Exception:
-                log.exception("internal error handling %s %s", rec.method,
-                              rec.target)
-                _observe_request(rec.method, 500, t0, layer, tenant_box[0])
-                self._respond(rec, self._error_bytes(500, "internal error"))
-                return
-            _observe_request(rec.method, status, t0, layer, tenant_box[0])
-            self._respond(
-                rec,
-                self._assemble(status, payload, ct, extra,
-                               headers.get("Accept-Encoding", ""),
-                               rec.method == "HEAD"),
+            status, message, ct, fields, body = answer(
+                layer, self._ctx, rec.method, rec.target,
+                _Headers(rec.headers), rec.body, t0,
             )
+            if message is not None:
+                data = _dated(*_error_wire(status, message))  # the body even for HEAD
+            else:
+                data = _dated(*_success_wire(status, body, ct, fields))
+                if rec.method == "HEAD":
+                    data = data[: len(data) - len(body)]
+            self._respond(rec, data)
         finally:
             layer.stages.responded()
             layer._request_ended()
-
-    def _assemble(self, status, payload, ct, extra, accept_encoding,
-                  is_head) -> bytes:
-        """Byte-for-byte mirror of Handler._handle_counted's write path."""
-        from oryx_tpu.serving.layer import gzip_compress
-
-        body = payload
-        headers = dict(extra)
-        if len(body) > 1024 and "gzip" in accept_encoding:
-            body = gzip_compress(body)
-            headers["Content-Encoding"] = "gzip"
-        lines = [
-            f"HTTP/1.1 {status} {_reason(status)}",
-            f"Server: {_SERVER}",
-            f"Date: {_http_date()}",
-            f"Content-Type: {ct}",
-            f"Content-Length: {len(body)}",
-        ]
-        for k, v in headers.items():
-            lines.append(f"{k}: {v}")
-        head = ("\r\n".join(lines) + "\r\n\r\n").encode("latin-1")
-        return head if is_head else head + body
-
-    def _error_bytes(self, status, message) -> bytes:
-        """Byte-for-byte mirror of Handler._send_error (the error body is
-        written even for HEAD, matching the Python front)."""
-        body = f"{status} {message}\n".encode("utf-8")
-        lines = [
-            f"HTTP/1.1 {status} {_reason(status)}",
-            f"Server: {_SERVER}",
-            f"Date: {_http_date()}",
-        ]
-        if status == 401:
-            lines.append('WWW-Authenticate: Basic realm="Oryx"')
-        lines.append("Content-Type: text/plain")
-        lines.append(f"Content-Length: {len(body)}")
-        return ("\r\n".join(lines) + "\r\n\r\n").encode("latin-1") + body
 
     def _respond(self, rec, data: bytes) -> None:
         """Hand one response to the C++ front, with no Python lock held:
@@ -522,8 +471,6 @@ class NativeFront:
             self._lib.hf_set_tenants(self._handle, _u8(blob), len(blob))
 
     def _push_shed_template(self) -> None:
-        from oryx_tpu.serving.layer import _shed_response
-
         resp = _shed_response(self._layer.overload_config.retry_after_s)
         resp.headers[_overload.SHED_HEADER] = "shed"
         status, payload, ct, extra = render(resp, "application/json")
@@ -566,10 +513,10 @@ class NativeFront:
 
     # -- answer-cache mirror -------------------------------------------------
 
-    def _on_cache_put(self, key, answer) -> None:
+    def _on_cache_put(self, key, cached) -> None:
         # called from request threads under no lock: just enqueue; the
         # control tick renders (rendering needs no request context)
-        self._cache_queue.append((key, answer))
+        self._cache_queue.append((key, cached))
 
     def _sync_cache(self) -> None:
         adm = self._layer.admission
@@ -584,15 +531,13 @@ class NativeFront:
             self._cache_queue.clear()
         while True:
             try:
-                key, answer = self._cache_queue.popleft()
+                key, cached = self._cache_queue.popleft()
             except IndexError:
                 break
-            if answer.generation != champion:
+            if cached.generation != champion:
                 continue
-            from oryx_tpu.serving.web import Response
-
             resp = Response(
-                answer.status, answer.payload, answer.content_type,
+                cached.status, cached.payload, cached.content_type,
                 headers={_overload.SHED_HEADER: "stale"},
             )
             try:

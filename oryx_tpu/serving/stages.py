@@ -10,6 +10,9 @@ differences tile its wall time exactly:
     woken -> _observe_request                           handler.post    |
     _observe_request -> response handed to the socket   front.respond
 
+(``_handle``: serving/python_front.py; ``_serve_one``: serving/native_front.py;
+``_observe_request``: serving/request.py, called once a request, from ``answer``.)
+
 The batcher feeds ``entry`` (and ``wake``, the part of it after the
 pass's results were on the host) from its own handles; everything else
 is fed here, once the answer has left, from stamps kept in one object a

@@ -11,7 +11,7 @@ right tenant's manager, tracker, or topic underneath them.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any
 
 from oryx_tpu.tenancy.context import current_tenant
@@ -20,18 +20,20 @@ from oryx_tpu.tenancy.spec import TenantSpec
 
 @dataclass
 class TenantRuntime:
-    """One tenant's live serving-side state on this replica."""
+    """One update stream's live serving-side state on this replica: a
+    tenant's or, with ``spec`` None, the one stream of a replica that
+    serves no tenants (``ServingLayer`` opens, feeds and closes every
+    runtime the same way; only the tenant kind goes behind the mux)."""
 
-    spec: TenantSpec
+    spec: TenantSpec | None
     config: Any  # the tenant's namespaced view (tenancy.spec.tenant_config)
-    manager: Any  # the tenant's serving model manager
-    health: Any  # per-tenant ServingHealth (staleness / live generation)
-    tracker: Any  # per-tenant GenerationTracker
-    store: Any = None  # per-tenant RegistryStore (None without a model dir)
-    consumer: Any = None  # per-tenant update-topic consumer
+    manager: Any  # the serving model manager (None: a replica without one)
+    health: Any  # ServingHealth (staleness / live generation)
+    tracker: Any  # GenerationTracker
+    store: Any = None  # RegistryStore (None without a model dir)
+    consumer: Any = None  # update-topic consumer
     thread: Any = None  # the SupervisedThread driving consume_blocks
-    producer: Any = None  # per-tenant input-topic producer (ingest path)
-    extras: dict = field(default_factory=dict)
+    producer: Any = None  # input-topic producer (ingest path)
 
 
 class TenantServingMux:
@@ -61,9 +63,6 @@ class TenantServingMux:
 
     def runtime(self, tenant_id: str) -> TenantRuntime | None:
         return self._runtimes.get(tenant_id)
-
-    def runtimes(self) -> dict[str, TenantRuntime]:
-        return dict(self._runtimes)
 
     def ids(self) -> list[str]:
         return list(self._runtimes)

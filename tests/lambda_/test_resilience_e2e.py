@@ -176,7 +176,7 @@ def _run_pipeline(locator, inner_locator):
         assert speed.healthy()
         assert not speed._consume_thread.is_alive()
         assert not speed._batch_thread.is_alive()
-        assert not serving._consume_thread.is_alive()
+        assert not serving._runtimes[0].thread.is_alive()
 
 
 def test_pipeline_converges_under_seeded_drop_and_delay():

@@ -543,7 +543,7 @@ def _stall(b, stub) -> list:
     ids=["refill-inside-the-pass", "refill-half-the-pass", "pass-a-tenth-of-the-refill", "synchronous"],
 )
 def test_two_passes_are_in_flight_whatever_the_pass_and_the_refill_take(stub, pass_ms, submit_ms):
-    """The depth rests at two and `inflight-cap.changes` does not move:
+    """The depth rests at two:
     measured on the chip, a deeper pipeline buys nothing where the pass is
     shorter than the refill either (PERF.md, PR 28). And no close is held
     where the pass is shorter than the lead (all but the first stub),
@@ -554,11 +554,10 @@ def test_two_passes_are_in_flight_whatever_the_pass_and_the_refill_take(stub, pa
     before = _pass_record()
     handle = object()
     try:
-        start = b._m_cap_changes.value
         assert b._inflight_cap == batcher_mod.MIN_INFLIGHT == 2
         for first in (1, 13, 25):
             _join(_ask(b, range(first, first + 12), uploaded=handle))
-        assert b._inflight_cap == 2 and b._m_cap_changes.value == start
+        assert b._inflight_cap == 2
         assert len(b._flight) <= 2  # settled at every submit, whether or not a close was weighed
     finally:
         b.close()
@@ -593,7 +592,6 @@ def test_an_explicit_max_inflight_pins_the_depth(stub, depth):
     b = TopNBatcher(max_inflight=depth)
     before = _pass_record()
     try:
-        start = b._m_cap_changes.value
         held = _stall(b, stub)
         assert len(held) == depth
         rest = _ask(b, [4, 5])
@@ -602,24 +600,11 @@ def test_an_explicit_max_inflight_pins_the_depth(stub, depth):
         stub.gate.set()
         for asked in held + [rest]:
             _join(asked)
-        assert b._inflight_cap == depth and b._m_cap_changes.value == start
+        assert b._inflight_cap == depth
     finally:
         b.close()
     got = _delta(before)
     assert got["passes"] == depth + 1 and got["depth_sum"] <= depth * got["passes"]
-
-
-def test_the_cap_changes_counter_is_there_and_reads_zero():
-    """An operator counter since PR 39 pruned the per-layer metric that
-    read it (the depth is fixed since PR 28, so it can only read 0): the
-    handle is taken at start, so the counter is in every snapshot."""
-    b = TopNBatcher()
-    try:
-        snap = batcher_mod._metrics.snapshot()
-        assert "value" in snap["serving.batcher.inflight-cap.changes"]
-        assert b._m_cap_changes is batcher_mod._metrics.counter("serving.batcher.inflight-cap.changes")
-    finally:
-        b.close()
 
 
 def test_a_scheduler_configured_with_nothing_holds_depth_two_and_4096_rows():

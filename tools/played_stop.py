@@ -112,7 +112,6 @@ def window_counters(span, stats) -> dict:
         "pass_inflight_mean_ms": mean_ms("serving.batcher.pass.seconds"),
         "rows_per_pass": d("serving.batcher.pass.rows", "value") / passes,
         "inflight_depth_mean": d("serving.batcher.pass.inflight-depth-sum", "value") / passes,
-        "depth_cap_changes": d("serving.batcher.inflight-cap.changes", "value"),
         "compiles": d("jax.compile.seconds", "count"),
     }
 
